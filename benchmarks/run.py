@@ -30,6 +30,9 @@ def main() -> None:
     args = ap.parse_args()
     want = set(filter(None, args.only.split(",")))
 
+    from repro.compile_cache import enable_compile_cache
+    print(f"# compile cache: {enable_compile_cache()}", flush=True)
+
     from benchmarks import (overhead, roofline_report, serving_hotpath,
                             sim_bench, stability, table1_throughput,
                             table3_bbs)

@@ -14,12 +14,11 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-import jax
 import numpy as np
 
-import repro.models as M
 from repro.configs import ensemble
 from repro.core import AllocationOptimizer, MeasuredBench, host_cpus
+from repro.launch.serve import init_member_params
 from repro.serving import (EnsembleClient, PredictionCache, PredictOptions,
                            InferenceSystem)
 
@@ -30,9 +29,8 @@ def main():
     # 1. the ensemble: 2 heterogeneous members (fast demo; see serve_ensemble
     #    for the full ENS4/ENS12 setups)
     cfgs = ensemble("ENS4")[:2]
-    rng = jax.random.PRNGKey(0)
-    params = [M.init_params(jax.random.fold_in(rng, i), c)
-              for i, c in enumerate(cfgs)]
+    # random weights on the host CPU device, like the CPU cells below
+    params = init_member_params(cfgs, ["fp32"] * len(cfgs))
     print("ensemble:", [c.name for c in cfgs])
 
     # 2. optimize the allocation matrix on 2 logical devices

@@ -15,12 +15,11 @@ import urllib.request
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-import jax
 import numpy as np
 
-import repro.models as M
 from repro.configs import ensemble
 from repro.core import AllocationOptimizer, MeasuredBench, host_cpus
+from repro.launch.serve import init_member_params
 from repro.serving.server import serve
 from repro.serving.system import InferenceSystem
 
@@ -43,9 +42,8 @@ def main():
     args = ap.parse_args()
 
     cfgs = ensemble(args.ensemble)[: args.members]
-    rng = jax.random.PRNGKey(0)
-    params = [M.init_params(jax.random.fold_in(rng, i), c)
-              for i, c in enumerate(cfgs)]
+    # random weights on the host CPU device, like the CPU cells below
+    params = init_member_params(cfgs, ["fp32"] * len(cfgs))
     print("members:", [c.name for c in cfgs])
 
     devices = host_cpus(args.devices, memory_bytes=4 * 1024 ** 3)

@@ -3,24 +3,49 @@
 The paper's "device" is one GPU or CPU socket.  Our generalization (DESIGN.md
 §2): a device is an **allocation cell** — one chip, or a sub-mesh slice with
 model-parallel sharding inside.  ``jax_devices`` carries the backing runtime
-devices; on this CPU container every cell maps to the single CpuDevice while
-keeping distinct *logical* memory budgets, which is exactly what the
-allocation algorithms reason about.
+devices: real TPU chips for :func:`tpu_cells`, the host's CPU device for
+:func:`host_cpus` (every CPU cell shares it while keeping a distinct
+*logical* memory budget, which is exactly what the allocation algorithms
+reason about).
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
-from typing import Optional, Sequence, Tuple
+from dataclasses import dataclass
+from typing import Sequence, Tuple
 
 import jax
 
 GiB = 1024 ** 3
 
-# TPU v5e chip constants (the deployment target; see ROOFLINE in the brief)
-TPU_V5E_PEAK_FLOPS = 197e12          # bf16
-TPU_V5E_HBM_BW = 819e9               # bytes/s
-TPU_V5E_HBM_BYTES = 16 * GiB
-TPU_V5E_LINK_BW = 50e9               # bytes/s per ICI link
+
+@dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks of one accelerator kind."""
+    flops: float          # dense bf16 FLOP/s
+    hbm_bw: float         # HBM bytes/s
+    hbm_bytes: int        # HBM capacity
+    link_bw: float        # bytes/s per inter-chip link
+
+
+# The one table of chip peaks, keyed by ``jax.Device.device_kind``.
+# "TPU v5 lite" is the v5e: Google Cloud documentation, "TPU v5e" — 197
+# TFLOP/s bf16, 16 GiB HBM2 at 819 GB/s, 1,600 Gbit/s of ICI over four links.
+CHIPS = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, hbm_bytes=16 * GiB,
+                             link_bw=50e9),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of ``device_kind``; a kind missing from :data:`CHIPS` is an
+    error, never a default."""
+    try:
+        return CHIPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak table entry for device kind {device_kind!r} "
+            f"(known: {sorted(CHIPS)})") from None
+
 
 # Reference V100 / host constants for paper-shaped simulated clusters
 V100_PEAK_FLOPS = 125e12 / 8         # fp32 tensor-core derate for inference mix
@@ -52,30 +77,34 @@ def simulated_gpus(n: int, memory_bytes: int = V100_HBM_BYTES) -> list:
             for i in range(n)]
 
 
-def simulated_tpus(n: int, memory_bytes: int = TPU_V5E_HBM_BYTES) -> list:
-    return [DeviceSpec(f"tpu{i}", "TPU", memory_bytes, TPU_V5E_PEAK_FLOPS,
-                       TPU_V5E_HBM_BW) for i in range(n)]
-
-
 def host_cpus(n: int = 1, memory_bytes: int = 16 * GiB) -> list:
-    """CPU devices; backed by the real CpuDevice when present."""
-    backing = tuple(d for d in jax.devices() if d.platform == "cpu")[:1]
+    """CPU cells, each backed by the host's CPU device — also when an
+    accelerator is JAX's default backend."""
+    backing = tuple(jax.devices("cpu")[:1])
     return [DeviceSpec(f"cpu{i}", "CPU", memory_bytes, HOST_PEAK_FLOPS, HOST_BW,
                        jax_devices=backing) for i in range(n)]
 
 
-def tpu_cells(mesh_devices: Sequence, cell_size: int, *,
-              memory_bytes: int = TPU_V5E_HBM_BYTES) -> list:
+def _memory_limit(device, default: int) -> int:
+    """Bytes the runtime lets one device allocate, where it reports them."""
+    stats = device.memory_stats() or {}
+    return int(stats.get("bytes_limit", default))
+
+
+def tpu_cells(mesh_devices: Sequence, cell_size: int) -> list:
     """Partition a flat device list into model-parallel cells of ``cell_size``
-    chips each — the beyond-paper 'cells' extension (DESIGN.md §9.2)."""
+    chips each — the beyond-paper 'cells' extension (DESIGN.md §9.2).  Peaks
+    come from :data:`CHIPS` by the chips' ``device_kind``; a cell's memory is
+    the sum of its chips' runtime ``bytes_limit`` (the table's HBM size where
+    the runtime reports none)."""
     cells = []
     flat = list(mesh_devices)
     for i in range(0, len(flat) - cell_size + 1, cell_size):
         group = tuple(flat[i:i + cell_size])
+        peaks = chip_peaks(group[0].device_kind)
+        mem = sum(_memory_limit(d, peaks.hbm_bytes) for d in group)
         cells.append(DeviceSpec(
-            f"cell{i // cell_size}", "TPU",
-            memory_bytes * cell_size,
-            TPU_V5E_PEAK_FLOPS * cell_size,
-            TPU_V5E_HBM_BW * cell_size,
+            f"cell{i // cell_size}", "TPU", mem,
+            peaks.flops * cell_size, peaks.hbm_bw * cell_size,
             jax_devices=group))
     return cells

@@ -32,25 +32,28 @@ def _kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # everything stays 2-D (one query row) so it maps onto (sublane, lane)
     q = q_ref[0, 0].astype(jnp.float32)            # (1, hd)
     k = k_ref[0, 0].astype(jnp.float32)            # (bkv, hd)
     v = v_ref[0, 0].astype(jnp.float32)
-    ok = valid_ref[0] > 0                          # (bkv,)
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)[0]   # (bkv,)
+    ok = valid_ref[...] > 0                        # (1, bkv)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)   # (1, bkv)
     s = jnp.where(ok, s, NEG_INF)
 
-    m_prev = m_ref[0]
-    m_new = jnp.maximum(m_prev, s.max())
+    m_prev = m_ref[...]                            # (1, 1)
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new)
-    l_ref[0] = l_ref[0] * alpha + p.sum()
+    l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
     acc_ref[...] = acc_ref[...] * alpha + \
-        jnp.dot(p[None, :], v, preferred_element_type=jnp.float32)
-    m_ref[0] = m_new
+        jnp.dot(p, v, preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
 
     @pl.when(ki == num_kv_blocks - 1)
     def _finalize():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[0], 1e-30)).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                       ).astype(o_ref.dtype)
 
 
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -70,7 +73,9 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     qt = q.transpose(0, 2, 1, 3)                   # (B,H,1,hd)
     kt = k.transpose(0, 2, 1, 3)                   # (B,KV,L,hd)
     vt = v.transpose(0, 2, 1, 3)
-    valid_i = valid.astype(jnp.int32).reshape(nk, block_kv)
+    # (1, L) so a mask block (1, block_kv) meets the (8, 128) tiling rule:
+    # its row dim equals the array's, its lane dim is a multiple of 128 or L
+    valid_i = valid.astype(jnp.int32).reshape(1, L)
 
     kernel = functools.partial(_kernel, num_kv_blocks=nk)
     out = pl.pallas_call(
@@ -80,13 +85,13 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, 1, 1, hd), lambda b_, h_, k_: (b_, h_, 0, 0)),
             pl.BlockSpec((1, 1, block_kv, hd), lambda b_, h_, k_: (b_, h_ // group, k_, 0)),
             pl.BlockSpec((1, 1, block_kv, hd), lambda b_, h_, k_: (b_, h_ // group, k_, 0)),
-            pl.BlockSpec((1, block_kv), lambda b_, h_, k_: (k_, 0)),
+            pl.BlockSpec((1, block_kv), lambda b_, h_, k_: (0, k_)),
         ],
         out_specs=pl.BlockSpec((1, 1, 1, hd), lambda b_, h_, k_: (b_, h_, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, 1, hd), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, hd), jnp.float32),
         ],
         interpret=interpret,

@@ -31,6 +31,11 @@ from jax.experimental.pallas import tpu as pltpu
 BLOCK_SEG = 128
 BLOCK_C = 512
 
+# The (M,) combine weights sit whole in scalar memory and the kernel reads
+# w[m] for the member on the grid: a rank-1 VMEM block of one weight breaks
+# the TPU's (8, 128) tiling rule for any M > 1.
+_WEIGHTS = pl.BlockSpec(memory_space=pltpu.SMEM)
+
 
 def _kernel(p_ref, w_ref, y_ref, acc_ref, *, members: int):
     mi = pl.program_id(2)
@@ -39,7 +44,7 @@ def _kernel(p_ref, w_ref, y_ref, acc_ref, *, members: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += p_ref[0].astype(jnp.float32) * w_ref[0].astype(jnp.float32)
+    acc_ref[...] += p_ref[0].astype(jnp.float32) * w_ref[mi]
 
     @pl.when(mi == members - 1)
     def _finalize():
@@ -53,7 +58,7 @@ def _accum_kernel(part_ref, p_ref, w_ref, y_ref, acc_ref, *, members: int):
     def _init():
         acc_ref[...] = part_ref[...].astype(jnp.float32)
 
-    acc_ref[...] += p_ref[0].astype(jnp.float32) * w_ref[0].astype(jnp.float32)
+    acc_ref[...] += p_ref[0].astype(jnp.float32) * w_ref[mi]
 
     @pl.when(mi == members - 1)
     def _finalize():
@@ -72,7 +77,7 @@ def _quant_accum_kernel(part_ref, q_ref, s_ref, w_ref, y_ref, acc_ref, *,
     # lane 0 and broadcast along lanes (the TPU-cheap direction).
     scale = s_ref[0][:, :1]
     deq = q_ref[0].astype(jnp.float32) * scale
-    acc_ref[...] += deq * w_ref[0].astype(jnp.float32)
+    acc_ref[...] += deq * w_ref[mi]
 
     @pl.when(mi == members - 1)
     def _finalize():
@@ -103,7 +108,7 @@ def ensemble_combine_quant(partial: jax.Array, q: jax.Array,
         tile,
         pl.BlockSpec((1, block_seg, block_c), lambda s_, c_, m_: (m_, s_, c_)),
         pl.BlockSpec((1, block_seg, 128), lambda s_, c_, m_: (m_, s_, 0)),
-        pl.BlockSpec((1,), lambda s_, c_, m_: (m_,)),
+        _WEIGHTS,
     ]
     return pl.pallas_call(
         functools.partial(_quant_accum_kernel, members=m),
@@ -113,7 +118,7 @@ def ensemble_combine_quant(partial: jax.Array, q: jax.Array,
         out_shape=jax.ShapeDtypeStruct((seg, c), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_seg, block_c), jnp.float32)],
         interpret=interpret,
-    )(partial, q, scales, weights)
+    )(partial, q, scales, weights.astype(jnp.float32))
 
 
 def ensemble_combine(preds: jax.Array, weights: jax.Array,
@@ -130,8 +135,9 @@ def ensemble_combine(preds: jax.Array, weights: jax.Array,
     tile = pl.BlockSpec((block_seg, block_c), lambda s_, c_, m_: (s_, c_))
     in_specs = [
         pl.BlockSpec((1, block_seg, block_c), lambda s_, c_, m_: (m_, s_, c_)),
-        pl.BlockSpec((1,), lambda s_, c_, m_: (m_,)),
+        _WEIGHTS,
     ]
+    weights = weights.astype(jnp.float32)
     if partial is None:
         kernel = functools.partial(_kernel, members=m)
         operands = (preds, weights)

@@ -5,8 +5,9 @@ Responsibilities:
     multiples, then slice results back;
   * pre-apply the softmax scale on q so zero-padding of head_dim cannot
     change results;
-  * select interpret mode automatically off-TPU (`pallas_enabled()` reports
-    whether the compiled TPU path is active).
+  * run the compiled Mosaic kernels whenever JAX's backend is the TPU, and
+    the Pallas interpreter otherwise — interpret mode is the CPU-test path
+    (`pallas_enabled()` reports whether the compiled TPU path is active).
 """
 from __future__ import annotations
 
@@ -20,18 +21,21 @@ from repro.kernels import ensemble_combine as _comb
 from repro.kernels import flash_attention as _fa
 from repro.kernels import ssd_scan as _ssd
 
-_FORCE_INTERPRET = None  # tests can monkeypatch via set_interpret()
+_FORCE_INTERPRET = None  # test-only override, see set_interpret()
 
 
 def set_interpret(value):
+    """Test hook: ``False`` lowers the Mosaic kernels off-TPU (compiling for
+    a described chip), ``None`` restores the default.  It cannot turn the
+    interpreter on when the backend is a TPU."""
     global _FORCE_INTERPRET
     _FORCE_INTERPRET = value
 
 
 def _interpret() -> bool:
-    if _FORCE_INTERPRET is not None:
-        return _FORCE_INTERPRET
-    return jax.default_backend() != "tpu"
+    if jax.default_backend() == "tpu":
+        return False
+    return True if _FORCE_INTERPRET is None else _FORCE_INTERPRET
 
 
 def pallas_enabled() -> bool:

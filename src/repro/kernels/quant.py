@@ -15,8 +15,7 @@ Symmetric scheme throughout: ``scale = max(|x|, axis) / qmax`` (clamped to
 1e-8 so all-zero channels stay finite), ``q = clip(round(x / scale))``.
 int8 uses qmax=127; fp8 (e4m3) uses qmax=448 and stores the scaled value
 directly in the narrow float format (no rounding step needed — the cast
-rounds).  fp8 is gated on the jax build exposing ``float8_e4m3fn``;
-:func:`validate_member_dtype` rejects it when unavailable.
+rounds).
 """
 from __future__ import annotations
 
@@ -30,7 +29,7 @@ import jax.numpy as jnp
 # therefore H2D traffic and packing density in the allocator).
 MEMBER_DTYPES = {"fp32": 4, "bf16": 2, "int8": 1, "fp8": 1}
 
-_FP8_DTYPE = getattr(jnp, "float8_e4m3fn", None)
+_FP8_DTYPE = jnp.float8_e4m3fn
 _FP8_MAX = 448.0  # largest finite e4m3 value
 
 
@@ -40,9 +39,6 @@ def validate_member_dtype(name: str) -> str:
         raise ValueError(
             f"unknown member dtype {name!r}; expected one of "
             f"{sorted(MEMBER_DTYPES)}")
-    if name == "fp8" and _FP8_DTYPE is None:
-        raise ValueError("fp8 member dtype requires a jax build with "
-                         "float8_e4m3fn support")
     return name
 
 
@@ -88,8 +84,6 @@ def quantize_symmetric(x: jax.Array, axis: int = -1,
         scale = jnp.maximum(amax / 127.0, 1e-8)
         q = jnp.clip(jnp.round(xf / scale), -127, 127).astype(jnp.int8)
     elif dtype == "fp8":
-        if _FP8_DTYPE is None:  # pragma: no cover - depends on jax build
-            raise ValueError("fp8 unavailable in this jax build")
         scale = jnp.maximum(amax / _FP8_MAX, 1e-8)
         q = (xf / scale).astype(_FP8_DTYPE)
     else:

@@ -1,17 +1,17 @@
 """Pallas TPU kernel for the Mamba2 SSD chunked scan.
 
-Tiling: grid = (batch, num_chunks); the chunk dim is the innermost sequential
-grid dim, so the inter-chunk SSM state (H, P, N) is carried in VMEM scratch
-(f32).  Each kernel invocation computes one chunk's dual form:
+Tiling: grid = (batch, heads, num_chunks); the chunk dim is the innermost
+sequential grid dim, so each head's inter-chunk SSM state (P, N) is carried
+in VMEM scratch (f32).  Each kernel invocation computes one head's dual form
+over one chunk:
 
     y_intra = (C B^T ∘ L) (dt x)        — attention-like, MXU matmuls
     y_inter = C h_in * exp(cumsum dA)   — contribution of the carried state
-    h_out   = h_in * exp(sum dA) + B^T (dt decay x)
+    h_out   = h_in * exp(sum dA) + (dt decay x)^T B
 
-For mamba2-1.3b a full state tile is 64*64*128*4B = 2 MiB and a chunk tile is
-~1 MiB — comfortably inside the ~16 MiB/core VMEM budget; chunk length 64
-keeps the L matrix (cl, cl) MXU-aligned when padded to 128 (done by ops.py
-only when cl < 8; default chunks are already aligned).
+Heads lead the layout (the wrapper transposes x to (B, H, S, P)), so every
+block is a 2-D (time, feature) tile.  For mamba2-1.3b a state tile is
+64*128*4B = 32 KiB and a chunk tile 16 KiB, far inside VMEM.
 """
 from __future__ import annotations
 
@@ -23,39 +23,51 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, h_ref, *, nheads: int,
-            hdim: int, dstate: int, chunk: int):
-    ci = pl.program_id(1)
+def _kernel(x_ref, dtc_ref, dtr_ref, a_ref, b_ref, c_ref, y_ref, h_ref, *,
+            chunk: int):
+    """One (batch, head, chunk) step.  Every value is 2-D: time runs down
+    the sublanes of the column operands and along the lanes of the row
+    operands, so no op needs a transpose or a 3-D layout."""
+    hi = pl.program_id(1)
+    ci = pl.program_id(2)
 
     @pl.when(ci == 0)
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    x = x_ref[0].astype(jnp.float32)               # (cl, H, P)
-    dt = dt_ref[0].astype(jnp.float32)             # (cl, H)
-    A = a_ref[...].astype(jnp.float32)             # (H,)
-    bm = b_ref[0].astype(jnp.float32)              # (cl, N)
-    cm = c_ref[0].astype(jnp.float32)              # (cl, N)
+    a = a_ref[hi]                                   # this head's A (SMEM)
+    x = x_ref[0, 0].astype(jnp.float32)             # (cl, P)
+    dt_col = dtc_ref[0, 0].astype(jnp.float32)      # (cl, 1)
+    dt_row = dtr_ref[0, 0, pl.ds(ci, 1), :].astype(jnp.float32)   # (1, cl)
+    bm = b_ref[0].astype(jnp.float32)               # (cl, N)
+    cm = c_ref[0].astype(jnp.float32)               # (cl, N)
 
-    dA = dt * A[None, :]                           # (cl, H)
-    cs = jnp.cumsum(dA, axis=0)                    # (cl, H)
-    # intra-chunk: scores (cl, cl), decay L per head
-    scores = jnp.dot(cm, bm.T, preferred_element_type=jnp.float32)  # (cl, cl)
-    diff = cs[:, None, :] - cs[None, :, :]         # (i, j, H)
-    tri = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(tri[:, :, None], jnp.exp(diff), 0.0)              # (i, j, H)
-    gated = scores[:, :, None] * L                                  # (i, j, H)
-    xdt = x * dt[:, :, None]                                        # (j, H, P)
-    y_intra = jnp.einsum("ijh,jhp->ihp", gated, xdt)
-    # inter-chunk: apply carried state
-    h_in = h_ref[...]                                               # (H, P, N)
-    y_inter = jnp.einsum("in,hpn->ihp", cm, h_in) * jnp.exp(cs)[:, :, None]
-    y_ref[0] = (y_intra + y_inter).astype(y_ref.dtype)
-    # state update
-    decay_to_end = jnp.exp(cs[-1:, :] - cs)                         # (j, H)
-    new_state = jnp.einsum("jn,jhp->hpn", bm, xdt * decay_to_end[:, :, None])
-    h_ref[...] = h_in * jnp.exp(cs[-1])[:, None, None] + new_state
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tri = row >= col                                # (i, j): j <= i
+    # inclusive prefix sums of dA, as a column and as a row (Mosaic has no
+    # cumsum lowering; a masked reduction over the (cl, cl) tile is exact)
+    cs_col = jnp.where(tri, dt_row * a, 0.0).sum(axis=1, keepdims=True)
+    cs_row = jnp.where(row <= col, dt_col * a, 0.0).sum(axis=0, keepdims=True)
+    total = (dt_row * a).sum(axis=1, keepdims=True)                 # (1, 1)
+
+    # intra-chunk: (C B^T ∘ L) (dt x)
+    scores = jax.lax.dot_general(cm, bm, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)  # (cl, cl)
+    decay = jnp.where(tri, jnp.exp(cs_col - cs_row), 0.0)
+    xdt = x * dt_col                                                  # (cl, P)
+    y = jnp.dot(scores * decay, xdt, preferred_element_type=jnp.float32)
+    # inter-chunk: C h_in^T, decayed from the chunk start
+    h_in = h_ref[...]                                                 # (P, N)
+    y = y + jax.lax.dot_general(cm, h_in, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * \
+        jnp.exp(cs_col)
+    y_ref[0, 0] = y.astype(y_ref.dtype)
+    # state update: h_out = h_in exp(sum dA) + (dt x decay-to-end)^T B
+    tail = xdt * jnp.exp(total - cs_col)                              # (cl, P)
+    h_ref[...] = h_in * jnp.exp(total) + jax.lax.dot_general(
+        tail, bm, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
 
 def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, bmat: jax.Array,
@@ -69,19 +81,26 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, bmat: jax.Array,
     assert s % chunk == 0
     nc = s // chunk
 
-    kernel = functools.partial(_kernel, nheads=h, hdim=p, dstate=n, chunk=chunk)
-    return pl.pallas_call(
+    xt = x.transpose(0, 2, 1, 3)                    # (B,H,S,P)
+    dtt = dt.transpose(0, 2, 1)                     # (B,H,S)
+    dt_col = dtt[..., None]                         # (B,H,S,1)
+    dt_rows = dtt.reshape(b, h, nc, chunk)          # (B,H,nc,cl)
+    kernel = functools.partial(_kernel, chunk=chunk)
+    out = pl.pallas_call(
         kernel,
-        grid=(b, nc),
+        grid=(b, h, nc),
         in_specs=[
-            pl.BlockSpec((1, chunk, h, p), lambda b_, c_: (b_, c_, 0, 0)),
-            pl.BlockSpec((1, chunk, h), lambda b_, c_: (b_, c_, 0)),
-            pl.BlockSpec((h,), lambda b_, c_: (0,)),
-            pl.BlockSpec((1, chunk, n), lambda b_, c_: (b_, c_, 0)),
-            pl.BlockSpec((1, chunk, n), lambda b_, c_: (b_, c_, 0)),
+            pl.BlockSpec((1, 1, chunk, p), lambda b_, h_, c_: (b_, h_, c_, 0)),
+            pl.BlockSpec((1, 1, chunk, 1), lambda b_, h_, c_: (b_, h_, c_, 0)),
+            pl.BlockSpec((1, 1, nc, chunk), lambda b_, h_, c_: (b_, h_, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, chunk, n), lambda b_, h_, c_: (b_, c_, 0)),
+            pl.BlockSpec((1, chunk, n), lambda b_, h_, c_: (b_, c_, 0)),
         ],
-        out_specs=pl.BlockSpec((1, chunk, h, p), lambda b_, c_: (b_, c_, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, s, h, p), x.dtype),
-        scratch_shapes=[pltpu.VMEM((h, p, n), jnp.float32)],
+        out_specs=pl.BlockSpec((1, 1, chunk, p),
+                               lambda b_, h_, c_: (b_, h_, c_, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, s, p), x.dtype),
+        scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         interpret=interpret,
-    )(x, dt, A, bmat, cmat)
+    )(xt, dt_col, dt_rows, A.astype(jnp.float32), bmat, cmat)
+    return out.transpose(0, 2, 1, 3)

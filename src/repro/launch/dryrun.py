@@ -35,12 +35,8 @@ OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
 
 
 def cost_analysis_dict(obj) -> dict:
-    """Normalize {Lowered,Compiled}.cost_analysis() across jax versions —
-    older releases return one dict per device in a list."""
-    ca = obj.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
+    """{Lowered,Compiled}.cost_analysis(), or {} where XLA gives none."""
+    return obj.cost_analysis() or {}
 
 
 def applicable(arch: str, shape: str) -> bool:

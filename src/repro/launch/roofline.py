@@ -26,10 +26,10 @@ from dataclasses import dataclass
 from typing import Dict, List, Optional
 
 from repro.configs import INPUT_SHAPES, get_config
+from repro.core.devices import chip_peaks
 
-PEAK_FLOPS = 197e12          # bf16 / chip (TPU v5e)
-HBM_BW = 819e9               # bytes/s / chip
-LINK_BW = 50e9               # bytes/s / ICI link
+# the dry-run's production meshes are v5e pods
+_CHIP = chip_peaks("TPU v5 lite")
 
 # wire-traffic weight per collective type (ring algorithms, large N)
 _WIRE_FACTOR = {"all-reduce": 2.0, "all-gather": 1.0, "reduce-scatter": 1.0,
@@ -79,11 +79,11 @@ def analyze_record(rec: dict) -> Optional[RooflineRow]:
         chips *= v
     gflops = rec.get("global_cost", {}).get("flops", 0.0)
     gbytes = rec.get("global_cost", {}).get("bytes_accessed", 0.0)
-    compute_s = gflops / (chips * PEAK_FLOPS)
-    memory_s = gbytes / (chips * HBM_BW)
+    compute_s = gflops / (chips * _CHIP.flops)
+    memory_s = gbytes / (chips * _CHIP.hbm_bw)
     coll = rec.get("collectives", {}).get("bytes", {})
     wire = sum(v * _WIRE_FACTOR.get(k, 1.0) for k, v in coll.items())
-    collective_s = wire / LINK_BW          # bytes already per-chip shards
+    collective_s = wire / _CHIP.link_bw    # bytes already per-chip shards
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     dominant = max(terms, key=terms.get)

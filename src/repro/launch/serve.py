@@ -1,23 +1,38 @@
-"""Pod-scale serving launcher: optimize an ensemble allocation over TPU cells
-and start the inference server.
+"""Serving launcher: optimize an ensemble allocation over device cells and
+start the inference server.
 
-On real hardware, cells are sub-mesh slices (core.devices.tpu_cells); on this
-container the same code path runs with CPU-backed logical devices.
+On a TPU host every chip is one allocation cell (core.devices.tpu_cells) and
+each worker runs on the chip its allocation row names; with
+``JAX_PLATFORMS=cpu`` the same path runs on host CPU cells.
 
-    python -m repro.launch.serve --ensemble ENS4 --cells 2 --port 8600
+    python -m repro.launch.serve --ensemble ENS4 --port 8600
+    python -m repro.launch.serve --ensemble qwen3-1.7b --members 2 \
+        --member-dtype bf16 --seq 128 --combine pallas --bench analytic
 """
 from __future__ import annotations
 
 import argparse
 import time
+from dataclasses import dataclass, field
+from typing import Dict, List, Optional
+
+ALLOC_CACHE = ".repro_alloc_cache.json"
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--ensemble", default="ENS4")
-    ap.add_argument("--members", type=int, default=0)
-    ap.add_argument("--cells", type=int, default=2)
-    ap.add_argument("--cell-mem-gib", type=float, default=4.0)
+    ap.add_argument("--ensemble", default="ENS4",
+                    help="ENS1|ENS4|ENS12, or one architecture name (e.g. "
+                         "qwen3-1.7b) served at its published widths as "
+                         "--members independently initialised members")
+    ap.add_argument("--members", type=int, default=0,
+                    help="first N members of the ensemble; copies of an "
+                         "architecture (default 1)")
+    ap.add_argument("--cells", type=int, default=2,
+                    help="host CPU cells (JAX_PLATFORMS=cpu only; on a TPU "
+                         "host every chip is one cell)")
+    ap.add_argument("--cell-mem-gib", type=float, default=4.0,
+                    help="memory budget of each host CPU cell")
     ap.add_argument("--port", type=int, default=8600)
     ap.add_argument("--segment-size", type=int, default=32)
     ap.add_argument("--seq", type=int, default=16)
@@ -135,58 +150,141 @@ def main(argv=None):
                          "bursts, brownout shifts, exhausted retries — "
                          "freeze tagged dumps at GET /v2/trace?dumps=1; "
                          "0 = off unless --trace-out, default ring 4096)")
-    args = ap.parse_args(argv)
+    return ap
 
+
+def parse_args(argv=None) -> argparse.Namespace:
+    return build_parser().parse_args(argv)
+
+
+def member_configs(name: str, members: int = 0) -> list:
+    """An ensemble by name, or ``members`` copies of one architecture at its
+    published widths (each initialised from its own seed)."""
+    from repro.configs import ARCHITECTURES, ensemble
+    if name in ARCHITECTURES:
+        return [ARCHITECTURES[name]] * max(1, members)
+    cfgs = ensemble(name)
+    return cfgs[:members] if members else cfgs
+
+
+def wanted_platform() -> str:
+    """TPU cells, unless JAX has been restricted to the CPU."""
     import jax
-    import numpy as np
+    return "cpu" if jax.config.jax_platforms == "cpu" else "tpu"
+
+
+def serving_devices(platform: str, cells: int = 2,
+                    cell_mem_gib: float = 4.0) -> list:
+    """Allocation cells: one per TPU chip, or ``cells`` host CPU cells.
+    Asking for TPU cells where JAX finds no TPU is an error."""
+    import jax
+    from repro.core import host_cpus, tpu_cells
+    if platform == "cpu":
+        return host_cpus(cells, memory_bytes=int(cell_mem_gib * 1024 ** 3))
+    chips = [d for d in jax.devices() if d.platform == platform]
+    if not chips:
+        raise RuntimeError(
+            f"asked for {platform} cells, but JAX finds none (devices: "
+            f"{[str(d) for d in jax.devices()]}); set JAX_PLATFORMS=cpu to "
+            f"serve on host CPU cells")
+    return tpu_cells(chips, 1)
+
+
+def init_member_params(cfgs, member_dtypes, seed: int = 0) -> list:
+    """Random weights for each member from ``seed``, made on the host CPU
+    device in the member's storage dtype (bf16 members straight into bf16):
+    workers then copy them to their own chips, and no chip holds a copy
+    that no worker uses."""
+    import jax
+    import jax.numpy as jnp
     import repro.models as M
-    from repro.configs import ensemble
-    from repro.core import (AllocationOptimizer, AnalyticBench, MeasuredBench,
-                            host_cpus, tpu_cells)
+    rng = jax.random.PRNGKey(seed)
+    with jax.default_device(jax.devices("cpu")[0]):
+        return [M.init_params(jax.random.fold_in(rng, i), c,
+                              jnp.bfloat16 if dt == "bf16" else jnp.float32)
+                for i, (c, dt) in enumerate(zip(cfgs, member_dtypes))]
+
+
+@dataclass
+class Serving:
+    """A running server and what it owns; :meth:`close` tears it down."""
+    args: argparse.Namespace
+    system: object
+    httpd: object
+    batcher: object
+    controller: object = None
+    brownout: object = None
+    recorder: object = None
+    setup_s: Dict[str, float] = field(default_factory=dict)
+
+    def close(self) -> None:
+        self.httpd.shutdown()
+        self.httpd.server_close()
+        self.batcher.stop()
+        self.system.shutdown()
+        args = self.args
+        if self.recorder is not None:
+            self.recorder.close()
+            print(f"trace: {len(self.recorder.events())} requests recorded "
+                  f"to {args.record_trace}")
+        if args.trace_out:
+            import json
+            trace = self.system.tracer.export()
+            with open(args.trace_out, "w") as f:
+                json.dump(trace, f)
+            print(f"span timeline: {len(trace['traceEvents'])} events "
+                  f"written to {args.trace_out} (open at "
+                  f"https://ui.perfetto.dev)")
+
+
+def start_serving(args: argparse.Namespace, *,
+                  alloc_cache: Optional[str] = ALLOC_CACHE,
+                  seed: int = 0) -> Serving:
+    """Members, dtypes, devices (:func:`wanted_platform`), planner,
+    :class:`InferenceSystem` and the HTTP server, from parsed CLI ``args``.
+    ``alloc_cache=None`` plans from scratch instead of reading a cached
+    allocation."""
+    import numpy as np
+    from repro.core import AllocationOptimizer, AnalyticBench, MeasuredBench
+    from repro.kernels.quant import validate_member_dtype
     from repro.serving.request_cache import PredictionCache
     from repro.serving.server import serve
     from repro.serving.system import InferenceSystem
 
-    cfgs = ensemble(args.ensemble)
-    if args.members:
-        cfgs = cfgs[: args.members]
-    from repro.kernels.quant import validate_member_dtype
+    setup_s: Dict[str, float] = {}
+    t0 = time.perf_counter()
+    cfgs = member_configs(args.ensemble, args.members)
     dts = [d.strip() for d in args.member_dtype.split(",") if d.strip()]
     if len(dts) == 1:
         dts = dts * len(cfgs)
     if len(dts) != len(cfgs):
-        ap.error(f"--member-dtype expects 1 or {len(cfgs)} values, "
-                 f"got {len(dts)}")
-    member_dtypes = [validate_member_dtype(d) for d in dts]
-    rng = jax.random.PRNGKey(0)
-    params = [M.init_params(jax.random.fold_in(rng, i), c)
-              for i, c in enumerate(cfgs)]
+        raise ValueError(f"--member-dtype expects 1 or {len(cfgs)} values, "
+                         f"got {len(dts)}")
+    member_dtypes: List[str] = [validate_member_dtype(d) for d in dts]
+    devices = serving_devices(wanted_platform(), args.cells,
+                              args.cell_mem_gib)
+    params = init_member_params(cfgs, member_dtypes, seed)
+    setup_s["init"] = time.perf_counter() - t0
 
-    tpus = [d for d in jax.devices() if d.platform == "tpu"]
-    if tpus:
-        devices = tpu_cells(tpus, cell_size=max(1, len(tpus) // args.cells))
-    else:
-        devices = host_cpus(args.cells,
-                            memory_bytes=int(args.cell_mem_gib * 1024 ** 3))
-
-    calib = np.random.default_rng(0).integers(
-        0, cfgs[0].vocab_size, (64, args.seq)).astype(np.int32)
+    t0 = time.perf_counter()
     if args.bench == "measured":
+        calib = np.random.default_rng(seed).integers(
+            0, cfgs[0].vocab_size, (64, args.seq)).astype(np.int32)
         bench = MeasuredBench(cfgs, params, calib,
                               segment_size=args.segment_size)
         opt = AllocationOptimizer(cfgs, devices, bench, max_iter=1,
                                   max_neighs=4, batch_sizes=(8, 16),
-                                  seq=args.seq,
-                                  cache_path=".repro_alloc_cache.json",
+                                  seq=args.seq, cache_path=alloc_cache,
                                   member_dtypes=member_dtypes)
     else:
         bench = AnalyticBench(cfgs, seq=args.seq,
                               member_dtypes=member_dtypes)
         opt = AllocationOptimizer(cfgs, devices, bench, max_iter=10,
                                   max_neighs=100, seq=args.seq,
-                                  cache_path=".repro_alloc_cache.json",
+                                  cache_path=alloc_cache,
                                   member_dtypes=member_dtypes)
     res = opt.optimize()
+    setup_s["plan"] = time.perf_counter() - t0
     print("allocation matrix:\n" + res.matrix.pretty())
     print(f"bench: A1={res.wfd_score:.1f} -> A2={res.final_score:.1f} "
           f"samples/s{' (cached)' if res.from_cache else ''}")
@@ -202,6 +300,7 @@ def main(argv=None):
         budget = AdmissionBudget(
             max_bytes=int(args.admission_budget_mib * 1024 ** 2))
     trace_cap = args.flight_recorder or (4096 if args.trace_out else 0)
+    t0 = time.perf_counter()
     system = InferenceSystem(cfgs, params, res.matrix,
                              segment_size=args.segment_size,
                              max_seq=args.seq, combine=args.combine,
@@ -218,6 +317,7 @@ def main(argv=None):
                              trace_capacity=trace_cap or 4096,
                              member_dtypes=member_dtypes,
                              dispatch_queue=args.dispatch_queue)
+    setup_s["system"] = time.perf_counter() - t0
     if any(d != "fp32" for d in member_dtypes):
         print(f"member dtypes: {','.join(member_dtypes)} (quantized members "
               f"run the fused dequant-combine epilogue)")
@@ -268,6 +368,15 @@ def main(argv=None):
     print(f"serving {len(cfgs)} models / {len(system.workers)} workers on "
           f"http://127.0.0.1:{args.port}  (POST /v2/predict with priority/"
           f"deadline_ms/members, GET /metrics; POST /predict = v1 shim)")
+    return Serving(args, system, httpd, batcher, controller, brownout,
+                   recorder, setup_s)
+
+
+def main(argv=None):
+    from repro.compile_cache import enable_compile_cache
+    args = parse_args(argv)
+    print(f"compile cache: {enable_compile_cache()}")
+    srv = start_serving(args)
     try:
         if args.duration:
             time.sleep(args.duration)
@@ -277,21 +386,7 @@ def main(argv=None):
     except KeyboardInterrupt:
         pass
     finally:
-        httpd.shutdown()
-        batcher.stop()
-        system.shutdown()
-        if recorder is not None:
-            recorder.close()
-            print(f"trace: {len(recorder.events())} requests recorded to "
-                  f"{args.record_trace}")
-        if args.trace_out:
-            import json
-            trace = system.tracer.export()
-            with open(args.trace_out, "w") as f:
-                json.dump(trace, f)
-            print(f"span timeline: {len(trace['traceEvents'])} events "
-                  f"written to {args.trace_out} (open at "
-                  f"https://ui.perfetto.dev)")
+        srv.close()
     return 0
 
 

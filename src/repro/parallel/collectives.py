@@ -14,7 +14,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.launch.mesh import batch_axes
@@ -80,9 +79,9 @@ def flash_decode(mesh, q, k_cache, v_cache, k_new, v_new, pos, *,
         out = acc / jnp.maximum(l_tot, 1e-30).transpose(0, 2, 1)[..., None]
         return out.astype(q.dtype), kc, vc
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(rep_spec, cache_spec, cache_spec, rep_spec, rep_spec, P()),
         out_specs=(rep_spec, cache_spec, cache_spec),
-        check_rep=False)
+        check_vma=False)
     return fn(q, k_cache, v_cache, k_new, v_new, pos)

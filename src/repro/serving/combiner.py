@@ -60,8 +60,10 @@ class DeviceCombiner:
     device math itself is dispatched asynchronously)."""
 
     def __init__(self, name: str, prediction_queue: "queue.Queue[Message]",
-                 timers: Optional[StageTimers] = None, tracer=None):
+                 timers: Optional[StageTimers] = None, tracer=None,
+                 device=None):
         self.name = name
+        self.device = device   # the jax.Device its workers run on, if any
         self.prediction_queue = prediction_queue
         self.timers = timers
         self.tracer = tracer
@@ -212,12 +214,11 @@ class DeviceCombiner:
         # mean / weighted (and pallas with host arrays from fake workers)
         return P * np.float32(w)
 
-    @staticmethod
-    def _fold(req: Request, acc, contrib, w: float, s: int, row_lo: int):
+    def _fold(self, req: Request, acc, contrib, w: float, s: int, row_lo: int):
         """Fold a span contribution into the full-segment partial at its row
         offset.  The partial is allocated once per (request, segment) at the
         segment's full row count, host- or device-side matching the first
-        contribution."""
+        contribution (device-side on this combiner's own device)."""
         lo, hi = req.bounds(s)
         seg_rows = hi - lo
         quant = isinstance(contrib, tuple)     # (q, per-row scale) pair
@@ -230,22 +231,22 @@ class DeviceCombiner:
             return acc
         import jax.numpy as jnp
         if acc is None:
-            acc = jnp.zeros((seg_rows, req.num_classes), jnp.float32)
+            acc = jnp.zeros((seg_rows, req.num_classes), jnp.float32,
+                            device=self.device)
         if req.combine == "pallas":
             from repro.kernels import ops as kops
+            w = jnp.full((1,), w, jnp.float32, device=self.device)
             if quant:
                 # fused dequant-weight-accumulate epilogue: q stays in its
                 # narrow storage dtype all the way into the kernel
                 q, scale = contrib
                 upd = kops.ensemble_accumulate_quant(
-                    acc[a:b], q[None], scale.reshape(1, -1),
-                    jnp.full((1,), w, jnp.float32))
+                    acc[a:b], q[None], scale.reshape(1, -1), w)
             else:
                 # the accumulate-into-partial Pallas kernel variant, on the
                 # span
                 upd = kops.ensemble_accumulate(
-                    acc[a:b], contrib[None].astype(jnp.float32),
-                    jnp.full((1,), w, jnp.float32))
+                    acc[a:b], contrib[None].astype(jnp.float32), w)
             return acc.at[a:b].set(upd) if (a, b) != (0, seg_rows) else upd
         return acc.at[a:b].add(contrib) if (a, b) != (0, seg_rows) \
             else acc + contrib

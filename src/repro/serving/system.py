@@ -176,9 +176,7 @@ class InferenceSystem:
         self._instances: Dict[int, List[Worker]] = {m: [] for m in range(self.M)}
         for d, m, batch in alloc.workers():
             if device_combine and d not in self.combiners:
-                self.combiners[d] = DeviceCombiner(
-                    f"d{d}", self.prediction_queue, timers=self.timers,
-                    tracer=self.tracer)
+                self.combiners[d] = self._make_combiner(d)
             w = self._make_worker(d, m, batch, generation=0)
             self.workers.append(w)
             self._instances[m].append(w)
@@ -201,6 +199,14 @@ class InferenceSystem:
             self.supervisor.start()
 
     # ---- live topology (online reconfiguration, DESIGN.md §8) ----------------
+    def _make_combiner(self, d: int) -> DeviceCombiner:
+        """The device-resident partial combiner of cell ``d``, allocating
+        its partials on the cell's own chip."""
+        backing = self.alloc.devices[d].jax_devices
+        return DeviceCombiner(f"d{d}", self.prediction_queue,
+                              timers=self.timers, tracer=self.tracer,
+                              device=backing[0] if backing else None)
+
     def _make_worker(self, d: int, m: int, batch: int, *,
                      generation: int, oom_sentinel: bool = True) -> Worker:
         """Construct (and warm up) one worker; does NOT register it for
@@ -259,9 +265,7 @@ class InferenceSystem:
             # (_make_worker and _on_request_complete read self.combiners)
             with self._submit_lock:
                 if d not in self.combiners:
-                    self.combiners[d] = DeviceCombiner(
-                        f"d{d}", self.prediction_queue, timers=self.timers,
-                        tracer=self.tracer)
+                    self.combiners[d] = self._make_combiner(d)
         # warm-up compile outside the routing lock: submission stays live
         w = self._make_worker(d, m, batch_size, generation=gen,
                               oom_sentinel=False)

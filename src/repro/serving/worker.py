@@ -29,8 +29,7 @@ requests/segments into full compiled batches:
   * a flushed slot is cut into full compiled batches plus a short remainder
     padded to the next **power-of-two bucket** (not the full compiled batch)
     — one jitted callable serves every bucket, with jit's shape cache
-    bounding compilations to ~log2(batch) entries, and input buffers are
-    donated on accelerators so XLA can reuse them;
+    bounding compilations to ~log2(batch) entries;
   * ``coalesce=False`` restores the PR-1 one-item-at-a-time batching (each
     (request, segment) flushes its own slot) as a measurement baseline;
   * slots come from a **preallocated ring** (free-list backpressure bounds
@@ -131,9 +130,39 @@ HEALTH_DEGRADED = 1     # a stage has been mid-work past the watchdog
 HEALTH_DEAD = 2         # a stage thread died (crashed event / not alive)
 # heartbeat states: a stage blocked on an empty queue is WAITing (healthy
 # at any age — idleness is not a stall); only an ACTIVE stamp going stale
-# means the stage is stuck mid-work
+# means the stage is stuck mid-work.  A stage inside an XLA backend compile
+# is COMPILING: making progress however long it takes (a full-width model's
+# first batch shape, or a first-use combine path, can outlast the watchdog)
 _HB_WAIT = 0
 _HB_ACTIVE = 1
+_HB_COMPILING = 2
+
+# JAX compiles on the calling thread and reports each phase's end: lowering
+# ends just before the backend compile starts.  Stage threads register their
+# heartbeat here, so the listener can mark the compile window on them.
+_LOWERED = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_COMPILED = "/jax/core/compile/backend_compile_duration"
+_stage_hb: Dict[int, list] = {}          # stage thread ident -> heartbeat
+_watching = False                        # listener registered (once)
+
+
+def _on_compile_phase(event: str, duration_secs: float, **_) -> None:
+    hb = _stage_hb.get(threading.get_ident())
+    if hb is None:
+        return
+    if event == _LOWERED and hb[0] == _HB_ACTIVE:
+        hb[:] = [_HB_COMPILING, time.perf_counter()]
+    elif event == _COMPILED and hb[0] == _HB_COMPILING:
+        hb[:] = [_HB_ACTIVE, time.perf_counter()]
+
+
+def _watch_compiles() -> None:
+    """Register :func:`_on_compile_phase` with JAX's monitoring, once."""
+    global _watching
+    if not _watching:
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_compile_phase)
+        _watching = True
 
 
 def bucket_for(n: int, batch_size: int) -> int:
@@ -145,12 +174,11 @@ def bucket_for(n: int, batch_size: int) -> int:
 
 
 def make_predict_fn(cfg: ModelConfig, use_kernel: bool = False,
-                    donate: bool = False, member_dtype: str = "fp32",
+                    member_dtype: str = "fp32",
                     quant_out: bool = False) -> Callable:
     """Classification-style serving fn: tokens (b,S) -> last-token class
-    scores (b, C) with C = the unpadded vocab (the paper's f(x)->y).
-    ``donate`` hands the token buffer to XLA for reuse (accelerators only —
-    CPU ignores donation and would warn on every compile).
+    scores (b, C) with C = the unpadded vocab (the paper's f(x)->y).  The
+    int32 token buffer is not donated: no output could reuse it.
 
     ``member_dtype`` != "fp32" expects params wrapped by
     :func:`repro.kernels.quant.quantize_params` — dequantization runs inside
@@ -172,7 +200,7 @@ def make_predict_fn(cfg: ModelConfig, use_kernel: bool = False,
             return kquant.quantize_symmetric(out, axis=-1)
         return out
 
-    return jax.jit(predict, donate_argnums=(1,) if donate else ())
+    return jax.jit(predict)
 
 
 def _span_rids(spans):
@@ -267,6 +295,13 @@ class Worker:
         # only released once the sender materializes a chunk)
         self._send_q: "queue.SimpleQueue" = queue.SimpleQueue()
         self._threads: List[threading.Thread] = []
+        if len(device.jax_devices) > 1 and not fake:
+            # nothing shards a member across a cell's chips: running on the
+            # first one alone would leave the rest idle and overfill it
+            raise ValueError(
+                f"{worker_id}: cell {device.name} spans "
+                f"{len(device.jax_devices)} chips; a worker runs on one chip "
+                f"(build cells with tpu_cells(devices, 1))")
         self._jax_device = device.jax_devices[0] if device.jax_devices else None
 
         # ---- fault tolerance (DESIGN.md §10) ----
@@ -327,13 +362,12 @@ class Worker:
                 fe = frontend if frontend is not None else np.zeros(
                     (batch_size, cfg.frontend_tokens, cfg.fdim), np.float32)
                 self.frontend = jnp.asarray(fe)
-            donate = jax.default_backend() in ("gpu", "tpu")
             # quantized members feeding a device combiner emit (q, scale)
             # logits for the fused dequant-weight-accumulate epilogue
             self._quant_out = (kquant.is_quantized_dtype(self.member_dtype)
                                and combiner is not None)
             self.predict_fn = make_predict_fn(
-                cfg, use_kernel, donate=donate,
+                cfg, use_kernel,
                 member_dtype=self.member_dtype, quant_out=self._quant_out)
             if not fake:   # warm-up compile so READY means actually servable
                 warm = jnp.zeros((batch_size, max_seq), jnp.int32)
@@ -351,14 +385,15 @@ class Worker:
 
     # ---- threads -------------------------------------------------------------
     def start(self):
+        _watch_compiles()
         for fn, name in [(self._batcher, "batcher"), (self._predictor, "predictor"),
                          (self._sender, "sender")]:
-            t = threading.Thread(target=self._guarded, args=(fn,),
+            t = threading.Thread(target=self._guarded, args=(fn, name),
                                  name=f"{self.worker_id}-{name}", daemon=True)
             t.start()
             self._threads.append(t)
 
-    def _guarded(self, fn):
+    def _guarded(self, fn, stage: str):
         """A stage thread dying mid-request would hang its request (and leak
         its in-flight window slot) forever.  Under supervision (``on_crash``
         set) the failure is *contained*: the supervisor quarantines this one
@@ -366,6 +401,7 @@ class Worker:
         Unsupervised, fall back to the paper's {-1, None, None} sentinel,
         which fails every in-flight request and shuts the system down
         (§II.C.2 all-or-nothing semantics, still the default)."""
+        _stage_hb[threading.get_ident()] = self._hb[stage]
         try:
             fn()
         except BaseException as e:
@@ -381,6 +417,8 @@ class Worker:
             if self._oom_sentinel:
                 self.prediction_queue.put(Message(seg.OOM, None, None))
             raise
+        finally:
+            _stage_hb.pop(threading.get_ident(), None)
 
     def join(self, timeout: float = 30.0) -> List[str]:
         """Join all stage threads against ONE shared deadline (the seed gave
@@ -403,8 +441,9 @@ class Worker:
         """Liveness verdict for the supervisor: DEAD when a stage thread
         crashed or exited; DEGRADED when a stage has been ACTIVE (mid-work,
         not blocked on an empty queue) longer than ``watchdog_s``; READY
-        otherwise.  WAIT-state stamps never age into DEGRADED — an idle
-        worker is healthy."""
+        otherwise.  WAIT- and COMPILING-state stamps never age into
+        DEGRADED — an idle worker is healthy, and a compiling one is making
+        progress."""
         if self.crashed.is_set():
             return HEALTH_DEAD
         if self._threads and not all(t.is_alive() for t in self._threads):

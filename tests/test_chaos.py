@@ -16,6 +16,7 @@ exercised at a reproducible pipeline position:
     budgets bound replay, NaN outputs crash their worker instead of
     folding into Y.
 """
+import threading
 import time
 
 import jax
@@ -386,3 +387,30 @@ def test_unsupervised_keeps_paper_semantics(ens2):
             h.result(30.0)
     finally:
         s.shutdown()
+
+
+def test_compiling_stage_is_not_a_stall():
+    """A stage thread inside an XLA compile is making progress: its ACTIVE
+    heartbeat turns COMPILING for the compile and ages into no stall; the
+    stage is ACTIVE again, freshly stamped, once the compile ends."""
+    from repro.serving import worker as wk
+    seen = []
+    hb = [wk._HB_ACTIVE, time.perf_counter() - 60.0]   # stale: would stall
+    me = threading.get_ident()
+    wk._stage_hb[me] = hb
+
+    def spy(event, duration_secs, **_):
+        if event == wk._LOWERED:
+            seen.append(hb[0])
+
+    wk._watch_compiles()
+    jax.monitoring.register_event_duration_secs_listener(spy)
+    try:
+        # a shape this process has never compiled, on this thread
+        jax.jit(lambda x: x * 3 + 1)(np.zeros((3, 7, 11), np.float32))
+    finally:
+        del wk._stage_hb[me]
+        jax.monitoring.unregister_event_duration_listener(spy)
+    assert wk._HB_COMPILING in seen
+    assert hb[0] == wk._HB_ACTIVE
+    assert time.perf_counter() - hb[1] < 30.0
