@@ -351,46 +351,46 @@ class PredictionAccumulator:
         return self._expected_ready_count or 1
 
     def _accumulate(self, msg: Message):
-        t0 = time.perf_counter()
-        with self._lock:
-            handle = self._requests.get(msg.rid)
-        if handle is None:                    # stale (timed-out/failed request)
-            return
-        req = handle.req
-        if req.expired():                     # deadline enforcement (§7)
-            self._finish(handle, DeadlineExceeded(
-                f"request {req.rid} missed its deadline mid-flight"))
-            return
-        lo, hi = req.bounds(msg.s)
-        self.data_messages += 1
-        handle.messages += 1
-        if msg.m is None:
-            # device partial: weights already applied on-device; the combiner
-            # flushes full segments, so this debits count x segment rows
-            handle.Y[lo:hi] += msg.P
-            rows = msg.count * (hi - lo)
-        else:
-            self._fold_member(handle, msg, lo, hi)
-            rows = int(msg.P.shape[0])
-        handle.remaining -= rows
-        if handle._seg_remaining is not None:
-            left = handle._seg_remaining[msg.s] - rows
-            handle._seg_remaining[msg.s] = left
-            if left == 0:                     # streaming partial: segment done
-                try:
-                    handle.on_segment(msg.s, lo, hi, handle.Y[lo:hi])
-                except Exception as e:
-                    # a raising client callback fails the request (through
-                    # the idempotent finish — never by assigning error
-                    # outside the lock) but must not kill this loop
-                    self._finish(handle, e)
-                    return
-        t1 = time.perf_counter()
-        self.timers.add("accumulate", t1 - t0)
+        with self.timers.stage("accumulate") as fold:
+            with self._lock:
+                handle = self._requests.get(msg.rid)
+            if handle is None:            # stale (timed-out/failed request)
+                return
+            req = handle.req
+            if req.expired():             # deadline enforcement (§7)
+                self._finish(handle, DeadlineExceeded(
+                    f"request {req.rid} missed its deadline mid-flight"))
+                return
+            lo, hi = req.bounds(msg.s)
+            self.data_messages += 1
+            handle.messages += 1
+            if msg.m is None:
+                # device partial: weights already applied on-device; the
+                # combiner flushes full segments, so this debits count x
+                # segment rows
+                handle.Y[lo:hi] += msg.P
+                rows = msg.count * (hi - lo)
+            else:
+                self._fold_member(handle, msg, lo, hi)
+                rows = int(msg.P.shape[0])
+            handle.remaining -= rows
+            if handle._seg_remaining is not None:
+                left = handle._seg_remaining[msg.s] - rows
+                handle._seg_remaining[msg.s] = left
+                if left == 0:             # streaming partial: segment done
+                    try:
+                        handle.on_segment(msg.s, lo, hi, handle.Y[lo:hi])
+                    except Exception as e:
+                        # a raising client callback fails the request
+                        # (through the idempotent finish -- never by
+                        # assigning error outside the lock) but must not
+                        # kill this loop
+                        self._finish(handle, e)
+                        return
         tr = self.tracer
         if tr is not None and tr.enabled:
             self._tr_ring.append(
-                ("X", "accumulate", t0, t1 - t0, msg.rid,
+                ("X", "accumulate", fold.t0, fold.t1 - fold.t0, msg.rid,
                  msg.s, rows, None))
         if handle.remaining == 0:
             self._complete(handle)

@@ -37,9 +37,9 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 from typing import Dict, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.serving.metrics import StageTimers
@@ -65,7 +65,7 @@ class DeviceCombiner:
         self.name = name
         self.device = device   # the jax.Device its workers run on, if any
         self.prediction_queue = prediction_queue
-        self.timers = timers
+        self.timers = timers if timers is not None else StageTimers()
         self.tracer = tracer
         self._tr_track = f"combine.{name}"
         # ring cached once: rings are cleared in place, never replaced
@@ -147,45 +147,52 @@ class DeviceCombiner:
         ``s`` into the device partial; post the partial once the segment's
         expected row count is reached.  ``P`` may be a numpy array (fake
         workers) or a device array — device arrays stay resident until the
-        single flush transfer."""
-        t0 = time.perf_counter()
+        single flush transfer.  The ``combine`` stage times the fold; the
+        flush is :meth:`_post`."""
         flush = None
         # quantized members forward (q, per-row scale) tuples
         nrows = int(P[0].shape[0]) if isinstance(P, tuple) else int(P.shape[0])
-        # the heavy elementwise math runs outside the lock; only the
-        # accumulate + bookkeeping is serialized
-        contrib = self._contribution(req, P, req.weights[m])
-        with self._lock:
-            expected = self._expected.get(req.rid)
-            if expected is None or s not in expected:   # request torn down
-                return
-            part = self._parts.setdefault((req.rid, s), _SegPartial())
-            part.acc = self._fold(req, part.acc, contrib, req.weights[m],
-                                  s, row_lo)
-            part.rows += nrows
-            count, want_rows = expected[s]
-            if part.rows >= want_rows:
-                flush = (part, count)
-                del self._parts[(req.rid, s)]
-                del expected[s]
-                if not expected:
-                    del self._expected[req.rid]
+        with self.timers.stage("combine") as fold:
+            # the heavy elementwise math runs outside the lock; only the
+            # accumulate + bookkeeping is serialized
+            contrib = self._contribution(req, P, req.weights[m])
+            with self._lock:
+                expected = self._expected.get(req.rid)
+                if expected is None or s not in expected:   # torn down
+                    return
+                part = self._parts.setdefault((req.rid, s), _SegPartial())
+                part.acc = self._fold(req, part.acc, contrib,
+                                      req.weights[m], s, row_lo)
+                part.rows += nrows
+                count, want_rows = expected[s]
+                if part.rows >= want_rows:
+                    flush = (part, count)
+                    del self._parts[(req.rid, s)]
+                    del expected[s]
+                    if not expected:
+                        del self._expected[req.rid]
+        t1 = fold.t1
         if flush is not None:
-            self._post(req.rid, s, *flush)
-        t1 = time.perf_counter()
-        if self.timers is not None:
-            self.timers.add("combine", t1 - t0)
+            t1 = self._post(req.rid, s, *flush)
         tr = self.tracer
         if tr is not None and tr.enabled:
             self._tr_ring.append(
-                ("X", "combine", t0, t1 - t0, req.rid,
+                ("X", "combine", fold.t0, t1 - fold.t0, req.rid,
                  s, m, flush is not None))
 
-    def _post(self, rid: int, s: int, part: _SegPartial, count: int) -> None:
-        """The single device->host transfer per device per segment."""
-        self.prediction_queue.put(Message(
-            s, None, np.asarray(part.acc), rid=rid, count=count))
+    def _post(self, rid: int, s: int, part: _SegPartial, count: int) -> float:
+        """The single device->host copy per device per segment.  The wait
+        for the partial's fold program (which the chip may run behind
+        queued member steps) is the ``combine_wait`` stage, the copy alone
+        ``copy``; returns the clock when the copy ended."""
+        with self.timers.stage("combine_wait"):
+            if not isinstance(part.acc, np.ndarray):   # fake workers: host
+                jax.block_until_ready(part.acc)
+        with self.timers.stage("copy") as copy:
+            acc = np.asarray(part.acc)
+        self.prediction_queue.put(Message(s, None, acc, rid=rid, count=count))
         self.partials_posted += 1
+        return copy.t1
 
     @staticmethod
     def _contribution(req: Request, P, w: float):

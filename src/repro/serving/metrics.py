@@ -1,15 +1,36 @@
 """Per-stage timing counters + serving gauges for the hot path (DESIGN.md §6).
 
-Stages (one wall-clock accumulator each, shared by all threads):
-  ``batcher_wait``   time a batcher spends blocked on its input queue,
-  ``batch_fill``     copying request rows into coalesced batch slots,
+Stages (one wall-clock accumulator each, shared by all threads).  A site
+times one count of a stage with ``with timers.stage(name):`` (or
+``stage(name).start()`` ... ``.stop()`` where the work outlives one block),
+which also records the profiler span ``serving.<name>`` while a
+``jax.profiler`` session runs (tracing.open_span), so both sinks share one
+name and one clock:
+  ``submit``         one ``predict_async``: admission, buffer take,
+                     striping, enqueue (``inflight_wait`` included),
+  ``inflight_wait``  one request's wait for a slot of the in-flight window,
+  ``slot_wait``      one bulk batch opening's wait for a free ring slot,
+  ``linger``         one batch, from its opening to its flush,
+  ``predict``        one predictor round, pop to last dispatch (async --
+                     excludes device time),
+  ``device_wait``    the sender's wait for one dispatched chunk's output,
+  ``copy``           one device->host copy of answers (a chunk's on the
+                     host-combine path, a device partial's when posted),
+  ``combine``        one member contribution's fold into a device partial,
+  ``combine_wait``   the wait for one device partial's fold program before
+                     its copy,
+  ``accumulate``     one accumulator fold.
+Timer only (:meth:`StageTimers.add`), with no span:
+  ``batch_fill``     packing one descriptor's rows into a batch slot
+                     (``slot_wait`` included),
   ``dispatch_wait.high`` / ``dispatch_wait.normal``
                      per-class time a chunk waits in the priority dispatch
                      queue between batcher and predictor (the preemption
                      lever: high should stay near zero under bulk load),
-  ``predict``        jitted-step dispatch (async — excludes device time),
-  ``transfer``       device sync + device->host fetch in the sender,
-  ``combine``        device-partial / accumulator fold time.
+  ``send_wait``      one dispatched group's wait in the sender's queue,
+                     from the predictor's hand-off to the sender's pick-up
+                     (it crosses threads, so no span: the sender holds its
+                     own spans over it).
 
 Counters (monotonic sums) instrument the coalescing scheduler:
   ``rows_valid``       request rows dispatched to the device,
@@ -47,7 +68,7 @@ import time
 from collections import defaultdict
 from typing import Dict, List, Optional
 
-LATENCY_WINDOW = 512      # retained for callers; histograms are unbounded
+from repro.serving.tracing import close_span, open_span
 
 # log-spaced latency bucket upper bounds (seconds): 1e-4 * sqrt(2)^i.
 # 42 finite buckets span 100µs .. ~148s; one overflow bucket above.
@@ -77,6 +98,33 @@ def _hist_percentile(counts: List[int], n: int, q: float) -> float:
     return LATENCY_BOUNDS_S[-1]
 
 
+class Stage:
+    """One count of a stage on both sinks: the timer and, while a profiler
+    session runs, the span ``serving.<name>``.  ``t0`` / ``t1`` hold the
+    clock when it started and stopped."""
+    __slots__ = ("_timers", "name", "_span", "t0", "t1")
+
+    def __init__(self, timers: "StageTimers", name: str):
+        self._timers = timers
+        self.name = name
+
+    def start(self) -> "Stage":
+        self._span = open_span(self.name)
+        self.t0 = time.perf_counter()
+        return self
+
+    def stop(self) -> float:
+        self.t1 = time.perf_counter()
+        self._timers.add(self.name, self.t1 - self.t0)
+        close_span(self._span)
+        return self.t1
+
+    __enter__ = start
+
+    def __exit__(self, *exc) -> None:
+        self.stop()
+
+
 class StageTimers:
     def __init__(self):
         self.total_s: Dict[str, float] = defaultdict(float)
@@ -95,6 +143,11 @@ class StageTimers:
     def add(self, stage: str, dt: float) -> None:
         self.total_s[stage] += dt
         self.count[stage] += 1
+
+    def stage(self, name: str) -> Stage:
+        """``with timers.stage(name):`` times one count of ``name`` on both
+        sinks (:class:`Stage`)."""
+        return Stage(self, name)
 
     def timed(self, stage: str, t0: float) -> float:
         """Record ``now - t0`` under ``stage``; returns now (chains stages)."""

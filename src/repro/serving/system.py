@@ -612,15 +612,15 @@ class InferenceSystem:
             else deadline - time.perf_counter()
         # bounded in-flight window; a deadline bounds the wait for a slot,
         # and an already-expired request fails fast without enqueuing work
-        if remaining is not None and (
-                remaining <= 0 or
-                not self._inflight.acquire(timeout=remaining)):
+        admitted = False
+        if remaining is None or remaining > 0:
+            with self.timers.stage("inflight_wait"):
+                admitted = self._inflight.acquire(timeout=remaining)
+        if not admitted:
             self._credit_admission(charge)
             return self._resolved_handle(X, 0, members, combine,
                                          DeadlineExceeded(
                                              "deadline expired at admission"))
-        if remaining is None:
-            self._inflight.acquire()
         try:
             handle = self._submit(X, n, width, members, combine, opts,
                                   deadline, tier_quality=tier_quality,
@@ -742,7 +742,8 @@ class InferenceSystem:
         ``options.members`` when both are given."""
         if self._shutdown:
             raise RuntimeError("system is shut down")
-        return self._broadcast(np.asarray(X, np.int32), members, options)
+        with self.timers.stage("submit"):
+            return self._broadcast(np.asarray(X, np.int32), members, options)
 
     def predict(self, X: np.ndarray, timeout: float = 600.0,
                 members=None,
@@ -806,8 +807,10 @@ class InferenceSystem:
                    for b in barriers)
 
     def stage_timings(self) -> Dict[str, Dict[str, float]]:
-        """Per-stage wall-clock counters (batcher wait / fill / predict /
-        transfer / combine / accumulate) since construction or reset."""
+        """Per-stage wall-clock counters (DESIGN.md §6: submit / in-flight
+        wait / slot wait / linger / fill / dispatch wait / predict / send
+        wait / device wait / copy / combine / combine wait / accumulate)
+        since construction or reset."""
         return self.timers.snapshot()
 
     def serving_counters(self) -> Dict[str, float]:

@@ -30,6 +30,17 @@ microseconds) — load it at https://ui.perfetto.dev or chrome://tracing.
 list tagged with its trigger (watchdog stall, deadline-miss burst,
 brownout level change, RetriesExhausted), so the window of spans *leading
 up to* a fault survives even after the ring wraps.
+
+Beside the rings, :func:`open_span` / :func:`close_span` put the
+pipeline's stages on ``jax.profiler``'s clock as host spans named
+``serving.<stage>``, so a device trace shows which stage held the host
+while the chip was idle.  Sites do not call them directly: each stage is
+timed through ``StageTimers.stage`` (metrics.py, DESIGN.md §6), which
+feeds the stage's timer and its span from one clock.  Spans record only
+while a profiler session runs; with none, a site pays one
+``is_enabled()`` check.  Each span starts and ends on one thread, and a
+thread waiting for input with no work in hand holds none (a batcher
+lingering over an open batch holds ``serving.linger``).
 """
 from __future__ import annotations
 
@@ -39,7 +50,10 @@ import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
-__all__ = ["FlightRecorder", "Tracer", "pack_times"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["FlightRecorder", "Tracer", "pack_times", "open_span",
+           "close_span"]
 
 # Emitted events are 8 flat fields: (ph, name, t0_s, dur_s, rid, a, b, c)
 #   ph    "X" complete span | "i" instant | "G"/"g" grouped records
@@ -459,3 +473,23 @@ class Tracer:
                 "anomalies": list(self._anomalies),
             },
         }
+
+
+# ---- profiler spans ---------------------------------------------------------
+SPAN_PREFIX = "serving."
+_profiling = TraceAnnotation.is_enabled
+
+
+def open_span(stage: str) -> Optional[TraceAnnotation]:
+    """Start ``serving.<stage>`` now; None when no profiler session runs.
+    The caller ends it with :func:`close_span` on the same thread."""
+    if not _profiling():
+        return None
+    span = TraceAnnotation(SPAN_PREFIX + stage)   # takes the start time
+    span.__enter__()
+    return span
+
+
+def close_span(span: Optional[TraceAnnotation]) -> None:
+    if span is not None:
+        span.__exit__(None, None, None)

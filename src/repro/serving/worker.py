@@ -74,11 +74,12 @@ priority :class:`~repro.serving.admission.DispatchQueue` as an independent
     per-span forwarding would multiply combiner/accumulator traffic by
     chunks-per-segment;
   * per-stage wall-clock counters (metrics.StageTimers) instrument the
-    batcher wait, batch fill, per-class dispatch-queue wait
-    (``dispatch_wait.high`` / ``dispatch_wait.normal``), predict dispatch,
-    and device sync/transfer; padding counters (``rows_valid`` /
-    ``rows_dispatched``) and the ``queue_depth`` gauge expose coalescing
-    efficiency.
+    wait for a ring slot, each batch's linger, batch fill, per-class
+    dispatch-queue wait (``dispatch_wait.high`` / ``dispatch_wait.normal``),
+    predict dispatch, a group's wait in the sender's queue, and the
+    sender's device wait and device-to-host copy; padding counters
+    (``rows_valid`` / ``rows_dispatched``) and the ``queue_depth`` gauge
+    expose coalescing efficiency.
 
 Request-API admission (DESIGN.md §7): the input queue is a two-level
 :class:`~repro.serving.admission.AdmissionQueue` — high-priority descriptors
@@ -110,7 +111,7 @@ from repro.kernels.ops import pow2_clamp
 from repro.serving import segments as seg
 from repro.serving.admission import DispatchQueue, chunk_level
 from repro.serving.faults import FaultPlan
-from repro.serving.metrics import StageTimers
+from repro.serving.metrics import Stage, StageTimers
 from repro.serving.tracing import pack_times
 from repro.serving.segments import (FLUSH, ChunkDesc, FlushBarrier, Message,
                                     Request, SHUTDOWN, SlotRef, Span)
@@ -213,15 +214,18 @@ def _span_rids(spans):
 
 class _OpenBatch:
     """The batcher's in-progress coalesced batch."""
-    __slots__ = ("slot", "buf", "width", "fill", "spans", "deadline")
+    __slots__ = ("slot", "buf", "width", "fill", "spans", "deadline",
+                 "linger")
 
-    def __init__(self, slot, buf, width: int, deadline: float):
+    def __init__(self, slot, buf, width: int, linger: Stage,
+                 linger_s: float):
         self.slot = slot             # ring index, or None (side-pool buffer)
         self.buf = buf
         self.width = width
         self.fill = 0
         self.spans: List[Span] = []
-        self.deadline = deadline     # linger expiry (perf_counter seconds)
+        self.linger = linger.start()  # stopped by Worker._flush
+        self.deadline = linger.t0 + linger_s   # linger expiry
 
 
 class Worker:
@@ -491,20 +495,21 @@ class Worker:
                 except queue.Empty:
                     slot = None
             else:
-                while True:
-                    try:
-                        slot = self._free_slots.get(timeout=0.002)
-                        break
-                    except queue.Empty:
-                        if self.input_queue.depth(seg.PRIORITY_HIGH):
-                            return None       # high work first; retry after
+                with self.timers.stage("slot_wait"):
+                    while True:
+                        try:
+                            slot = self._free_slots.get(timeout=0.002)
+                            break
+                        except queue.Empty:
+                            if self.input_queue.depth(seg.PRIORITY_HIGH):
+                                return None   # high work first; retry after
             if slot is not None:
                 buf = self._ring[slot]
         if buf is None:        # side pool: mismatched seq or express overflow
             slot = None
             buf = self._side_buffer(width)
-        return _OpenBatch(slot, buf, width,
-                          time.perf_counter() + self._effective_linger())
+        return _OpenBatch(slot, buf, width, self.timers.stage("linger"),
+                          self._effective_linger())
 
     def _recycle(self, slot: Optional[int], buf: np.ndarray) -> None:
         if slot is not None:
@@ -534,7 +539,8 @@ class Worker:
         priority dispatch queue.  The slot's :class:`SlotRef` refcount
         starts at the chunk count, so the ring buffer recycles only after
         every chunk's output is materialized.  Padding counters make
-        coalescing efficiency observable."""
+        coalescing efficiency observable; the batch's open-to-flush time is
+        the ``linger`` stage."""
         chunks = []                           # (offset, bucket, valid) views
         for off in range(0, batch.fill, self.batch_size):
             valid = min(self.batch_size, batch.fill - off)
@@ -546,6 +552,7 @@ class Worker:
             self.timers.inc("rows_dispatched", bucket)
         self.timers.inc("batches", len(chunks))
         self.timers.inc("spans", len(batch.spans))
+        batch.linger.stop()
         if not chunks:                        # defensive: nothing packed
             self._recycle(batch.slot, batch.buf)
             return
@@ -593,13 +600,13 @@ class Worker:
                     else:
                         item = self.input_queue.get_nowait()
                 except queue.Empty:
-                    t0 = self.timers.timed("batcher_wait", t0)
+                    t0 = time.perf_counter()
                     hb[:] = [_HB_ACTIVE, t0]
                     self._flush(open_batch)   # linger expired
                     open_batch = None
                     self.timers.timed("batch_fill", t0)
                     continue
-            t0 = self.timers.timed("batcher_wait", t0)
+            t0 = time.perf_counter()
             hb[:] = [_HB_ACTIVE, t0]
             self.timers.gauge(self._depth_gauge, self.input_queue.qsize())
             if item == SHUTDOWN:
@@ -764,7 +771,8 @@ class Worker:
             committed = 0
             stop = False
             ctl = False                   # round saw a non-chunk item
-            t0 = time.perf_counter()
+            rnd = self.timers.stage("predict").start()  # pop to last dispatch
+            t0 = rnd.t0
             # double-buffered H2D staging: after committing chunk i, chunk
             # i+1's device_put is issued immediately (device_put is async),
             # so its upload overlaps chunk i's compute instead of
@@ -791,7 +799,7 @@ class Worker:
                     break
                 if isinstance(item, FlushBarrier):
                     if group:         # every earlier chunk is dispatched
-                        self._send_q.put(group)
+                        self._send_q.put((group, time.perf_counter()))
                         group = []
                     item.done.set()
                     ctl = True
@@ -840,12 +848,11 @@ class Worker:
                                 staged = (nxt, _upload(nxt))
                                 break
                 group.append((chunk, y, t0, False))
+            t1 = rnd.stop()
             for _ in range(tokens - committed):   # unused / skipped tokens
                 self._dispatch_sem.release()
             if group:
-                self._send_q.put(group)
-            if committed:
-                t1 = self.timers.timed("predict", t0)
+                self._send_q.put((group, time.perf_counter()))
             if tr is not None and tr.enabled and items:
                 # ONE flat rid-free record per pop round (invisible to
                 # the GC), with ZERO per-chunk work in the loop above:
@@ -894,16 +901,18 @@ class Worker:
         hb = self._hb["sender"]
         while True:
             hb[:] = [_HB_WAIT, time.perf_counter()]
-            batch = self._send_q.get()
-            if batch is None:
+            item = self._send_q.get()
+            if item is None:
                 return
+            batch, t_put = item
             t0 = time.perf_counter()
             hb[:] = [_HB_ACTIVE, t0]
+            self.timers.add("send_wait", t0 - t_put)
             profiled = []                  # (bucket, valid) materialized
             for chunk, y, t_dispatch, skipped in batch:
                 self._send_chunk(chunk, y, skipped, staging, on_device,
                                  profiled)
-            now = self.timers.timed("transfer", t0)   # sync+scatter, group
+            now = time.perf_counter()
             if tr is not None and tr.enabled:
                 # grouped single span: slot a carries the group's shared
                 # dispatch (pop) time — the correlation key export joins
@@ -929,16 +938,19 @@ class Worker:
         if not skipped:
             if self._fault is not None:
                 self._fault.tick(self.worker_id, "sender")
+            # one device wait per dispatched chunk (none to wait for from a
+            # fake predictor or an injected NaN output, both on the host);
+            # on the host-combine path, the device-to-host copy after it
+            on_chip = y is not None and not isinstance(y, np.ndarray)
+            with self.timers.stage("device_wait"):
+                if on_chip:
+                    # (q, scale) tuples from quantized members block as a
+                    # pytree; compute done, arrays stay on device
+                    jax.block_until_ready(y)
+            if on_chip and not on_device:
+                with self.timers.stage("copy"):
+                    y = np.asarray(y)
             if y is not None:
-                if on_device:
-                    if isinstance(y, np.ndarray):    # injected NaN output
-                        pass
-                    else:
-                        # (q, scale) tuples from quantized members block as
-                        # a pytree; compute done, arrays stay on device
-                        jax.block_until_ready(y)
-                else:
-                    y = np.asarray(y)      # d->h sync
                 if self.nan_guard and isinstance(y, np.ndarray) \
                         and np.isnan(y).any():
                     # poisoned output: dying here (WorkerCrashed through

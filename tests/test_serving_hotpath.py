@@ -220,6 +220,6 @@ def test_stage_timings_populated(ens2):
     with make_system(cfgs, params, np.array([[8, 8]]), segment_size=16) as s:
         s.predict(X)
         stages = s.stage_timings()
-    for key in ("batcher_wait", "batch_fill", "predict", "transfer",
-                "combine", "accumulate"):
+    for key in ("inflight_wait", "linger", "batch_fill", "predict",
+                "device_wait", "copy", "combine", "accumulate"):
         assert key in stages and stages[key]["count"] > 0, (key, stages)
