@@ -44,7 +44,7 @@ def pallas_enabled() -> bool:
 
 def pow2_clamp(n: int, lo: int, hi: int) -> int:
     """Next power of two >= n, clamped to [lo, hi] (block-size selection)."""
-    return min(hi, max(lo, 1 << max(n - 1, 1).bit_length()))
+    return min(hi, max(lo, 1 << max(n - 1, 0).bit_length()))
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:

@@ -27,9 +27,9 @@ requests/segments into full compiled batches:
     ``max_wait_us`` for more rows (bounded latency), and ``SHUTDOWN`` /
     ``FLUSH`` (quiesce) force an immediate flush;
   * a flushed slot is cut into full compiled batches plus a short remainder
-    padded to the next **power-of-two bucket** (not the full compiled batch)
-    — one jitted callable serves every bucket, with jit's shape cache
-    bounding compilations to ~log2(batch) entries;
+    padded to the next **power-of-two bucket** (1, 2, 4, ..., batch; not the
+    full compiled batch) — one jitted callable serves every bucket, and the
+    constructor compiles all log2(batch)+1 of them before READY;
   * ``coalesce=False`` restores the PR-1 one-item-at-a-time batching (each
     (request, segment) flushes its own slot) as a measurement baseline;
   * slots come from a **preallocated ring** (free-list backpressure bounds
@@ -116,7 +116,6 @@ from repro.serving.tracing import pack_times
 from repro.serving.segments import (FLUSH, ChunkDesc, FlushBarrier, Message,
                                     Request, SHUTDOWN, SlotRef, Span)
 
-MIN_BUCKET = 8
 RING_SLOTS = 4          # in-flight slot bound per worker
 ALT_POOL_CAP = 4        # pooled mismatched-seq buffers per width
 ADAPTIVE_DEPTH = 8      # linger="adaptive": backlog at which linger hits 0
@@ -167,11 +166,15 @@ def _watch_compiles() -> None:
 
 
 def bucket_for(n: int, batch_size: int) -> int:
-    """Compiled batch shape for an ``n``-row chunk: the full batch size, or
-    the next power of two >= n (min 8) for remainder chunks."""
-    if n >= batch_size:
-        return batch_size
-    return pow2_clamp(n, MIN_BUCKET, batch_size)
+    """Compiled batch shape for an ``n``-row chunk: the next power of two
+    >= n, clamped to ``[1, batch_size]`` (a full chunk runs the batch)."""
+    return pow2_clamp(n, 1, batch_size)
+
+
+def bucket_ladder(batch_size: int) -> List[int]:
+    """Every shape :func:`bucket_for` can give: 1, 2, 4, ..., batch_size."""
+    return sorted({bucket_for(n, batch_size)
+                   for n in range(1, batch_size + 1)})
 
 
 def make_predict_fn(cfg: ModelConfig, use_kernel: bool = False,
@@ -273,6 +276,9 @@ class Worker:
                              f"got {linger!r}")
         self.linger_mode = linger
         self._depth_gauge = f"queue_depth.{worker_id}"
+        # per-bucket chunk counters, named once: _flush is hot
+        self._chunk_counter = {b: f"chunks.b{b}"
+                               for b in bucket_ladder(batch_size)}
         self.num_classes = cfg.vocab_size
         # chunk-granular dispatch: priority queue batcher -> predictor, plus
         # the dispatch-ahead window (K outstanding async XLA dispatches —
@@ -373,10 +379,21 @@ class Worker:
             self.predict_fn = make_predict_fn(
                 cfg, use_kernel,
                 member_dtype=self.member_dtype, quant_out=self._quant_out)
-            if not fake:   # warm-up compile so READY means actually servable
-                warm = jnp.zeros((batch_size, max_seq), jnp.int32)
-                jax.block_until_ready(
-                    self.predict_fn(self.params, warm, self.frontend))
+            if not fake:   # warm every bucket so READY means servable at
+                # each shape the batcher emits (side-pool widths compile
+                # on first use)
+                for b in bucket_ladder(batch_size):
+                    fe = (self.frontend[:b]
+                          if self.frontend is not None else None)
+                    y = self.predict_fn(self.params, self._to_device(
+                        np.zeros((b, max_seq), np.int32)), fe)
+                    if combiner is not None:
+                        # the sender cuts span rows out of the output on
+                        # the device: one slice program per (bucket, rows),
+                        # the row offset being an argument
+                        y = [leaf[:n] for leaf in jax.tree.leaves(y)
+                             for n in range(1, b)]
+                    jax.block_until_ready(y)
             self.prediction_queue.put(Message(seg.READY, model_idx, None))
         except (MemoryError, RuntimeError, ValueError):
             # paper §II.C.2: {-1, None, None} triggers system shutdown.  A
@@ -386,6 +403,14 @@ class Worker:
             if oom_sentinel:
                 self.prediction_queue.put(Message(seg.OOM, None, None))
             raise
+
+    def _to_device(self, rows: np.ndarray):
+        """Token rows as the step takes them, committed to the worker's chip
+        where it has one: warm-up and serving must pass the same kind of
+        array, or each hits its own entry of the step's jit cache."""
+        if self._jax_device is not None:
+            return jax.device_put(rows, self._jax_device)
+        return jnp.asarray(rows)
 
     # ---- threads -------------------------------------------------------------
     def start(self):
@@ -534,11 +559,12 @@ class Worker:
     # ---- stage 1: batcher ----------------------------------------------------
     def _flush(self, batch: _OpenBatch) -> None:
         """Close a slot: cut it into compiled-batch chunks (full batches plus
-        a pow2-bucketed remainder), zero stale pad rows, and enqueue each
-        chunk as an independently schedulable :class:`ChunkDesc` on the
-        priority dispatch queue.  The slot's :class:`SlotRef` refcount
-        starts at the chunk count, so the ring buffer recycles only after
-        every chunk's output is materialized.  Padding counters make
+        a pow2-bucketed remainder, counted per bucket as ``chunks.b<rows>``),
+        zero stale pad rows, and enqueue each chunk as an independently
+        schedulable :class:`ChunkDesc` on the priority dispatch queue.  The
+        slot's :class:`SlotRef` refcount starts at the chunk count, so the
+        ring buffer recycles only after every chunk's output is
+        materialized.  Padding counters make
         coalescing efficiency observable; the batch's open-to-flush time is
         the ``linger`` stage."""
         chunks = []                           # (offset, bucket, valid) views
@@ -548,6 +574,7 @@ class Worker:
             if valid < bucket:
                 batch.buf[off + valid:off + bucket] = 0   # stale tail rows
             chunks.append((off, bucket, valid))
+            self.timers.inc(self._chunk_counter[bucket])
             self.timers.inc("rows_valid", valid)
             self.timers.inc("rows_dispatched", bucket)
         self.timers.inc("batches", len(chunks))
@@ -789,8 +816,7 @@ class Worker:
                     for sp in c.spans)
 
             def _upload(c):
-                view = c.ref.buf[c.off:c.off + c.bucket]
-                return jax.device_put(view, self._jax_device)
+                return self._to_device(c.ref.buf[c.off:c.off + c.bucket])
 
             for pos, item in enumerate(items):
                 if item is None:
@@ -830,11 +856,8 @@ class Worker:
                     if staged is not None and staged[0] is chunk:
                         x = staged[1]          # upload already in flight
                         self.timers.inc("h2d_staged", 1)
-                    elif self._jax_device is not None:
-                        x = _upload(chunk)
                     else:
-                        x = jnp.asarray(
-                            chunk.ref.buf[chunk.off:chunk.off + chunk.bucket])
+                        x = _upload(chunk)
                     staged = None
                     fe = (self.frontend[:chunk.bucket]
                           if self.frontend is not None else None)

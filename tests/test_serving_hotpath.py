@@ -11,7 +11,7 @@ import repro.models as M
 from repro.configs import ensemble
 from repro.core import AllocationMatrix, host_cpus
 from repro.serving.system import InferenceSystem
-from repro.serving.worker import bucket_for
+from repro.serving.worker import bucket_for, bucket_ladder
 
 SEQ = 16
 
@@ -44,15 +44,55 @@ def make_system(cfgs, params, A, **kw):
 
 # ---- shape buckets ----------------------------------------------------------
 
-def test_bucket_for_shapes():
-    assert bucket_for(8, 8) == 8
-    assert bucket_for(3, 8) == 8          # min bucket
-    assert bucket_for(9, 16) == 16
-    assert bucket_for(17, 64) == 32       # next power of two
-    assert bucket_for(33, 64) == 64
-    assert bucket_for(5, 64) == 8
-    assert bucket_for(64, 64) == 64
-    assert bucket_for(100, 64) == 64      # clamped to the compiled batch
+@pytest.mark.parametrize("n, batch, bucket", [
+    (1, 8, 1), (2, 8, 2), (3, 8, 4), (5, 8, 8), (8, 8, 8),
+    (9, 16, 16), (5, 64, 8), (17, 64, 32), (33, 64, 64), (64, 64, 64),
+    (100, 64, 64),                        # clamped to the compiled batch
+    (10, 12, 12),                         # a batch that is no power of two
+])
+def test_bucket_for_shapes(n, batch, bucket):
+    """Next power of two >= n, clamped to [1, batch]: no floor above one
+    row, so a 1-row chunk runs a 1-row program."""
+    assert bucket_for(n, batch) == bucket
+
+
+def test_worker_warms_every_bucket(ens2):
+    """Each worker's constructor compiles its whole ladder (1, 2, 4, ...,
+    batch), so no request compiles a predict program."""
+    cfgs, params = ens2
+    with make_system(cfgs, params, np.array([[8, 16]]),
+                     segment_size=16) as s:
+        for w in s.workers:
+            ladder = bucket_ladder(w.batch_size)
+            assert ladder == [2 ** k for k in
+                              range(int(np.log2(w.batch_size)) + 1)]
+            assert w.predict_fn._cache_size() == len(ladder)
+
+
+@pytest.mark.parametrize("n, bucket", [(1, 1), (3, 4)])
+def test_small_chunk_runs_its_own_bucket(ens2, n, bucket):
+    """A lone n-row request runs the n-row bucket, not a padded batch of 8,
+    and its rows equal the same rows computed inside a full batch.  Serving
+    hits the programs the constructor compiled: the cache does not grow."""
+    cfgs, params = ens2
+    X = np.random.default_rng(20 + n).integers(0, 512, (8, SEQ)) \
+        .astype(np.int32)
+    with make_system(cfgs, params, np.array([[8, 8]]),
+                     segment_size=16) as s:
+        s.timers.reset()
+        y_small = s.predict(X[:n])
+        c = s.serving_counters()
+        assert c[f"chunks.b{bucket}"] == len(cfgs)     # one chunk a member
+        assert not any(c.get(f"chunks.b{b}") for b in (1, 2, 4, 8)
+                       if b != bucket)
+        assert c["rows_valid"] == len(cfgs) * n
+        assert c["rows_dispatched"] == len(cfgs) * bucket
+        y_full = s.predict(X)
+        for w in s.workers:
+            assert w.predict_fn._cache_size() == 4      # 1, 2, 4, 8
+    np.testing.assert_allclose(y_small, y_full[:n], rtol=1e-5, atol=2e-5)
+    np.testing.assert_allclose(y_small, oracle(cfgs, params, X[:n]),
+                               atol=2e-5)
 
 
 @pytest.mark.parametrize("n", [1, 7, 8, 9, 20, 31, 32, 70])
