@@ -2,7 +2,8 @@
 decoder with grouped-query attention, rotary positions, a sliding window,
 RMSNorm and a SwiGLU MLP, with an untied output head.
 
-This file imports nothing of the program under test.  It holds three things:
+This file imports nothing of the program under test.  It defines what
+``chipbench.manifest.REFERENCE_API`` names; chiefly:
 
 * :func:`init_params` -- the benchmark's own weight generator.  It draws
   every member's weights from a key on the device, in the layout the
@@ -15,6 +16,8 @@ This file imports nothing of the program under test.  It holds three things:
   (what the served classifier answers).  ``weights="int8"`` rounds every
   matrix to int8 first: the correctness control.
 * :func:`program_config` -- the program's config object for these sizes.
+* :func:`rehearsal` -- the size the CPU rehearsal runs, and
+  :func:`model_flops_per_row` -- the work ``mfu`` counts.
 """
 from __future__ import annotations
 
@@ -22,6 +25,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from chipbench import flops
 
 INIT_SCALE = 0.02
 _NORMS = ("pre_norm", "mlp_norm", "final_norm")
@@ -70,6 +75,24 @@ def param_bytes(cfg: dict, itemsize: int = 2) -> int:
             size *= s
         total += size
     return total * itemsize
+
+
+def rehearsal(cfg: dict) -> dict:
+    """The configuration cut to a few layers and a narrow width, for the
+    CPU rehearsal only; the cells run it at the published widths."""
+    return dict(cfg, num_hidden_layers=2, hidden_size=64,
+                num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+                intermediate_size=128, vocab_size=512,
+                # at this size on the CPU the program reads about 3e-8 on
+                # both numbers and the int8 control 4e-3 and 1e-2; the limits
+                # sit between, as the configuration's do between the chip's
+                # readings
+                limits={"max_abs_err": 1e-4, "rms_rel_err": 1e-4})
+
+
+def model_flops_per_row(cfg: dict, seq: int) -> float:
+    """A dense decoder's work for one row: ``flops.model_flops_per_row``."""
+    return flops.model_flops_per_row(cfg, seq)
 
 
 def _quantize_int8(w: jax.Array, in_axes: tuple) -> jax.Array:

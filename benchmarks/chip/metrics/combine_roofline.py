@@ -10,12 +10,14 @@ the rows answered in the window, times the members, over the calls the
 program's ``combine`` stage timer counted in the same window."""
 from chipbench import flops
 
+KERNEL = "combine"
+
 
 def read(w):
     if w.trace is None:
         return None
-    secs = w.trace["kernel_s"].get("combine", 0.0)
-    calls = w.trace["kernel_calls"].get("combine", 0)
+    secs = w.trace["kernel_s"].get(KERNEL, 0.0)
+    calls = w.trace["kernel_calls"].get(KERNEL, 0)
     _total, adds = w.stage("combine")
     rows = sum(r.rows for r in w.completed_in_window()) * w.members
     if not secs or not calls or not adds or not rows:

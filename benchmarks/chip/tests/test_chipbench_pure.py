@@ -63,11 +63,72 @@ def test_cell_finds_everything_by_name(cell):
 def test_config_files_load(name):
     cfg = manifest.config(MAN, name)
     entry = next(c for c in MAN["configs"] if c["name"] == name)
-    assert entry["reduced"] == cfg["reduced"] == []
-    assert cfg["members"] >= 1 and set(cfg["limits"]) == {
-        "max_abs_err", "rms_rel_err"}
+    manifest.check_config(entry, cfg)
     pcfg = manifest.reference(cfg).program_config(cfg)
     assert pcfg.param_count() == manifest.reference(cfg).param_bytes(cfg, 1)
+
+
+@pytest.mark.parametrize("name", [c["name"] for c in MAN["configs"]])
+def test_reference_defines_the_api(name):
+    ref = manifest.reference(manifest.config(MAN, name))
+    missing = [f for f in manifest.REFERENCE_API
+               if not callable(getattr(ref, f, None))]
+    assert missing == []
+
+
+def _cut_danube():
+    """danube2 cut to half its depth, stated as a cut file states it."""
+    entry = dict(next(c for c in MAN["configs"] if c["name"] == "danube2"),
+                 name="danube2_cut", reduced=["num_hidden_layers"])
+    cfg = dict(DANUBE, num_hidden_layers=12, reduced=["num_hidden_layers"],
+               published={"num_hidden_layers": 24})
+    return entry, cfg
+
+
+def test_a_cut_configuration_passes():
+    manifest.check_config(*_cut_danube())
+
+
+def _lacks_the_key(entry, cfg):
+    entry["reduced"] = cfg["reduced"] = ["num_experts"]
+    cfg["published"] = {"num_experts": 64}
+
+
+def _no_published_value(entry, cfg):
+    del cfg["published"]
+
+
+def _published_value_held(entry, cfg):
+    cfg["num_hidden_layers"] = 24
+
+
+def _no_deployment(entry, cfg):
+    del cfg["deployment"]
+
+
+def _a_width_cut(entry, cfg):
+    entry["reduced"] = cfg["reduced"] = ["intermediate_size"]
+    cfg["published"] = {"intermediate_size": 6912}
+    cfg["intermediate_size"] = 3456
+
+
+def _reduced_differs_from_the_manifest(entry, cfg):
+    entry["reduced"] = []
+
+
+def _other_limits(entry, cfg):
+    cfg["limits"] = {"max_abs_err": 0.2}
+
+
+@pytest.mark.parametrize("fault", [
+    _lacks_the_key, _no_published_value, _published_value_held,
+    _no_deployment, _a_width_cut, _reduced_differs_from_the_manifest,
+    _other_limits], ids=lambda f: f.__name__[1:])
+def test_a_cut_configuration_that_does_not_say_so_fails(fault):
+    entry, cfg = _cut_danube()
+    fault(entry, cfg)
+    with pytest.raises(ValueError):
+        manifest.check_config(entry, cfg)
 
 
 # ---- FLOP and byte counts -------------------------------------------------
@@ -102,6 +163,29 @@ def test_combine_bytes_and_peaks():
                                           "hbm_bytes_per_s": 819e9}
     with pytest.raises(ValueError):
         flops.peaks("TPU v9 imaginary")
+
+
+class _Window:
+    """What mfu reads of a window."""
+
+    def __init__(self, rows, seconds, chips, members, cfg, seq):
+        self.rows, self.seconds, self.chips = rows, seconds, chips
+        self.members, self.cfg = members, cfg
+        self.mix = type("Mix", (), {"seq": seq})()
+        self.peak = flops.PEAKS["TPU v5 lite"]
+
+    def completed_in_window(self):
+        return [type("Rec", (), {"rows": n})() for n in self.rows]
+
+
+@pytest.mark.parametrize("rows,seconds,chips", [((1, 2, 4, 8), 51.0, 1),
+                                                ((8,) * 40, 20.0, 4)])
+def test_mfu_counts_the_dense_formula(rows, seconds, chips):
+    w = _Window(rows, seconds, chips, 2, DANUBE, 128)
+    work = sum(rows) * 2 * flops.model_flops_per_row(DANUBE, 128)
+    want = 100.0 * work / (seconds * chips * 197e12)
+    assert manifest.metric_reader("mfu.chat").read(w) == pytest.approx(
+        want, rel=1e-12)
 
 
 # ---- traffic ----------------------------------------------------------------
@@ -257,6 +341,23 @@ def test_reduce_on_a_synthetic_trace():
     assert ops["fusion.3"] == pytest.approx(10 / 2 * 1e-6)
 
 
+def test_an_op_kernel_reads_the_ops_own_time():
+    out = trace_reduce.reduce(SYNTH, {
+        "call": "op:^custom-call", "fusion": "op:^fusion\\.",
+        "loop": "op:^while", "step": "^jit_predict"})
+    # the op itself, not the 14 us of its program
+    assert out["kernel_s"]["call"] == pytest.approx(10e-6)
+    assert out["kernel_calls"]["call"] == 1
+    # every matching op wholly inside the window, on every chip: fusion.3
+    # [90,120] runs past the close
+    assert out["kernel_s"]["fusion"] == pytest.approx((10 + 15 + 50) * 1e-6)
+    assert out["kernel_calls"]["fusion"] == 3
+    # a container's time is its body's
+    assert out["kernel_calls"]["loop"] == 0
+    # a program pattern still matches programs
+    assert out["kernel_s"]["step"] == pytest.approx(20e-6)
+
+
 def test_union_merges_overlaps():
     assert trace_reduce.union([(5, 7), (0, 2), (1, 3), (7, 8)]) == \
         [(0, 3), (5, 8)]
@@ -276,3 +377,76 @@ def test_reduce_on_a_recorded_chip_trace():
     assert out["kernel_s"] == pytest.approx(exp["kernel_s"], rel=1e-9)
     assert out["kernel_calls"] == exp["kernel_calls"]
     assert 0.0 < out["idle_share"] < 1.0
+
+
+@pytest.mark.skipif(not RECORDED.exists(), reason="no recorded trace")
+def test_the_combine_op_lies_inside_its_program_on_a_recorded_trace():
+    with open(RECORDED) as f:
+        rec = json.load(f)
+    out = trace_reduce.reduce(rec["trace"], {
+        "op": "op:^ensemble_accumulate", "program": "^jit_ensemble_accumulate"})
+    assert 0.0 < out["kernel_s"]["op"] <= out["kernel_s"]["program"]
+    assert out["kernel_calls"]["op"] == out["kernel_calls"]["program"]
+
+
+# ---- the program's spans against the device's idle time --------------------
+US = 1000.0        # ns per us
+
+
+def _us(name, start_us, dur_us):
+    return [name, start_us * US, dur_us * US]
+
+
+# a 100 us window; chip 0 runs [10,20] and [50,60], chip 1 all of it; a
+# batch lingers [25,45] with a copy [30,35] inside it on another thread, and
+# a request is submitted over [70,72]
+STAGED = {
+    "devices": {"/device:TPU:0": [_us("fusion.1", 10, 10),
+                                  _us("fusion.2", 50, 10)],
+                "/device:TPU:1": [_us("fusion.1", 0, 100)]},
+    "programs": {},
+    "host": [_us("chipbench.traced", 0, 100),
+             _us("chipbench.arrival_wait", -5, 110)],
+    "program": [_us("serving.linger", 25, 20), _us("serving.copy", 30, 5),
+                _us("serving.submit", 70, 2)],
+}
+
+
+def test_idle_in_program_and_gap_labels():
+    out = trace_reduce.reduce(STAGED)
+    assert out["window_s"] == pytest.approx(100e-6)
+    # chip 0 is idle [0,10], [20,50], [60,100]: 20 us of it under the
+    # linger (the copy inside it), 2 us under the submit; chip 1 never
+    assert out["idle_in_program_share"] == pytest.approx(22 / 2 / 100)
+    assert out["idle_with_stage_open_share"] == pytest.approx(
+        {"serving.copy": 5 / 200, "serving.linger": 20 / 200,
+         "serving.submit": 2 / 200})
+    # [60,100] (middle 80) and [0,10] (middle 5) lie in no program span
+    # and keep the benchmark's label; [20,50] (middle 35) takes the
+    # innermost program span open there
+    assert out["breakdown"]["idle_gaps"] == [
+        ["arrival_wait", pytest.approx(40e-6)],
+        ["serving.copy", pytest.approx(30e-6)],
+        ["arrival_wait", pytest.approx(10e-6)]]
+
+
+@pytest.mark.parametrize("source", ["synthetic", "recorded"])
+def test_without_program_spans_it_names_the_gaps_as_reduce_does(source):
+    if source == "synthetic":
+        trace = {k: v for k, v in STAGED.items() if k != "program"}
+    else:
+        with open(RECORDED) as f:
+            trace = json.load(f)["trace"]
+    out = trace_reduce.reduce(trace)
+    assert out["idle_in_program_share"] == 0.0
+    assert out["idle_with_stage_open_share"] == {}
+    assert out["breakdown"] == \
+        trace_reduce.reduce(dict(trace, program=[]))["breakdown"]
+    labels = [g[0] for g in out["breakdown"]["idle_gaps"]]
+    assert labels and not any(g.startswith("serving.") for g in labels)
+    if source == "synthetic":
+        # every gap of chip 0 lies under the benchmark's arrival wait
+        assert out["breakdown"]["idle_gaps"] == [
+            ["arrival_wait", pytest.approx(40e-6)],
+            ["arrival_wait", pytest.approx(30e-6)],
+            ["arrival_wait", pytest.approx(10e-6)]]

@@ -6,8 +6,9 @@ broken underneath, once for each fault the cell can have, must come out
 not correct; and so must the run with the correctness control, the int8
 reference, put in the program's place.
 
-The member is cut to a few layers and a narrow width here only; the cells
-run it at the published widths.
+Each configuration is run at the size its reference module's ``rehearsal``
+gives, with that size's own limits; the cells run it at the published
+widths.
 """
 from __future__ import annotations
 
@@ -23,13 +24,6 @@ sys.path.insert(0, str(HERE))
 
 from chipbench import flops, harness, manifest, runner  # noqa: E402
 
-SMALL = dict(num_hidden_layers=2, hidden_size=64, num_attention_heads=4,
-             num_key_value_heads=2, head_dim=16, intermediate_size=128,
-             vocab_size=512,
-             # at this size on the CPU the program reads about 3e-8 on both
-             # numbers and the int8 control 4e-3 and 1e-2; the limits sit
-             # between, as the configuration's do between the chip's readings
-             limits={"max_abs_err": 1e-4, "rms_rel_err": 1e-4})
 SEQ = 16
 SEED = 2**33 + 11
 CELLS = [w["name"] for w in manifest.load_manifest()["workloads"]]
@@ -37,7 +31,8 @@ CELLS = [w["name"] for w in manifest.load_manifest()["workloads"]]
 
 @pytest.fixture
 def rehearse(monkeypatch):
-    """Run a cell on a CPU cell at the small size; returns the result."""
+    """Run a cell on a CPU cell at its rehearsal size; returns the
+    result."""
     import jax
     from repro.core import host_cpus
     real = runner.load_cell
@@ -45,7 +40,7 @@ def rehearse(monkeypatch):
     def load_cell(name):
         man, cell, cfg, mix, ref = real(name)
         mix = dataclasses.replace(mix, seq=SEQ, rate_per_s=25.0)
-        return man, cell, dict(cfg, **SMALL), mix, ref
+        return man, cell, ref.rehearsal(cfg), mix, ref
 
     monkeypatch.setattr(runner, "load_cell", load_cell)
     monkeypatch.setattr(runner, "configure_jax", lambda cache: None)
@@ -83,10 +78,12 @@ def test_a_traced_run_traces_the_whole_window(rehearse, cell):
     assert res["device"]["window_s"] == pytest.approx(1.0, abs=0.05)
     man = manifest.load_manifest()
     want = {m["name"] for m in manifest.metrics_of(man, cell, "per_layer")}
-    # the CPU runs no device operation: the kernel's roofline finds nothing
-    # to read and is left out; every other per-layer metric is there
-    assert set(res["metrics"]) == {m for m in want
-                                   if not m.startswith("combine_roofline")}
+    # the CPU runs no device operation: a reader of a kernel's device time
+    # (one that declares its KERNEL) finds nothing to read and is left out;
+    # every other per-layer metric is there
+    kernel = {m for m in want
+              if hasattr(manifest.metric_reader(m), "KERNEL")}
+    assert set(res["metrics"]) == want - kernel
 
 
 def _break_step(monkeypatch, fn):
