@@ -33,7 +33,32 @@ class MoEConfig:
     capacity_factor: float = 1.25     # used by the "capacity" (GShard) impl
     impl: str = "capacity"            # "capacity" (TPU expert-parallel, may drop
                                       # tokens) | "dense" (dropless, exact; used by
-                                      # reduced configs and correctness tests)
+                                      # reduced configs and correctness tests) |
+                                      # "grouped" (dropless, held share only:
+                                      # sorted rows through a grouped matmul)
+    # expert-parallel share ("grouped" impl): the router scores
+    # ``routed_experts`` experts (0 -> num_experts, every expert held) and
+    # this instance holds ``num_experts`` of them, router indices
+    # ``first_expert`` .. ``first_expert + num_experts - 1``
+    routed_experts: int = 0
+    first_expert: int = 0
+
+    @property
+    def router_width(self) -> int:
+        return self.routed_experts or self.num_experts
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (arXiv:2309.00071): frequencies interpolated by
+    ``factor`` below a ramp between ``beta_fast`` and ``beta_slow``
+    rotations over ``original_max_position`` positions, and cos/sin scaled
+    by ``attention_factor``."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -61,6 +86,8 @@ class ModelConfig:
     sliding_window: int = 4096        # window for SWA layers
     qk_norm: bool = False
     rope_theta: float = 10000.0
+    yarn: Optional[YarnConfig] = None  # YaRN on the full-attention (ATTN)
+                                       # layers; windowed layers keep plain RoPE
     norm_eps: float = 1e-6
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
@@ -143,8 +170,9 @@ class ModelConfig:
         total = dense_like.param_count()
         per_expert = 3 * self.d_model * m.d_ff_expert
         total += self.num_layers * (
-            m.top_k * per_expert
-            + self.d_model * m.num_experts                 # router
+            # a held share sees its experts' part of the top-k on average
+            m.top_k * m.num_experts * per_expert // m.router_width
+            + self.d_model * m.router_width                # router
             + (3 * self.d_model * m.d_ff_shared if m.shared_expert else 0))
         return total
 
@@ -171,7 +199,7 @@ class ModelConfig:
         if kind != SSM or self.d_ff > 0:
             if self.moe is not None:
                 m = self.moe
-                n += self.d_model * m.num_experts                      # router
+                n += self.d_model * m.router_width                     # router
                 n += m.num_experts * 3 * self.d_model * m.d_ff_expert  # experts
                 if m.shared_expert:
                     n += 3 * self.d_model * m.d_ff_shared
@@ -235,7 +263,7 @@ class ModelConfig:
                 self.moe, num_experts=min(max_experts, self.moe.num_experts),
                 top_k=min(self.moe.top_k, 2), d_ff_expert=d_model,
                 d_ff_shared=d_model if self.moe.shared_expert else 0,
-                impl="dense")
+                impl="dense", routed_experts=0, first_expert=0)
         ssm = None
         if self.ssm is not None:
             ssm = dataclasses.replace(self.ssm, d_state=16, head_dim=32, chunk=16)

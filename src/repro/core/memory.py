@@ -43,16 +43,37 @@ def worker_bytes(cfg: ModelConfig, batch: int, seq: int,
     # activation workspace: residual + mixer + mlp peaks per layer (x2 for
     # double-buffering); heads term covers attention q/k/v blocks
     per_tok = (4 * cfg.d_model
-               + (cfg.d_ff if cfg.moe is None else
-                  cfg.moe.top_k * cfg.moe.d_ff_expert +
-                  (cfg.moe.d_ff_shared if cfg.moe.shared_expert else 0))
+               + (cfg.d_ff if cfg.moe is None else _expert_per_tok(cfg))
                + 2 * cfg.num_heads * cfg.hd
                + (2 * cfg.d_inner if cfg.ssm else 0))
     acts = 2 * batch * seq * per_tok * dtype_bytes
     logits = batch * cfg.padded_vocab * dtype_bytes
     cache = cfg.kv_cache_bytes(batch, serving_cache_len or seq, 2) \
         if serving_cache_len else 0
-    return params + acts + logits + cache
+    return params + acts + _score_bytes(cfg, batch, seq) + logits + cache
+
+
+def _expert_per_tok(cfg: ModelConfig) -> int:
+    """Expert-layer workspace per token, in elements.  The grouped layer
+    holds every one of a token's top-k assignments as a row, whichever
+    expert it goes to: its input and output (d_model each) and the
+    SwiGLU's gate, up and product (d_ff_expert each)."""
+    m = cfg.moe
+    shared = m.d_ff_shared if m.shared_expert else 0
+    if m.impl == "grouped":
+        return m.top_k * (2 * cfg.d_model + 3 * m.d_ff_expert) + shared
+    return m.top_k * m.d_ff_expert + shared
+
+
+def _score_bytes(cfg: ModelConfig, batch: int, seq: int) -> int:
+    """The attention scores of one layer, float32: the whole (seq, seq)
+    square per head up to ``attention._DENSE_MAX`` positions, where the
+    step takes the plain masked path, and one KV chunk's columns above."""
+    from repro.models import attention
+    if not cfg.has_attention:
+        return 0
+    cols = seq if seq <= attention._DENSE_MAX else attention._CHUNK
+    return batch * cfg.num_heads * seq * cols * 4
 
 
 def device_usage(alloc: AllocationMatrix, cfgs: Sequence[ModelConfig],

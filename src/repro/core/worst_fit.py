@@ -2,7 +2,9 @@
 
 Models sorted by decreasing memory size; each is placed (at the minimum batch
 size) on the accelerator with the most remaining memory, falling back to the
-CPU side only when no accelerator fits, and erroring when nothing fits.
+CPU side only when no accelerator fits, and erroring when nothing fits.  The
+minimum batch is ``default_batch_size``, halved while the models do not all
+fit at it (a long-sequence member's workspace grows with its batch).
 """
 from __future__ import annotations
 
@@ -43,7 +45,22 @@ def worst_fit_decreasing(cfgs: Sequence[ModelConfig],
     ``member_dtypes`` (one dtype name per model, None = fp32) makes the
     footprints dtype-size-aware: int8/fp8 members sort and pack at ~1/4 the
     fp32 param bytes, roughly doubling members per device (DESIGN.md §14).
+    Where the models do not all fit at ``default_batch_size``, the whole
+    placement is made again at half that batch, down to 1.
     """
+    batch = default_batch_size
+    while True:
+        try:
+            return _place(cfgs, devices, batch, seq, member_dtypes)
+        except AllocationError:
+            if batch <= 1:
+                raise
+            batch //= 2
+
+
+def _place(cfgs, devices, batch: int, seq: int,
+           member_dtypes) -> AllocationMatrix:
+    """Every model once at ``batch``, worst fit decreasing."""
     names = [c.name for c in cfgs]
     alloc = zeros(devices, names)
 
@@ -52,8 +69,8 @@ def worst_fit_decreasing(cfgs: Sequence[ModelConfig],
 
     # sort models in descending order of memory size (offline heuristic)
     order = sorted(range(len(cfgs)),
-                   key=lambda m: mem.worker_bytes(cfgs[m], default_batch_size,
-                                                  seq, member_dtype=mdt(m)),
+                   key=lambda m: mem.worker_bytes(cfgs[m], batch, seq,
+                                                  member_dtype=mdt(m)),
                    reverse=True)
     for m in order:
         placed = False
@@ -62,7 +79,7 @@ def worst_fit_decreasing(cfgs: Sequence[ModelConfig],
             if d < 0:
                 continue
             cand = alloc.copy()
-            cand.A[d, m] = default_batch_size
+            cand.A[d, m] = batch
             if mem.fit_mem(cand, cfgs, seq, member_dtypes=member_dtypes):
                 alloc = cand
                 placed = True
@@ -70,6 +87,6 @@ def worst_fit_decreasing(cfgs: Sequence[ModelConfig],
         if not placed:
             raise AllocationError(
                 f"no device has enough memory for {names[m]} "
-                f"(batch={default_batch_size})")
+                f"(batch={batch})")
     alloc.validate()
     return alloc

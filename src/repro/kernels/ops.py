@@ -7,7 +7,8 @@ Responsibilities:
     change results;
   * run the compiled Mosaic kernels whenever JAX's backend is the TPU, and
     the Pallas interpreter otherwise — interpret mode is the CPU-test path
-    (`pallas_enabled()` reports whether the compiled TPU path is active).
+    (`pallas_enabled()` reports whether the compiled TPU path is active);
+    the grouped matmul alone runs ``lax.ragged_dot`` off the TPU.
 """
 from __future__ import annotations
 
@@ -15,6 +16,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox import gmm as megablox_gmm
 
 from repro.kernels import decode_attention as _dec
 from repro.kernels import ensemble_combine as _comb
@@ -155,3 +157,47 @@ def ensemble_accumulate_quant(partial, q, scales, weights):
     out = _comb.ensemble_combine_quant(part, qp, sp, weights, block_seg=bs,
                                        block_c=bc, interpret=_interpret())
     return out[:seg, :c]
+
+
+GMM_ROWS = 128              # row tile of the grouped matmul
+GMM_RHS_VMEM = 8 << 20      # bytes of one double-buffered weight tile
+
+
+def _gmm_tiling(k: int, n: int):
+    """Row tile :data:`GMM_ROWS`; the whole contraction and as much of the
+    output width as keeps the double-buffered bf16 weight tile within
+    :data:`GMM_RHS_VMEM`: with one contraction tile, consecutive row tiles
+    of one expert reuse the weight tile in VMEM instead of reading it
+    again."""
+    tn = n
+    while 2 * 2 * k * tn > GMM_RHS_VMEM and tn % 256 == 0:
+        tn //= 2
+    return GMM_ROWS, k, tn
+
+
+def _gmm(lhs, rhs, group_sizes, *, interpret: bool):
+    """Megablox's grouped matmul on bf16 operands with float32 sums, rows
+    padded to the row tile."""
+    m = lhs.shape[0]
+    lhs = _pad_to(lhs.astype(jnp.bfloat16), 0, GMM_ROWS)
+    out = megablox_gmm(lhs, rhs.astype(jnp.bfloat16), group_sizes,
+                       preferred_element_type=jnp.float32,
+                       tiling=_gmm_tiling(lhs.shape[1], rhs.shape[2]),
+                       interpret=interpret)
+    return out[:m]
+
+
+@jax.jit
+def grouped_matmul(lhs, rhs, group_sizes):
+    """lhs (m, k) rows sorted by group, rhs (G, k, n), group_sizes (G,)
+    int32 -> (m, n) float32: the first ``group_sizes[0]`` rows times
+    ``rhs[0]``, the next ``group_sizes[1]`` times ``rhs[1]``, and so on.
+    Rows past ``sum(group_sizes)`` are unspecified.
+
+    On the TPU, the Pallas megablox kernel fed bfloat16, which is what
+    XLA's default precision feeds the MXU; elsewhere ``lax.ragged_dot`` in
+    the operands' dtype."""
+    if pallas_enabled():
+        return _gmm(lhs, rhs, group_sizes, interpret=False)
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                              preferred_element_type=jnp.float32)

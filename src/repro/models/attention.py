@@ -107,11 +107,12 @@ def chunked_attention(q, k, v, q_pos, k_pos, *, causal: bool, window: int = 0,
 
 
 def self_attention(cfg: ModelConfig, p, x, positions, *, window: int = 0,
-                   use_kernel: bool = False) -> jax.Array:
-    """Full-sequence causal attention for train/prefill.  x: (B,S,D)."""
+                   use_kernel: bool = False, yarn=None) -> jax.Array:
+    """Full-sequence causal attention for train/prefill.  x: (B,S,D);
+    ``yarn`` scales the layer's RoPE (full-attention layers)."""
     q, k, v = project_qkv(cfg, p, x)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    q = apply_rope(q, positions, cfg.rope_theta, yarn)
+    k = apply_rope(k, positions, cfg.rope_theta, yarn)
     s = x.shape[1]
     if use_kernel:
         from repro.kernels import ops as kops
@@ -141,7 +142,7 @@ def cross_attention(cfg: ModelConfig, p, x, frontend, *, use_kernel: bool = Fals
 
 def decode_attention(cfg: ModelConfig, p, x, k_cache, v_cache, pos, *,
                      window: int = 0, use_kernel: bool = False,
-                     k_scale=None, v_scale=None):
+                     k_scale=None, v_scale=None, yarn=None):
     """One-token attention against a cache.
 
     x: (B,1,D); k_cache/v_cache: (B,L,KV,hd) (ring buffer for SWA layers);
@@ -152,8 +153,8 @@ def decode_attention(cfg: ModelConfig, p, x, k_cache, v_cache, pos, *,
     """
     q, k_new, v_new = project_qkv(cfg, p, x)
     posv = jnp.full((x.shape[0], 1), pos)
-    q = apply_rope(q, posv, cfg.rope_theta)
-    k_new = apply_rope(k_new, posv, cfg.rope_theta)
+    q = apply_rope(q, posv, cfg.rope_theta, yarn)
+    k_new = apply_rope(k_new, posv, cfg.rope_theta, yarn)
     quantized = k_scale is not None
     from repro import runtime_flags
     _fd_mesh = runtime_flags.SHARDING_OPTS.get("decode_cache_seq")

@@ -1,6 +1,8 @@
 """Shared primitive layers: RMSNorm, RoPE, SwiGLU MLP, embeddings."""
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -12,18 +14,39 @@ def rms_norm(x: jax.Array, w: jax.Array, eps: float = 1e-6) -> jax.Array:
     return (x * (1.0 + w.astype(jnp.float32))).astype(dt)
 
 
-def rope_freqs(head_dim: int, theta: float) -> jax.Array:
-    """(head_dim//2,) inverse frequencies."""
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def rope_freqs(head_dim: int, theta: float, yarn=None) -> jax.Array:
+    """(head_dim//2,) inverse frequencies; with ``yarn`` (a
+    :class:`~repro.configs.base.YarnConfig`), YaRN's: interpolated by
+    ``factor`` below the ramp between ``beta_fast`` and ``beta_slow``
+    rotations, extrapolated above it (arXiv:2309.00071 §3.2)."""
+    inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    if yarn is None:
+        return inv
+
+    def dim_of(rotations):      # the dimension that turns ``rotations`` times
+        return head_dim * math.log(
+            yarn.original_max_position / (rotations * 2 * math.pi)) / (
+                2 * math.log(theta))
+
+    low = max(math.floor(dim_of(yarn.beta_fast)), 0)
+    high = min(math.ceil(dim_of(yarn.beta_slow)), head_dim - 1)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return inv / yarn.factor * ramp + inv * (1.0 - ramp)
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (..., S, H, hd), positions: broadcastable to (..., S)."""
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               yarn=None) -> jax.Array:
+    """x: (..., S, H, hd), positions: broadcastable to (..., S).  With
+    ``yarn``, YaRN's frequencies and cos/sin scaled by its
+    ``attention_factor``."""
     hd = x.shape[-1]
-    inv = rope_freqs(hd, theta)                       # (hd/2,)
+    inv = rope_freqs(hd, theta, yarn)                 # (hd/2,)
     ang = positions[..., :, None].astype(jnp.float32) * inv        # (..., S, hd/2)
     cos = jnp.cos(ang)[..., None, :]                  # (..., S, 1, hd/2)
     sin = jnp.sin(ang)[..., None, :]
+    if yarn is not None:
+        cos, sin = cos * yarn.attention_factor, sin * yarn.attention_factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

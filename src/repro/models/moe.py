@@ -1,10 +1,17 @@
-"""Mixture-of-Experts layer: top-k router + GShard-style capacity dispatch.
+"""Mixture-of-Experts layer: top-k router + GShard-style capacity dispatch,
+or a dropless grouped layer over the experts one instance holds.
 
 The dispatch/combine formulation uses one-hot einsums over (group, capacity)
 so that sharding experts over the "model" mesh axis induces all-to-all — the
 TPU-native expert-parallel pattern — instead of gathers XLA cannot shard.
 Tokens are processed in groups of ``GROUP`` so dispatch cost stays linear in
 sequence length.
+
+The grouped layer (``impl="grouped"``, :func:`moe_ffn_grouped`) is one
+chip's share of an expert-parallel deployment: the router scores every
+expert, the (token, expert) assignments to held experts are sorted by expert
+and run through a grouped matmul, and assignments to absent experts add
+nothing (their chips would add them).
 """
 from __future__ import annotations
 
@@ -56,11 +63,51 @@ def moe_ffn_dense(cfg: ModelConfig, p, x: jax.Array) -> Tuple[jax.Array, jax.Arr
     return out, aux
 
 
-def moe_ffn(cfg: ModelConfig, p, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """x: (B,S,D) -> (out (B,S,D), aux_loss scalar)."""
+def moe_ffn_grouped(cfg: ModelConfig, p, x: jax.Array
+                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Dropless layer over the held experts: x (B,S,D) -> (out (B,S,D),
+    aux_loss scalar, rows int32), ``rows`` being the (token, expert)
+    assignments the held experts computed.
+
+    The router's softmax runs over all ``router_width`` experts in float32,
+    then top-k and renormalise; every assignment to a held expert is
+    computed, with no capacity.  The assignments are sorted by expert (the
+    absent ones last), each held expert's SwiGLU runs on its own rows
+    through :func:`repro.kernels.ops.grouped_matmul`, and each output row is
+    added to its token times its router weight."""
+    from repro.kernels import ops as kops
+    m = cfg.moe
+    b, s, d = x.shape
+    xt = x.reshape(-1, d)
+    weights, idx, probs = _router(xt, p["router"], m.top_k)
+    aux = load_balance_loss(probs, idx, m.router_width) * m.router_aux_coef
+    local = idx.reshape(-1) - m.first_expert             # (T*k,)
+    held = (local >= 0) & (local < m.num_experts)
+    key = jnp.where(held, local, m.num_experts)          # absent: one past
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.bincount(key, length=m.num_experts + 1)[:-1].astype(jnp.int32)
+    tok = order // m.top_k                              # token of each row
+    xs = xt[tok]
+    h = kops.grouped_matmul(xs, p["w_gate"], sizes)
+    u = kops.grouped_matmul(xs, p["w_up"], sizes)
+    y = kops.grouped_matmul(jax.nn.silu(h) * u, p["w_down"], sizes)
+    # rows past the held ones are left unwritten by the kernel: select,
+    # never multiply, so whatever they hold cannot reach the output
+    w = weights.reshape(-1)[order].astype(jnp.float32)
+    y = jnp.where(held[order][:, None], y * w[:, None], 0.0)
+    out = jnp.zeros((xt.shape[0], d), jnp.float32).at[tok].add(y)
+    return out.astype(x.dtype).reshape(b, s, d), aux, sizes.sum()
+
+
+def moe_ffn(cfg: ModelConfig, p, x: jax.Array
+            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """x: (B,S,D) -> (out (B,S,D), aux_loss scalar, expert rows the grouped
+    layer computed; 0 for the dense and capacity paths)."""
     m = cfg.moe
     if m.impl == "dense":
-        return moe_ffn_dense(cfg, p, x)
+        return (*moe_ffn_dense(cfg, p, x), 0)
+    if m.impl == "grouped":
+        return moe_ffn_grouped(cfg, p, x)
     b, s, d = x.shape
     t = b * s
     xt = x.reshape(t, d)
@@ -143,4 +190,4 @@ def moe_ffn(cfg: ModelConfig, p, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
         sh = jnp.einsum("bsd,df->bsf", x, p["ws_gate"])
         su = jnp.einsum("bsd,df->bsf", x, p["ws_up"])
         out = out + jnp.einsum("bsf,fd->bsd", jax.nn.silu(sh) * su, p["ws_down"])
-    return out, aux
+    return out, aux, 0

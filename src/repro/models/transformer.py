@@ -7,6 +7,8 @@ and decode caches carry a leading ``repeats`` dim and the stack is a single
 Public API:
     init_params(rng, cfg)                         -> param pytree
     forward(params, cfg, tokens, frontend=None)   -> (logits, aux_loss)
+    last_logits(params, cfg, tokens, ...)         -> (last-position logits,
+                                                      expert rows computed)
     prefill(params, cfg, tokens, max_len, ...)    -> (logits, cache)
     decode_step(params, cfg, cache, token, pos)   -> (logits, cache)
 """
@@ -45,7 +47,7 @@ def _layer_param_shapes(cfg: ModelConfig, kind: str) -> Dict[str, Tuple[int, ...
                       norm=(di,), out_proj=(di, d))
     if cfg.moe is not None:
         m = cfg.moe
-        shapes.update(mlp_norm=(d,), router=(d, m.num_experts),
+        shapes.update(mlp_norm=(d,), router=(d, m.router_width),
                       w_gate=(m.num_experts, d, m.d_ff_expert),
                       w_up=(m.num_experts, d, m.d_ff_expert),
                       w_down=(m.num_experts, m.d_ff_expert, d))
@@ -124,14 +126,15 @@ def param_struct(cfg: ModelConfig, dtype=jnp.bfloat16):
 # Full-sequence forward (training / benchmark-mode serving)
 # --------------------------------------------------------------------------
 def _apply_mlp(cfg: ModelConfig, lp, x):
+    """-> (x, aux_loss, expert rows the grouped layer computed)."""
     if cfg.moe is not None:
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        out, aux = moe_ffn(cfg, lp, h)
-        return x + out, aux
+        out, aux, rows = moe_ffn(cfg, lp, h)
+        return x + out, aux, rows
     if cfg.d_ff > 0:
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        return x + swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"]), 0.0
-    return x, 0.0
+        return x + swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"]), 0.0, 0
+    return x, 0.0, 0
 
 
 def _seq_constraint(x):
@@ -157,7 +160,7 @@ def _apply_layer(cfg: ModelConfig, kind: str, lp, x, positions, frontend,
     h = rms_norm(x, lp["pre_norm"], cfg.norm_eps)
     if kind == ATTN:
         x = x + attn_mod.self_attention(cfg, lp, h, positions, window=0,
-                                        use_kernel=use_kernel)
+                                        use_kernel=use_kernel, yarn=cfg.yarn)
     elif kind == SWA:
         x = x + attn_mod.self_attention(cfg, lp, h, positions,
                                         window=cfg.sliding_window,
@@ -175,24 +178,24 @@ def _apply_layer(cfg: ModelConfig, kind: str, lp, x, positions, frontend,
         x = x + 0.5 * (a + m)
     else:
         raise ValueError(kind)
-    x, aux2 = _apply_mlp(cfg, lp, x)
-    return x, aux + aux2
+    x, aux2, rows = _apply_mlp(cfg, lp, x)
+    return x, aux + aux2, rows
 
 
-def forward(params, cfg: ModelConfig, tokens: jax.Array,
-            frontend: Optional[jax.Array] = None, *, use_kernel: bool = False,
-            remat: bool = False) -> Tuple[jax.Array, jax.Array]:
-    """tokens: (B,S) int32 -> (logits (B,S,Vpad), aux_loss)."""
+def _layers(params, cfg: ModelConfig, tokens: jax.Array,
+            frontend: Optional[jax.Array], use_kernel: bool, remat: bool):
+    """Embedding and layer stack: tokens (B,S) -> (final hidden states
+    (B,S,D) before the final norm, aux_loss, expert rows computed)."""
     x = embed(tokens, params["embed"], cfg.embed_scale)
     positions = jnp.arange(tokens.shape[1])
 
     def unit_body(carry, unit_params):
-        x, aux = carry
+        x, aux, rows = carry
         for i, kind in enumerate(cfg.pattern):
-            x, a = _apply_layer(cfg, kind, unit_params[i], x, positions,
-                                frontend, use_kernel)
-            aux = aux + a
-        return (x, aux), None
+            x, a, r = _apply_layer(cfg, kind, unit_params[i], x, positions,
+                                   frontend, use_kernel)
+            aux, rows = aux + a, rows + r
+        return (x, aux, rows), None
 
     from repro import runtime_flags
     if remat:
@@ -202,12 +205,34 @@ def forward(params, cfg: ModelConfig, tokens: jax.Array,
         body = jax.checkpoint(unit_body, policy=policy)
     else:
         body = unit_body
-    (x, aux), _ = jax.lax.scan(body, (x, jnp.float32(0.0)), params["layers"],
-                               unroll=runtime_flags.scan_unroll())
+    (x, aux, rows), _ = jax.lax.scan(
+        body, (x, jnp.float32(0.0), jnp.int32(0)), params["layers"],
+        unroll=runtime_flags.scan_unroll())
+    return x, aux, rows
+
+
+def _head(params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = unembed(x, params["embed"] if cfg.tie_embeddings else params["head"],
-                     cfg.tie_embeddings)
-    return logits, aux
+    return unembed(x, params["embed"] if cfg.tie_embeddings else params["head"],
+                   cfg.tie_embeddings)
+
+
+def forward(params, cfg: ModelConfig, tokens: jax.Array,
+            frontend: Optional[jax.Array] = None, *, use_kernel: bool = False,
+            remat: bool = False) -> Tuple[jax.Array, jax.Array]:
+    """tokens: (B,S) int32 -> (logits (B,S,Vpad), aux_loss)."""
+    x, aux, _ = _layers(params, cfg, tokens, frontend, use_kernel, remat)
+    return _head(params, cfg, x), aux
+
+
+def last_logits(params, cfg: ModelConfig, tokens: jax.Array,
+                frontend: Optional[jax.Array] = None, *,
+                use_kernel: bool = False) -> Tuple[jax.Array, jax.Array]:
+    """tokens: (B,S) int32 -> (logits of the last position (B,Vpad), the
+    (token, expert) rows the grouped expert layers computed, int32): the
+    final norm and the head run on the last position only."""
+    x, _, rows = _layers(params, cfg, tokens, frontend, use_kernel, False)
+    return _head(params, cfg, x[:, -1]), rows
 
 
 # --------------------------------------------------------------------------
@@ -233,8 +258,9 @@ def _prefill_layer(cfg: ModelConfig, kind: str, lp, x, positions, frontend,
         window = 0 if kind == ATTN else cfg.sliding_window
         q, k, v = attn_mod.project_qkv(cfg, lp, h)
         from repro.models.layers import apply_rope
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        yarn = cfg.yarn if kind == ATTN else None
+        q = apply_rope(q, positions, cfg.rope_theta, yarn)
+        k = apply_rope(k, positions, cfg.rope_theta, yarn)
         s = x.shape[1]
         if s <= attn_mod._DENSE_MAX:
             out = attn_mod.dense_attention(q, k, v, positions, positions,
@@ -278,7 +304,7 @@ def _prefill_layer(cfg: ModelConfig, kind: str, lp, x, positions, frontend,
         x = x + m_out
     elif kind == HYBRID:
         x = x + 0.5 * (a_out + m_out)
-    x, _ = _apply_mlp(cfg, lp, x)
+    x, _, _ = _apply_mlp(cfg, lp, x)
     return x, entry
 
 
@@ -305,10 +331,7 @@ def prefill(params, cfg: ModelConfig, tokens: jax.Array, max_len: int,
     from repro import runtime_flags
     x, cache_layers = jax.lax.scan(unit_body, x, params["layers"],
                                    unroll=runtime_flags.scan_unroll())
-    x = rms_norm(x[:, -1:], params["final_norm"], cfg.norm_eps)
-    logits = unembed(x, params["embed"] if cfg.tie_embeddings else params["head"],
-                     cfg.tie_embeddings)
-    return logits[:, 0], {"layers": cache_layers}
+    return _head(params, cfg, x[:, -1]), {"layers": cache_layers}
 
 
 # --------------------------------------------------------------------------
@@ -320,16 +343,17 @@ def _decode_layer(cfg: ModelConfig, kind: str, lp, entry, x, pos,
     new_entry = dict(entry)
     if kind in (ATTN, SWA):
         window = 0 if kind == ATTN else cfg.sliding_window
+        yarn = cfg.yarn if kind == ATTN else None
         if "k_scale" in entry:       # int8-quantized cache
             (a_out, new_entry["k"], new_entry["v"], new_entry["k_scale"],
              new_entry["v_scale"]) = attn_mod.decode_attention(
                 cfg, lp, h, entry["k"], entry["v"], pos, window=window,
                 use_kernel=use_kernel, k_scale=entry["k_scale"],
-                v_scale=entry["v_scale"])
+                v_scale=entry["v_scale"], yarn=yarn)
         else:
             a_out, new_entry["k"], new_entry["v"] = attn_mod.decode_attention(
                 cfg, lp, h, entry["k"], entry["v"], pos, window=window,
-                use_kernel=use_kernel)
+                use_kernel=use_kernel, yarn=yarn)
         x = x + a_out
     elif kind == CROSS:
         q, _, _ = attn_mod.project_qkv(
@@ -361,7 +385,7 @@ def _decode_layer(cfg: ModelConfig, kind: str, lp, entry, x, pos,
         m_out, new_entry["h"], new_entry["conv"] = ssm_mod.ssm_decode_step(
             cfg, lp, h, entry["h"], entry["conv"])
         x = x + 0.5 * (a_out + m_out)
-    x, _ = _apply_mlp(cfg, lp, x)
+    x, _, _ = _apply_mlp(cfg, lp, x)
     return x, new_entry
 
 
@@ -382,10 +406,7 @@ def decode_step(params, cfg: ModelConfig, cache, token: jax.Array, pos,
     from repro import runtime_flags
     x, new_layers = jax.lax.scan(unit_body, x, (params["layers"], cache["layers"]),
                                  unroll=runtime_flags.scan_unroll())
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = unembed(x, params["embed"] if cfg.tie_embeddings else params["head"],
-                     cfg.tie_embeddings)
-    return logits[:, 0], {"layers": new_layers}
+    return _head(params, cfg, x[:, 0]), {"layers": new_layers}
 
 
 # --------------------------------------------------------------------------

@@ -95,6 +95,7 @@ immediately, idle queue → full ``max_wait_us``; ROADMAP item b).
 """
 from __future__ import annotations
 
+import collections
 import queue
 import threading
 import time
@@ -182,7 +183,8 @@ def make_predict_fn(cfg: ModelConfig, use_kernel: bool = False,
                     quant_out: bool = False) -> Callable:
     """Classification-style serving fn: tokens (b,S) -> last-token class
     scores (b, C) with C = the unpadded vocab (the paper's f(x)->y).  The
-    int32 token buffer is not donated: no output could reuse it.
+    final norm and the head run on the last position only.  The int32
+    token buffer is not donated: no output could reuse it.
 
     ``member_dtype`` != "fp32" expects params wrapped by
     :func:`repro.kernels.quant.quantize_params` — dequantization runs inside
@@ -191,20 +193,40 @@ def make_predict_fn(cfg: ModelConfig, use_kernel: bool = False,
     quantizes the output logits per row (symmetric int8 over classes) and
     returns ``(q (b, C) int8, scale (b, 1) f32)`` for the fused
     dequant-weight-accumulate combine epilogue; per-row scales are uniform
-    across classes, so argmax/vote downstream is unaffected."""
-    from repro.models import forward
+    across classes, so argmax/vote downstream is unaffected.
+
+    With a grouped expert layer the step also counts the (token, expert)
+    rows its held experts computed, padding rows included: the returned
+    callable appends each call's count (an int32 device scalar) to its
+    ``expert_rows`` deque, in call order, and returns the scores alone, so
+    that whatever takes or wraps a step sees one output for every
+    configuration."""
+    from repro.models.transformer import last_logits
 
     wrapped = member_dtype != "fp32"
+    counts = cfg.moe is not None and cfg.moe.impl == "grouped"
 
     def predict(params, tokens, frontend):
         p = kquant.dequantize_params(params) if wrapped else params
-        logits, _ = forward(p, cfg, tokens, frontend, use_kernel=use_kernel)
-        out = logits[:, -1, :cfg.vocab_size]
+        logits, rows = last_logits(p, cfg, tokens, frontend,
+                                   use_kernel=use_kernel)
+        out = logits[:, :cfg.vocab_size]
         if quant_out:
-            return kquant.quantize_symmetric(out, axis=-1)
-        return out
+            out = kquant.quantize_symmetric(out, axis=-1)
+        return (out, rows) if counts else out
 
-    return jax.jit(predict)
+    step = jax.jit(predict)
+    if not counts:
+        return step
+    expert_rows: collections.deque = collections.deque()
+
+    def counted(params, tokens, frontend):
+        y, rows = step(params, tokens, frontend)
+        expert_rows.append(rows)
+        return y
+
+    counted.expert_rows = expert_rows
+    return counted
 
 
 def _span_rids(spans):
@@ -379,6 +401,9 @@ class Worker:
             self.predict_fn = make_predict_fn(
                 cfg, use_kernel,
                 member_dtype=self.member_dtype, quant_out=self._quant_out)
+            # per-call expert row counts, read by the sender (grouped
+            # expert layers only)
+            self._expert_rows = getattr(self.predict_fn, "expert_rows", None)
             if not fake:   # warm every bucket so READY means servable at
                 # each shape the batcher emits (side-pool widths compile
                 # on first use)
@@ -394,6 +419,8 @@ class Worker:
                         y = [leaf[:n] for leaf in jax.tree.leaves(y)
                              for n in range(1, b)]
                     jax.block_until_ready(y)
+                if self._expert_rows is not None:
+                    self._expert_rows.clear()      # warm-up's, not served
             self.prediction_queue.put(Message(seg.READY, model_idx, None))
         except (MemoryError, RuntimeError, ValueError):
             # paper §II.C.2: {-1, None, None} triggers system shutdown.  A
@@ -970,6 +997,11 @@ class Worker:
                     # (q, scale) tuples from quantized members block as a
                     # pytree; compute done, arrays stay on device
                     jax.block_until_ready(y)
+            if on_chip and self._expert_rows is not None:
+                # this chunk's count: the predictor appended it when it
+                # dispatched the chunk, and chunks reach here in that order
+                self.timers.inc("expert_rows",
+                                int(self._expert_rows.popleft()))
             if on_chip and not on_device:
                 with self.timers.stage("copy"):
                     y = np.asarray(y)

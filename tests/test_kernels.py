@@ -139,3 +139,43 @@ def test_kernels_used_by_models_match():
     l1, _ = M.forward(params, cfg, tokens, use_kernel=False)
     l2, _ = M.forward(params, cfg, tokens, use_kernel=True)
     assert float(jnp.abs(l1 - l2).max()) < 2e-3
+
+
+GMM_CASES = [
+    # (group sizes, k, n): empty groups, rows past the groups, a row count
+    # off the row tile
+    ((50, 0, 130, 70), 256, 384),
+    ((0, 128, 0), 128, 128),
+    ((3, 5), 256, 256),
+]
+
+
+def _gmm_loop(lhs, rhs, sizes):
+    """Each group's rows times its matrix, one group at a time."""
+    out = np.zeros((lhs.shape[0], rhs.shape[2]), np.float64)
+    lo = 0
+    for g, n in enumerate(sizes):
+        out[lo:lo + n] = np.asarray(lhs[lo:lo + n], np.float64) @ \
+            np.asarray(rhs[g], np.float64)
+        lo += n
+    return out, lo
+
+
+@pytest.mark.parametrize("sizes,k,n", GMM_CASES)
+def test_grouped_matmul(sizes, k, n):
+    """The CPU path and the Pallas megablox kernel (interpret mode, on the
+    bfloat16 operands it is fed on the TPU) against a loop over groups."""
+    m = sum(sizes) + 7                           # rows past the groups
+    lhs = _rand(11, m, k)
+    rhs = _rand(12, len(sizes), k, n)
+    gs = jnp.asarray(sizes, jnp.int32)
+    want, valid = _gmm_loop(lhs, rhs, sizes)
+    got = ops.grouped_matmul(lhs, rhs, gs)
+    np.testing.assert_allclose(np.asarray(got[:valid]), want[:valid],
+                               rtol=1e-4, atol=1e-3)
+    lb, rb = (x.astype(jnp.bfloat16).astype(jnp.float32) for x in (lhs, rhs))
+    want_b, _ = _gmm_loop(lb, rb, sizes)
+    got_b = ops._gmm(lhs, rhs, gs, interpret=True)
+    assert got_b.shape == (m, n) and got_b.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got_b[:valid]), want_b[:valid],
+                               rtol=1e-4, atol=1e-3)

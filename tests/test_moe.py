@@ -5,10 +5,12 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import get_config
 from repro.configs.base import MoEConfig
-from repro.models.moe import load_balance_loss, moe_ffn, moe_ffn_dense
+from repro.models.moe import (load_balance_loss, moe_ffn, moe_ffn_dense,
+                              moe_ffn_grouped)
 import repro.models.transformer as T
 
 
@@ -34,8 +36,8 @@ def test_capacity_matches_dense_when_ample():
     cfg_c = dataclasses.replace(cfg_d, moe=dataclasses.replace(
         cfg_d.moe, impl="capacity", capacity_factor=float(cfg_d.moe.num_experts)))
     x = jax.random.normal(jax.random.PRNGKey(5), (2, 24, cfg_d.d_model))
-    out_d, aux_d = moe_ffn(cfg_d, lp, x)
-    out_c, aux_c = moe_ffn(cfg_c, lp, x)
+    out_d, aux_d, _ = moe_ffn(cfg_d, lp, x)
+    out_c, aux_c, _ = moe_ffn(cfg_c, lp, x)
     np.testing.assert_allclose(np.asarray(out_d), np.asarray(out_c),
                                atol=1e-5)
     assert abs(float(aux_d) - float(aux_c)) < 1e-6
@@ -47,8 +49,8 @@ def test_capacity_drops_reduce_output_norm():
     cfg_tight = dataclasses.replace(cfg_d, moe=dataclasses.replace(
         cfg_d.moe, impl="capacity", capacity_factor=0.25))
     x = jax.random.normal(jax.random.PRNGKey(6), (2, 64, cfg_d.d_model))
-    out_d, _ = moe_ffn(cfg_d, lp, x)
-    out_t, _ = moe_ffn(cfg_tight, lp, x)
+    out_d, _, _ = moe_ffn(cfg_d, lp, x)
+    out_t, _, _ = moe_ffn(cfg_tight, lp, x)
     assert float(jnp.abs(out_d - out_t).max()) > 1e-4
 
 
@@ -82,3 +84,49 @@ def test_shared_expert_always_on():
     cfg = get_config("llama4-scout-17b-a16e").reduced()
     assert cfg.moe.shared_expert
     assert cfg.moe.top_k == 1
+
+
+# ---- the grouped layer: one chip's share of an expert-parallel layer --------
+
+def _grouped(cfg, held, first=0):
+    return dataclasses.replace(cfg, moe=dataclasses.replace(
+        cfg.moe, impl="grouped", num_experts=held,
+        routed_experts=cfg.moe.num_experts, first_expert=first))
+
+
+def _share(lp, first, held):
+    """A share's weights: the whole router, its own experts."""
+    return {k: v[first:first + held] if k.startswith("w_") else v
+            for k, v in lp.items()}
+
+
+@pytest.mark.parametrize("experts,top_k", [(4, 2), (8, 3)])
+def test_grouped_with_every_expert_held_equals_dense(experts, top_k):
+    """Dropless and exact: holding every expert, the grouped layer computes
+    what the dense one does, and every (token, expert) assignment."""
+    cfg_d, lp = _setup(impl="dense", experts=experts, top_k=top_k)
+    x = jax.random.normal(jax.random.PRNGKey(7), (2, 24, cfg_d.d_model))
+    out_d, aux_d = moe_ffn_dense(cfg_d, lp, x)
+    out_g, aux_g, rows = moe_ffn_grouped(_grouped(cfg_d, experts), lp, x)
+    np.testing.assert_allclose(np.asarray(out_g), np.asarray(out_d),
+                               atol=1e-5)
+    assert abs(float(aux_g) - float(aux_d)) < 1e-6
+    assert int(rows) == 2 * 24 * top_k
+
+
+def test_expert_shares_sum_to_the_uncut_layer():
+    """Four expert-parallel shares, each holding a quarter of the experts
+    behind the same router, add up to the whole layer; each assignment is
+    computed by exactly one of them."""
+    cfg_d, lp = _setup(impl="dense", experts=8, top_k=3)
+    x = jax.random.normal(jax.random.PRNGKey(8), (2, 24, cfg_d.d_model))
+    whole, _ = moe_ffn_dense(cfg_d, lp, x)
+    outs, rows = [], 0
+    for first in range(0, 8, 2):
+        out, _, r = moe_ffn_grouped(_grouped(cfg_d, 2, first),
+                                    _share(lp, first, 2), x)
+        outs.append(np.asarray(out))
+        rows += int(r)
+    assert all(np.abs(o).max() > 0 for o in outs)
+    np.testing.assert_allclose(sum(outs), np.asarray(whole), atol=1e-5)
+    assert rows == 2 * 24 * 3

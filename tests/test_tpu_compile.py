@@ -93,3 +93,15 @@ def test_ssd_scan_compiles(one_chip):
     _compile(ops.ssd_scan, _s(one_chip, (b, s, h, p)),
              _s(one_chip, (b, s, h)), _s(one_chip, (h,)),
              _s(one_chip, (b, s, n)), _s(one_chip, (b, s, n)), chunk=64)
+
+
+# Mellum2-12B-A2.5B's expert layer at batch 2: 2 x 2048 tokens x 8
+# assignments as rows, 16 held experts, hidden 2304, expert width 896
+EXPERT_ROWS, HIDDEN, EXPERT_FF, HELD = 2 * 2048 * 8, 2304, 896, 16
+
+
+@pytest.mark.parametrize("k,n", [(HIDDEN, EXPERT_FF), (EXPERT_FF, HIDDEN)],
+                         ids=["gate_up", "down"])
+def test_grouped_matmul_compiles(one_chip, k, n):
+    _compile(ops.grouped_matmul, _s(one_chip, (EXPERT_ROWS, k)),
+             _s(one_chip, (HELD, k, n)), _s(one_chip, (HELD,), jnp.int32))
