@@ -73,8 +73,9 @@ def moe_ffn_grouped(cfg: ModelConfig, p, x: jax.Array
     then top-k and renormalise; every assignment to a held expert is
     computed, with no capacity.  The assignments are sorted by expert (the
     absent ones last), each held expert's SwiGLU runs on its own rows
-    through :func:`repro.kernels.ops.grouped_matmul`, and each output row is
-    added to its token times its router weight."""
+    through :func:`repro.kernels.ops.grouped_matmul`, and each token gathers
+    its held assignments' output rows back from the sorted order and sums
+    them times their router weights."""
     from repro.kernels import ops as kops
     m = cfg.moe
     b, s, d = x.shape
@@ -91,11 +92,16 @@ def moe_ffn_grouped(cfg: ModelConfig, p, x: jax.Array
     h = kops.grouped_matmul(xs, p["w_gate"], sizes)
     u = kops.grouped_matmul(xs, p["w_up"], sizes)
     y = kops.grouped_matmul(jax.nn.silu(h) * u, p["w_down"], sizes)
-    # rows past the held ones are left unwritten by the kernel: select,
-    # never multiply, so whatever they hold cannot reach the output
-    w = weights.reshape(-1)[order].astype(jnp.float32)
-    y = jnp.where(held[order][:, None], y * w[:, None], 0.0)
-    out = jnp.zeros((xt.shape[0], d), jnp.float32).at[tok].add(y)
+    # un-sort by a gather, not a scatter-add of d-wide rows.  A held
+    # assignment's sorted row is its expert's offset plus its stable rank
+    # among that expert's rows; absent ones point past the end and take
+    # the fill, so rows the kernel left unwritten never reach the output
+    onehot = jax.nn.one_hot(key, m.num_experts, dtype=jnp.int32)
+    rank = ((jnp.cumsum(onehot, axis=0) - onehot) * onehot).sum(1)
+    start = jnp.cumsum(sizes) - sizes
+    pos = jnp.where(held, start[key] + rank, key.shape[0]).reshape(-1, m.top_k)
+    yk = jnp.take(y, pos, axis=0, mode="fill", fill_value=0.0)    # (T,k,D)
+    out = (yk * weights.astype(jnp.float32)[..., None]).sum(1)
     return out.astype(x.dtype).reshape(b, s, d), aux, sizes.sum()
 
 

@@ -114,19 +114,71 @@ def test_grouped_with_every_expert_held_equals_dense(experts, top_k):
     assert int(rows) == 2 * 24 * top_k
 
 
-def test_expert_shares_sum_to_the_uncut_layer():
+@pytest.mark.parametrize("unrouted", [(), (6, 7)],
+                         ids=["every-share-routed", "one-share-unrouted"])
+def test_expert_shares_sum_to_the_uncut_layer(unrouted):
     """Four expert-parallel shares, each holding a quarter of the experts
     behind the same router, add up to the whole layer; each assignment is
-    computed by exactly one of them."""
+    computed by exactly one of them.  A share whose experts the router
+    never picks adds exactly zero and computes no row."""
     cfg_d, lp = _setup(impl="dense", experts=8, top_k=3)
     x = jax.random.normal(jax.random.PRNGKey(8), (2, 24, cfg_d.d_model))
+    if unrouted:
+        # positive inputs, and a router that scores the unrouted experts
+        # below zero and every other expert above it
+        x = jnp.abs(x)
+        sign = jnp.where(jnp.isin(jnp.arange(8), jnp.array(unrouted)), -1, 1)
+        lp = dict(lp, router=jnp.abs(lp["router"]) * sign)
     whole, _ = moe_ffn_dense(cfg_d, lp, x)
     outs, rows = [], 0
     for first in range(0, 8, 2):
         out, _, r = moe_ffn_grouped(_grouped(cfg_d, 2, first),
                                     _share(lp, first, 2), x)
-        outs.append(np.asarray(out))
+        out = np.asarray(out)
+        if {first, first + 1} <= set(unrouted):
+            assert not out.any() and int(r) == 0
+        else:
+            assert np.abs(out).max() > 0
+        outs.append(out)
         rows += int(r)
-    assert all(np.abs(o).max() > 0 for o in outs)
     np.testing.assert_allclose(sum(outs), np.asarray(whole), atol=1e-5)
     assert rows == 2 * 24 * 3
+
+
+def test_one_token_routed_to_every_held_expert():
+    """One token whose k assignments are every expert the layer holds: every
+    sorted row is held and gathered back to the same token."""
+    cfg_d, lp = _setup(impl="dense", experts=4, top_k=4)
+    x = jax.random.normal(jax.random.PRNGKey(9), (1, 1, cfg_d.d_model))
+    out_d, _ = moe_ffn_dense(cfg_d, lp, x)
+    out_g, _, rows = moe_ffn_grouped(_grouped(cfg_d, 4), lp, x)
+    np.testing.assert_allclose(np.asarray(out_g), np.asarray(out_d),
+                               atol=1e-5)
+    assert int(rows) == 4
+
+
+def test_unwritten_expert_rows_never_reach_the_output(monkeypatch):
+    """The TPU kernel leaves the rows past ``sum(group_sizes)`` unwritten;
+    ``ragged_dot`` writes zeros there.  With NaN written there instead, a
+    share's output stays finite and equals the dense layer's over the held
+    experts alone."""
+    from repro.kernels import ops
+    real = ops.grouped_matmul
+
+    def nan_past_groups(lhs, rhs, group_sizes):
+        out = real(lhs, rhs, group_sizes)
+        row = jnp.arange(out.shape[0])[:, None]
+        return jnp.where(row < group_sizes.sum(), out, jnp.nan)
+
+    monkeypatch.setattr(ops, "grouped_matmul", nan_past_groups)
+    cfg_d, lp = _setup(impl="dense", experts=8, top_k=3)
+    x = jax.random.normal(jax.random.PRNGKey(10), (2, 24, cfg_d.d_model))
+    first, held = 2, 2
+    others = jnp.ones(8).at[first:first + held].set(0)[:, None, None]
+    only_held = dict(lp, w_down=lp["w_down"] * (1 - others))
+    want, _ = moe_ffn_dense(cfg_d, only_held, x)
+    out, _, rows = moe_ffn_grouped(_grouped(cfg_d, held, first),
+                                   _share(lp, first, held), x)
+    assert 0 < int(rows) < 2 * 24 * 3
+    assert np.isfinite(np.asarray(out)).all()
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)

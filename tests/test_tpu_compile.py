@@ -6,12 +6,17 @@ lowering rules these compiles enforce.
 All such compiles live in this one file: only one process at a time may load
 the TPU compiler's library, so the topology is described inside a fixture of
 this module (never at import), and the module skips where it cannot be."""
+import re
+import types
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.base import MoEConfig
 from repro.kernels import ops
+from repro.models.moe import moe_ffn_grouped
 
 VOCAB = 151936            # qwen3-1.7b's class count: the combine's C
 SEG = 32                  # the serving segment size
@@ -105,3 +110,21 @@ EXPERT_ROWS, HIDDEN, EXPERT_FF, HELD = 2 * 2048 * 8, 2304, 896, 16
 def test_grouped_matmul_compiles(one_chip, k, n):
     _compile(ops.grouped_matmul, _s(one_chip, (EXPERT_ROWS, k)),
              _s(one_chip, (HELD, k, n)), _s(one_chip, (HELD,), jnp.int32))
+
+
+def test_grouped_expert_layer_unsorts_without_a_scatter(one_chip):
+    """The whole grouped layer at 1 x 2048 tokens, 16 of 64 experts held,
+    k 8: its output rows return to their tokens by a gather, so no scatter
+    of d-wide float32 rows into the (tokens, hidden) output is left."""
+    tokens, routed, top_k = 2048, 64, 8
+    cfg = types.SimpleNamespace(moe=MoEConfig(
+        num_experts=HELD, top_k=top_k, d_ff_expert=EXPERT_FF, impl="grouped",
+        routed_experts=routed))
+    p = {"router": _s(one_chip, (HIDDEN, routed), jnp.bfloat16),
+         "w_gate": _s(one_chip, (HELD, HIDDEN, EXPERT_FF), jnp.bfloat16),
+         "w_up": _s(one_chip, (HELD, HIDDEN, EXPERT_FF), jnp.bfloat16),
+         "w_down": _s(one_chip, (HELD, EXPERT_FF, HIDDEN), jnp.bfloat16)}
+    hlo = _compile(lambda p, x: moe_ffn_grouped(cfg, p, x), p,
+                   _s(one_chip, (1, tokens, HIDDEN)))
+    assert not re.search(
+        rf"= f32\[{tokens},{HIDDEN}\]\{{[^}}]*\}} scatter\(", hlo)
