@@ -1,134 +1,26 @@
-"""Serving hot-path A/B: seed path vs pipelined engine vs coalescing scheduler.
+"""Replay a recorded request trace against a fake-device serving system.
 
-Overhead-dominated regime (paper §IV.A): fake workers sharing ONE device make
-prediction cost ~nothing, isolating the serving machinery — batching, queues,
-transfers, combination.  Scenarios:
+    PYTHONPATH=src python -m benchmarks.serving_hotpath --replay-trace PATH \
+        [--replay-speed X]
 
-  * ``seed``        per-member messages (``device_combine=False``), one
-                    request in flight — the vendored seed behavior;
-  * ``pipelined``   the PR-1 engine: device-resident partial combine +
-                    multi-request window, but batches formed strictly within
-                    one (request, segment) pair (``coalesce=False``);
-  * ``coalesced``   the PR-2 coalescing scheduler: cross-request continuous
-                    batching with span scatter descriptors;
-  * ``many_small``  the north-star workload — many concurrent requests each
-                    far smaller than a segment, run with REAL (tiny) models
-                    so padding waste costs real compute.  Compares the PR-1
-                    engine against the coalescing scheduler and reports
-                    padding efficiency (valid rows / dispatched rows);
-  * ``mixed_priority``  the SLO workload (ISSUEs 3/5, ROADMAP items a/e/k):
-                    a bulk scan saturates the admission queues while small
-                    latency-sensitive requests trickle in.  Runs the same
-                    trace twice — all-normal (strict FIFO, the PR-2
-                    behavior) vs the small requests at ``priority="high"``
-                    — and reports per-class p50/p99 latency plus total
-                    segments/sec.  With the chunk-granular dispatch queue
-                    (ISSUE 5) a high-priority chunk jumps bulk chunks
-                    already *flushed* into the predictor pipeline, so the
-                    p50 (not just the p99 tail) approaches the queue-jump
-                    ideal: ``hp_p50_improvement`` gates it;
-  * ``skewed_load``  the elasticity workload (ISSUE 4, ROADMAP items c/g):
-                    one hot member under a 4:1 per-member request skew,
-                    served by a slow batch-8 instance (co-located with the
-                    cold member) and a fast batch-128 data-parallel sibling.
-                    Fake workers with *simulated device time*
-                    (``fake_delay_us`` per compiled batch — the sleep
-                    releases the GIL, so worker parallelism and service
-                    rates are deterministic on any host) isolate the
-                    scheduling effect: static striping leaves half the hot
-                    member's segments queued behind the slow instance while
-                    the fast sibling idles.  Runs the identical trace with
-                    the work-stealing fast path off vs on and reports the
-                    throughput ratio;
-  * ``overload_brownout``  the overload workload (ISSUE 7, DESIGN.md §11):
-                    a cheap and a heavy member on simulated device time,
-                    requests paced at ~3x the heavy member's service rate.
-                    Runs the identical trace twice — plain system (queues
-                    grow without bound, every request waits behind the
-                    heavy backlog) vs the brownout controller + admission
-                    byte budget (pressure crosses the hysteresis band,
-                    in-flight requests are demoted to the cheap tier and
-                    new ones planned against it; anything past the budget
-                    is shed with a *typed* ``Overloaded`` + Retry-After).
-                    Reports ``completed_or_shed_ratio`` (every request
-                    either resolves with a quality-stamped result or a
-                    typed rejection — nothing hangs or dies untyped) and
-                    ``brownout_p99_improvement`` (normal-class p99 off/on);
-  * ``fault_recovery``  the chaos workload (ISSUE 6, DESIGN.md §10): two
-                    data-parallel siblings of a hot member on simulated
-                    device time, a ``FaultPlan`` killing one sibling's
-                    predictor a few chunks into the trace.  The supervisor
-                    must quarantine the instance and replay its outstanding
-                    units on the survivor: the scenario reports the
-                    completed-at-full-quality ratio, the crash-to-replay
-                    recovery latency, and a ``recovery_ok`` verdict.
-
-Acceptance (ISSUE 2): many_small coalesced >= 1.5x the PR-1 engine
-segments/sec; single large-request throughput within 5% (the
-``large_request_ratio``); padding efficiency reported in BENCH_serving.json.
-Acceptance (ISSUE 3): high-priority p99 improves >= 3x over FIFO while total
-segments/sec stays within 10% (``mixed_priority.hp_p99_improvement`` /
-``.throughput_ratio`` in BENCH_serving.json, gated by check_regression.py).
-Acceptance (ISSUE 4): work stealing >= 1.3x throughput under the 4:1 skew
-(``skewed_load.steal_throughput_ratio``, gated by check_regression.py).
-Acceptance (ISSUE 5): with the chunk-granular dispatch queue, high-priority
-p50 improves >= 4x over strict FIFO (``mixed_priority.hp_p50_improvement``)
-while hp_p99_improvement and throughput_ratio hold their floors.
-Acceptance (ISSUE 6): killing one sibling mid-trace loses zero requests
-(``fault_recovery.completed_ratio`` == 1.0 at full quality) and recovery
-lands within a second (``fault_recovery.recovery_ok`` == 1.0), both gated
-by check_regression.py.
-  * ``sim_fidelity``  the calibration workload (ISSUE 8, DESIGN.md §12):
-                    a real run on simulated device time records its own
-                    request trace (``system.trace_recorder``) and feeds a
-                    LiveBench; the trace then replays in the discrete-event
-                    simulator (``repro.serving.sim``) against a
-                    ``ServiceModel`` fitted from that LiveBench snapshot.
-                    Reports sim/real throughput and p99 ratios plus a
-                    ``fidelity_ok`` verdict (both within 20%).
-
-Every scenario draws its inputs from ``--seed`` (recorded as ``rng_seed``
-in BENCH_serving.json); ``--scenario NAME`` (repeatable) runs a subset —
-the serving-smoke CI job uses it to stay within its time budget.
-``--replay-trace PATH`` replays a trace recorded with
-``launch/serve.py --record-trace`` against a fake-device system instead.
-
-Acceptance (ISSUE 7): under 3x saturation every request completes or is
-typed-rejected (``overload_brownout.completed_or_shed_ratio`` == 1.0) and
-brownout improves normal-class p99 >= 2x over the uncontrolled run
-(``overload_brownout.brownout_p99_improvement``), both gated by
-check_regression.py.
-Acceptance (ISSUE 8): the simulator reproduces the real mixed-delay run's
-throughput and pooled p99 within 20% (``sim_fidelity.fidelity_ok``, gated
-by check_regression.py).
-  * ``quantized_members``  the quantization workload (ISSUE 10, DESIGN.md
-                    §14): two legs.  The *speedup* leg runs the heavy-member
-                    trace twice on simulated device time — fp32 vs int8 —
-                    with the int8 leg's ``fake_delay_us`` scaled by the
-                    dtype-aware ``AnalyticBench`` memory-term ratio (weight
-                    streaming dominates heavy members, so narrow params cut
-                    per-batch device time ~3x; the serving machinery around
-                    it is real either way).  The *parity* leg runs REAL tiny
-                    models through the fused dequant-weight-accumulate
-                    epilogue (``combine="pallas"``, int8 members) against
-                    the fp32 reference and checks the combined output and a
-                    member-subset output stay within int8 tolerance.
-Acceptance (ISSUE 10): quantized members >= 1.3x segments/sec on the
-heavy-member scenario (``quantized_members.quant_speedup``) with combine
-output within tolerance of the fp32 reference
-(``quantized_members.quant_parity_ok``), both gated by check_regression.py.
+The trace is one that ``launch/serve.py --record-trace`` (or
+``system.trace_recorder``) wrote.  Each request is submitted with its
+recorded priority, deadline and member subset, paced by the recorded
+inter-arrival gaps, to an ``InferenceSystem`` of fake workers on one CPU
+device, so the serving machinery (admission, batching, combine) runs
+without model compute.  Prints completed requests per second and the
+p50/p99 latency.  These are host-clock numbers of the serving machinery,
+not device speeds: speed is measured on the chip by ``benchmarks/chip``.
 """
 from __future__ import annotations
 
-import statistics
+import threading
 import time
 
 import numpy as np
 
-from benchmarks.seed_baseline import SeedSystem
 from repro.configs import ensemble
 from repro.core import AllocationMatrix, host_cpus
-from repro.serving import segments as seg
 from repro.serving.segments import PredictOptions
 
 GiB = 1024 ** 3
@@ -138,500 +30,13 @@ def _pctl(values, q: float) -> float:
     return float(np.percentile(np.asarray(values, np.float64), q))
 
 
-def _measure(system, X, requests: int, pipelined: bool) -> dict:
-    n_segments = seg.num_segments(X.shape[0], system.segment_size)
-    system.predict(X)                      # warm
-    if pipelined:
-        system.timers.reset()
-    msg0 = system.accumulator.data_messages
-    t0 = time.perf_counter()
-    if pipelined:                          # overlap through the window
-        handles = [system.predict_async(X) for _ in range(requests)]
-        for h in handles:
-            h.result(600.0)
-    else:                                  # seed path: requests serialize
-        for _ in range(requests):
-            system.predict(X)
-    dt = time.perf_counter() - t0
-    out = {
-        "requests": requests,
-        "segments_per_request": n_segments,
-        "seconds": dt,
-        "segments_per_sec": requests * n_segments / dt,
-        "samples_per_sec": requests * X.shape[0] / dt,
-        "messages_per_request":
-            (system.accumulator.data_messages - msg0) / requests,
-        "stage_timings": system.stage_timings() if pipelined else {},
-    }
-    if pipelined:
-        out["counters"] = system.serving_counters()
-        out["padding_efficiency"] = out["counters"]["padding_efficiency"]
-    return out
-
-
-def _measure_many_small(system, Xs, rounds: int) -> dict:
-    """Submit ``rounds`` waves of the small concurrent requests through the
-    in-flight window and measure aggregate segments/sec + padding."""
-    for X in Xs[:4]:                       # warm the small pow2 bucket shapes
-        system.predict(X)
-    system.predict(np.concatenate(Xs, axis=0)[:32])   # warm the full batch
-    system.timers.reset()
-    msg0 = system.accumulator.data_messages
-    n_requests = rounds * len(Xs)
-    n_segments = sum(seg.num_segments(x.shape[0], system.segment_size)
-                     for x in Xs) * rounds
-    n_samples = sum(x.shape[0] for x in Xs) * rounds
-    t0 = time.perf_counter()
-    handles = []
-    for _ in range(rounds):
-        handles.extend(system.predict_async(x) for x in Xs)
-    for h in handles:
-        h.result(600.0)
-    dt = time.perf_counter() - t0
-    counters = system.serving_counters()
-    return {
-        "requests": n_requests,
-        "segments": n_segments,
-        "seconds": dt,
-        "segments_per_sec": n_segments / dt,
-        "samples_per_sec": n_samples / dt,
-        "messages_per_request":
-            (system.accumulator.data_messages - msg0) / n_requests,
-        "padding_efficiency": counters["padding_efficiency"],
-        "counters": counters,
-        "queue_depth": {k: v for k, v in system.serving_gauges().items()
-                        if k.startswith("queue_depth.")},
-        "stage_timings": system.stage_timings(),
-    }
-
-
-def _measure_mixed_priority(system, bulk_X, small_Xs, rounds: int,
-                            high_priority: bool) -> dict:
-    """Sustained-load SLO trace: every bulk round is submitted up front
-    (normal priority) so the backlog persists for the whole window, and the
-    small requests are *paced* — submitted at fixed wall-clock intervals
-    from short-lived threads while the backlog drains.  Under strict FIFO a
-    small request's latency is the remaining bulk backlog at its submit
-    time (seconds); with priority admission + the chunk-granular dispatch
-    queue it is the non-preemptible head (the chunk on the device plus the
-    dispatch-ahead window — tens of ms).  The pace is calibrated from a
-    measured solo bulk scan so the small trace spans ~60% of the backlog
-    window on any host speed."""
-    import threading
-
-    opts = PredictOptions(priority="high" if high_priority else "normal")
-    system.predict(bulk_X[:system.segment_size])     # warm shapes
-    tb = time.perf_counter()
-    system.predict(bulk_X)                           # calibrate drain time
-    bulk_s = time.perf_counter() - tb
-    for x in small_Xs[:2]:
-        system.predict(x, options=opts)
-    seg_sz = system.segment_size
-    n_segments = rounds * (seg.num_segments(bulk_X.shape[0], seg_sz) +
-                           sum(seg.num_segments(x.shape[0], seg_sz)
-                               for x in small_Xs))
-    n_smalls = rounds * len(small_Xs)
-    pace = bulk_s * rounds * 0.6 / n_smalls
-    lat_high, lat_bulk = [], []
-    lock = threading.Lock()
-
-    def one_small(x):
-        t1 = time.perf_counter()
-        system.predict(x, options=opts, timeout=600.0)
-        with lock:
-            lat_high.append(time.perf_counter() - t1)
-
-    t0 = time.perf_counter()
-    bulk_handles = [system.predict_async(bulk_X) for _ in range(rounds)]
-    threads = []
-    for i in range(n_smalls):
-        time.sleep(pace)
-        t = threading.Thread(target=one_small,
-                             args=(small_Xs[i % len(small_Xs)],))
-        t.start()
-        threads.append(t)
-    for t in threads:
-        t.join()
-    for h in bulk_handles:
-        h.result(600.0)
-        lat_bulk.append(time.perf_counter() - t0)
-    dt = time.perf_counter() - t0
-    return {
-        "rounds": rounds,
-        "seconds": dt,
-        "segments_per_sec": n_segments / dt,
-        "high": {"requests": len(lat_high),
-                 "p50_ms": 1e3 * _pctl(lat_high, 50),
-                 "p99_ms": 1e3 * _pctl(lat_high, 99)},
-        "bulk": {"requests": len(lat_bulk),
-                 "p50_ms": 1e3 * _pctl(lat_bulk, 50),
-                 "p99_ms": 1e3 * _pctl(lat_bulk, 99)},
-    }
-
-
-def _measure_skewed(cfgs, params, devs, seq: int, requests: int,
-                    fake_delay_us: int, steal: bool, seed: int = 0) -> dict:
-    """One skewed_load pass: 4:1 per-member request skew against a hot
-    member with heterogeneous data-parallel instances (d0@8 slow, d1@128
-    fast); the cold member rides the slow device.  With ``steal`` the
-    reconfiguration controller's fast path re-routes the slow instance's
-    backlog (expected-row maps move between the device combiners)."""
-    from repro.serving.control import ReconfigController
-    from repro.serving.system import InferenceSystem
-
-    seg_sz = 128
-    A = np.array([[8, 128], [128, 0]])
-    alloc = AllocationMatrix(devs, [c.name for c in cfgs], A)
-    srng = np.random.default_rng([seed, 4])
-    member_lists = [[0] if i % 5 < 4 else [1] for i in range(requests)]
-    Xs = [srng.integers(0, 512, (seg_sz, seq)).astype(np.int32)
-          for _ in member_lists]
-    with InferenceSystem(cfgs, params, alloc, segment_size=seg_sz,
-                         max_seq=seq, fake=True,
-                         fake_delay_us=fake_delay_us,
-                         max_in_flight=requests, max_wait_us=200) as system:
-        controller = ReconfigController(
-            system, replan=False, steal=steal, steal_interval_s=0.001,
-            steal_threshold=1, steal_max=64)
-        controller.start()
-        for _ in range(3):                 # warm the live latency profile
-            system.predict(Xs[0], members=[0])
-            system.predict(Xs[1], members=[1])
-        t0 = time.perf_counter()
-        handles = [system.predict_async(x, members=m)
-                   for x, m in zip(Xs, member_lists)]
-        for h in handles:
-            h.result(600.0)
-        dt = time.perf_counter() - t0
-        stolen = controller.counters["stolen"]
-    return {
-        "requests": requests,
-        "seconds": dt,
-        "segments_per_sec": requests / dt,   # single-segment requests
-        "stolen_descriptors": stolen,
-    }
-
-
-def _measure_fault_recovery(cfgs, params, seq: int, requests: int,
-                            fake_delay_us: int, seed: int = 0) -> dict:
-    """One chaos pass (ISSUE 6): member 0 runs two equal data-parallel
-    siblings (d0/d1); a FaultPlan kills the d1 sibling's predictor after 3
-    chunks.  Simulated device time makes the service rates — and thus the
-    crash position in the trace — deterministic on any host.  A 1 ms
-    watcher thread timestamps the crash (``worker_crashes`` counter, set on
-    the dying thread) and the recovery (``segments_replayed``, set when the
-    supervisor resubmits the dead worker's outstanding units), so
-    ``recovery_s`` is the supervisor's crash-to-replay latency."""
-    import threading
-
-    from repro.serving.faults import FaultPlan, FaultSpec
-    from repro.serving.system import InferenceSystem
-
-    seg_sz = 64
-    devs = host_cpus(2, memory_bytes=8 * GiB)
-    A = np.array([[seg_sz, seg_sz], [seg_sz, 0]])
-    alloc = AllocationMatrix(devs, [c.name for c in cfgs], A)
-    fp = FaultPlan(FaultSpec(stage="predictor", kind="raise", after=3,
-                             worker="w1.0"))
-    srng = np.random.default_rng([seed, 6])
-    Xs = [srng.integers(0, 512, (seg_sz, seq)).astype(np.int32)
-          for _ in range(requests)]
-    marks: dict = {}
-    with InferenceSystem(cfgs, params, alloc, segment_size=seg_sz,
-                         max_seq=seq, fake=True,
-                         fake_delay_us=fake_delay_us,
-                         max_in_flight=requests, supervise=True,
-                         supervise_interval_s=0.01,
-                         fault_plan=fp) as system:
-
-        def watch():
-            while not marks.get("stop"):
-                c = system.serving_counters()
-                if "t_crash" not in marks and c.get("worker_crashes", 0):
-                    marks["t_crash"] = time.perf_counter()
-                if c.get("segments_replayed", 0):
-                    marks.setdefault("t_crash", time.perf_counter())
-                    marks["t_recovered"] = time.perf_counter()
-                    return
-                time.sleep(0.001)
-
-        wt = threading.Thread(target=watch)
-        wt.start()
-        t0 = time.perf_counter()
-        handles = [system.predict_async(x) for x in Xs]
-        full_quality = 0
-        for h in handles:
-            y = h.result(600.0)           # raises on any lost request
-            if y.shape[0] == seg_sz and h.quality == 1.0:
-                full_quality += 1
-        dt = time.perf_counter() - t0
-        marks["stop"] = True
-        wt.join(5.0)
-        counters = system.serving_counters()
-    recovery_s = (marks["t_recovered"] - marks["t_crash"]
-                  if "t_recovered" in marks else float("inf"))
-    completed_ratio = full_quality / requests
-    recovery_ok = float(completed_ratio == 1.0 and
-                        counters.get("quarantines", 0) == 1 and
-                        recovery_s <= 1.0)
-    return {
-        "requests": requests,
-        "seconds": dt,
-        "completed_ratio": completed_ratio,
-        "recovery_s": recovery_s,
-        "recovery_ok": recovery_ok,
-        "segments_replayed": counters.get("segments_replayed", 0),
-        "worker_crashes": counters.get("worker_crashes", 0),
-    }
-
-
-def _measure_overload_brownout(cfgs, params, seq: int, requests: int,
-                               pace_s: float, cheap_delay_us: int,
-                               heavy_delay_us: int, brownout: bool,
-                               seed: int = 0) -> dict:
-    """One overload pass (ISSUE 7): member 0 cheap, member 1 heavy (each on
-    its own simulated device), requests paced at ~3x the heavy member's
-    service rate.  With ``brownout`` a :class:`BrownoutController` (explicit
-    two-level tier table: full ensemble, then the cheap member alone) and an
-    admission byte budget are attached; without, the plain system queues
-    without bound.  Per-request latency comes from the system's own
-    normal-class snapshot, so both passes measure identically."""
-    from repro.serving.admission import AdmissionBudget
-    from repro.serving.segments import Overloaded
-    from repro.serving.system import InferenceSystem
-
-    seg_sz = 64
-    devs = host_cpus(2, memory_bytes=8 * GiB)
-    A = np.array([[seg_sz, 0], [0, seg_sz]])
-    alloc = AllocationMatrix(devs, [c.name for c in cfgs], A)
-    srng = np.random.default_rng([seed, 7])
-    Xs = [srng.integers(0, 512, (seg_sz, seq)).astype(np.int32)
-          for _ in range(requests)]
-    budget = (AdmissionBudget(max_bytes=40 * seg_sz * seq * 4)
-              if brownout else None)
-    with InferenceSystem(cfgs, params, alloc, segment_size=seg_sz,
-                         max_seq=seq, fake=True,
-                         fake_delay_us=cheap_delay_us,
-                         max_in_flight=requests,
-                         admission_budget=budget) as system:
-        for w in system.instances(1):      # heterogeneous member costs
-            w.fake_delay_us = heavy_delay_us
-        ctl = None
-        if brownout:
-            from repro.serving.control import BrownoutController
-            ctl = BrownoutController(
-                system, tiers=[(0, 1), (0,)], high=1.0, low=0.2,
-                up_ticks=2, down_ticks=1000, interval_s=0.002,
-                depth_ref=8.0).start()
-        handles, shed = [], 0
-        t0 = time.perf_counter()
-        for x in Xs:
-            try:
-                handles.append(system.predict_async(x))
-            except Overloaded:
-                shed += 1                   # typed, fail-fast, retryable
-            time.sleep(pace_s)
-        completed = 0
-        qualities = []
-        for h in handles:
-            y = h.result(600.0)             # raises on any lost request
-            if y.shape[0] == seg_sz:
-                completed += 1
-                qualities.append(float(h.quality))
-        dt = time.perf_counter() - t0
-        lat = system.latency_snapshot().get("normal", {})
-        counters = system.serving_counters()
-        out = {
-            "requests": requests,
-            "seconds": dt,
-            "completed": completed,
-            "shed": shed,
-            "completed_or_shed_ratio": (completed + shed) / requests,
-            "p50_ms": lat.get("p50_ms", 0.0),
-            "p99_ms": lat.get("p99_ms", 0.0),
-            "mean_quality": (float(np.mean(qualities))
-                             if qualities else 0.0),
-            "requests_demoted": counters.get("requests_demoted", 0),
-            "admission_rejections": counters.get("admission_rejections", 0),
-            "brownout_level": ctl.level if ctl is not None else 0,
-            "brownout_transitions": ctl.transitions if ctl is not None else 0,
-        }
-    return out
-
-
-def _measure_tracing_overhead(cfgs, params, alloc, X, seq: int,
-                              requests: int) -> dict:
-    """Tracing-on vs tracing-off on the fake-worker hot path (ISSUE 9).
-
-    Same configuration as the core coalesced scenario.  ONE system,
-    toggling the runtime ``tracer.enabled`` flag, so both modes share
-    threads, compiled shapes and allocator state.  Machine throughput
-    drifts at the few-percent scale over seconds (shared hosts), which
-    is the same magnitude as the budget being gated, so the estimator
-    has to be burst-robust: waves alternate off/on (order flipped every
-    pair, so slow drift hits both modes equally), each estimate is the
-    ratio of 10%-TRIMMED per-mode sums (a burst landing on a few waves
-    is discarded instead of averaged in), and the reported ratio is the
-    median of ``reps`` independent estimates.  ``overhead_ratio`` is
-    the span layer's whole cost with the flight recorder enabled;
-    ``overhead_ok`` asserts the <= 5% budget (check_regression.py gates
-    it at 1.0)."""
-    from repro.serving.system import InferenceSystem
-
-    n_segments = seg.num_segments(X.shape[0], 128)
-    waves = max(2, requests // 4)          # concurrent requests per wave
-    reps, alternations = 3, 40
-    times = {"off": [], "on": []}
-    with InferenceSystem(cfgs, params, alloc, segment_size=128,
-                         max_seq=seq, fake=True, device_combine=True,
-                         max_in_flight=4, coalesce=True,
-                         tracing=False) as system:
-
-        def wave() -> float:
-            t0 = time.perf_counter()
-            handles = [system.predict_async(X) for _ in range(waves)]
-            for h in handles:
-                h.result(600.0)
-            return time.perf_counter() - t0
-
-        def trimmed_sum(xs: list) -> float:
-            s = sorted(xs)
-            k = len(s) // 10
-            return sum(s[k:len(s) - k])
-
-        def estimate() -> float:
-            t = {"off": [], "on": []}
-            for i in range(alternations):
-                order = ("off", "on") if i % 2 == 0 else ("on", "off")
-                for mode in order:
-                    system.tracer.enabled = mode == "on"
-                    dt = wave()
-                    t[mode].append(dt)
-                    times[mode].append(dt)
-            return trimmed_sum(t["on"]) / trimmed_sum(t["off"])
-
-        for _ in range(2):                 # warm threads + slot rings
-            wave()
-        ratios = [estimate() for _ in range(reps)]
-        trace_events = sum(len(evs)
-                           for evs in system.tracer.tracks().values())
-    ratio = statistics.median(ratios)
-    per_wave = waves * n_segments
-    return {"off_segments_per_sec":
-            per_wave / statistics.median(times["off"]),
-            "on_segments_per_sec":
-            per_wave / statistics.median(times["on"]),
-            "estimate_ratios": ratios,
-            "overhead_ratio": ratio,
-            "overhead_ok": float(ratio <= 1.05),
-            "trace_events": trace_events}
-
-
-def _measure_sim_fidelity(cfgs, params, seq: int, requests: int,
-                          pace_s: float, cheap_delay_us: int,
-                          heavy_delay_us: int, seed: int = 0) -> dict:
-    """Calibration harness for the discrete-event simulator (DESIGN.md §12).
-
-    One real pass on simulated device time (two members with heterogeneous
-    ``fake_delay_us``, each on its own device, all requests exactly one
-    compiled batch so LiveBench attributes every observation to the bucket
-    it ran in), recording the offered trace and fitting a
-    :class:`ServiceModel` from the LiveBench EWMA the run itself produced.
-    The recorded trace then replays in the simulator on the same
-    allocation, and the pooled p99 + request throughput must land within
-    20% of the real run (``fidelity_ok``, gated by check_regression.py) —
-    the evidence that conclusions drawn in-sim (forecast-fed replanning,
-    dispatch-ahead tuning, EDF) transfer to the live engine.
-
-    The pace runs the heavy member slightly *past* saturation on purpose:
-    the p99 tail is then dominated by deterministic backlog growth — which
-    the simulator reproduces exactly from the recorded arrival times —
-    rather than by host scheduling jitter, which no deterministic model
-    reproduces.  (At comfortable utilization the real tail is pure sleep/
-    thread jitter and the comparison measures the host, not the sim.)"""
-    import threading
-
-    from repro.serving.control import LiveBench
-    from repro.serving.sim import ServiceModel, SimSystem
-    from repro.serving.system import InferenceSystem
-    from repro.serving.trace import TraceRecorder
-
-    seg_sz = 64
-    devs = host_cpus(2, memory_bytes=8 * GiB)
-    A = np.array([[seg_sz, 0], [0, seg_sz]])
-    alloc = AllocationMatrix(devs, [c.name for c in cfgs], A)
-    srng = np.random.default_rng([seed, 8])
-    Xs = [srng.integers(0, 512, (seg_sz, seq)).astype(np.int32)
-          for _ in range(requests)]
-    live = LiveBench(cfgs)
-    rec = TraceRecorder()
-    lat: list = []
-    lock = threading.Lock()
-    with InferenceSystem(cfgs, params, alloc, segment_size=seg_sz,
-                         max_seq=seq, fake=True,
-                         fake_delay_us=cheap_delay_us,
-                         max_in_flight=requests, dispatch_ahead=4,
-                         max_wait_us=200) as system:
-        for w in system.instances(1):      # heterogeneous member costs
-            w.fake_delay_us = heavy_delay_us
-        system.set_profiler(live)
-        for m in (0, 1):                   # warm shapes + the EWMA prior
-            system.predict(Xs[0], members=[m])
-            system.predict(Xs[1], members=[m])
-        system.trace_recorder = rec        # record only the measured trace
-
-        def waiter(h, t1):
-            h.result(600.0)
-            with lock:
-                lat.append(time.perf_counter() - t1)
-
-        threads = []
-        t0 = time.perf_counter()
-        for i, x in enumerate(Xs):
-            h = system.predict_async(x, members=[i % 2])
-            th = threading.Thread(target=waiter,
-                                  args=(h, time.perf_counter()))
-            th.start()
-            threads.append(th)
-            time.sleep(pace_s)
-        for th in threads:
-            th.join()
-        real_dt = time.perf_counter() - t0
-        snapshot = live.snapshot()
-
-    real = {"requests": requests, "seconds": real_dt,
-            "req_per_s": requests / real_dt,
-            "p50_ms": 1e3 * _pctl(lat, 50), "p99_ms": 1e3 * _pctl(lat, 99)}
-
-    svc = ServiceModel.from_livebench(snapshot)
-    sim = SimSystem.from_alloc(alloc, svc, segment_size=seg_sz,
-                               dispatch_ahead=4, max_wait_us=200)
-    trace = rec.events()
-    sim.run(trace)
-    r = sim.results()
-    sim_out = {"requests": len(trace), "req_per_s": r["throughput_req_per_s"],
-               "p50_ms": r["p50_ms"], "p99_ms": r["p99_ms"],
-               "completed": r["completed"], "failed": r["failed"]}
-    thr_ratio = sim_out["req_per_s"] / real["req_per_s"]
-    p99_ratio = sim_out["p99_ms"] / max(real["p99_ms"], 1e-9)
-    tol = 0.20
-    fidelity_ok = float(abs(thr_ratio - 1.0) <= tol and
-                        abs(p99_ratio - 1.0) <= tol and
-                        r["completed"] == len(trace))
-    return {"real": real, "sim": sim_out, "trace_requests": len(trace),
-            "throughput_ratio": thr_ratio, "p99_ratio": p99_ratio,
-            "tolerance": tol, "fidelity_ok": fidelity_ok}
-
-
 def replay_trace(path: str, *, seq: int = 16, workers: int = 2,
-                 speed: float = 1.0, csv: bool = True) -> dict:
+                 speed: float = 1.0) -> dict:
     """Replay a recorded request trace (``--record-trace`` /
     ``system.trace_recorder``) against a real fake-device system,
     preserving per-request priority, deadline and member subsets and
     pacing submissions by the recorded inter-arrival gaps (divided by
     ``speed``).  The offline twin of the simulator's ``sim.run(trace)``."""
-    import threading
-
     import jax
     import repro.models as M
     from repro.serving.system import InferenceSystem
@@ -694,385 +99,25 @@ def replay_trace(path: str, *, seq: int = 16, workers: int = 2,
            "req_per_s": len(lat) / dt,
            "p50_ms": 1e3 * _pctl(lat, 50) if lat else 0.0,
            "p99_ms": 1e3 * _pctl(lat, 99) if lat else 0.0}
-    if csv:
-        print(f"serving_hotpath:replay.req_per_s,{out['req_per_s']:.1f},")
-        print(f"serving_hotpath:replay.p50/p99_ms,{out['p50_ms']:.1f},"
-              f"{out['p99_ms']:.1f}")
+    print(f"serving_hotpath:replay.req_per_s,{out['req_per_s']:.1f},")
+    print(f"serving_hotpath:replay.p50/p99_ms,{out['p50_ms']:.1f},"
+          f"{out['p99_ms']:.1f}")
     return out
-
-
-def _measure_quantized_members(cfgs, params, seq: int, requests: int,
-                               heavy_delay_us: int, seed: int = 0) -> dict:
-    """One quantization pass (ISSUE 10, DESIGN.md §14).
-
-    Speedup leg: both members heavy (``heavy_delay_us`` simulated device
-    time per compiled batch) on one shared device; the int8 run scales the
-    delay by the dtype-aware ``AnalyticBench`` *memory-term* ratio — heavy
-    members are weight-streaming-bound on accelerators, and this is the
-    same term the allocator prices quantized members with — so the measured
-    segments/sec ratio isolates what narrow params buy while queues,
-    staging, and combine run for real.
-
-    Parity leg: real tiny models, fp32 system vs int8 system with the
-    device-resident pallas combine (the fused dequant-weight-accumulate
-    epilogue), full ensemble and a member subset; ``quant_parity_ok``
-    verdicts both within int8 tolerance.
-    """
-    from repro.core.bench import AnalyticBench
-    from repro.kernels import quant as kq
-    from repro.serving.system import InferenceSystem
-
-    seg_sz = 64
-    devs = host_cpus(1, memory_bytes=8 * GiB)
-    A = np.array([[seg_sz, seg_sz]])
-    alloc = AllocationMatrix(devs, [c.name for c in cfgs], A)
-    srng = np.random.default_rng([seed, 10])
-    Xs = [srng.integers(0, 512, (seg_sz, seq)).astype(np.int32)
-          for _ in range(requests)]
-
-    bench = AnalyticBench(cfgs, seq=seq)
-    ratio = (sum(bench.bytes_moved(c, seg_sz, "int8") for c in cfgs) /
-             sum(bench.bytes_moved(c, seg_sz) for c in cfgs))
-    out: dict = {"roofline_ratio": ratio}
-    for mode, dts, delay in (
-            ("fp32", None, heavy_delay_us),
-            ("int8", ["int8"] * len(cfgs), int(heavy_delay_us * ratio))):
-        with InferenceSystem(cfgs, params, alloc, segment_size=seg_sz,
-                             max_seq=seq, fake=True, fake_delay_us=delay,
-                             max_in_flight=requests,
-                             member_dtypes=dts) as system:
-            t0 = time.perf_counter()
-            handles = [system.predict_async(x) for x in Xs]
-            for h in handles:
-                h.result(600.0)
-            dt = time.perf_counter() - t0
-        out[mode] = {"requests": requests, "seconds": dt,
-                     "fake_delay_us": delay,
-                     "segments_per_sec": requests / dt}
-    out["quant_speedup"] = (out["int8"]["segments_per_sec"] /
-                            out["fp32"]["segments_per_sec"])
-
-    # ---- parity leg: real tiny models through the fused epilogue ----------
-    Xp = srng.integers(0, 512, (2 * seg_sz, seq)).astype(np.int32)
-
-    def real_run(dts):
-        with InferenceSystem(cfgs, params, alloc, segment_size=seg_sz,
-                             max_seq=seq, combine="pallas",
-                             member_dtypes=dts) as system:
-            y_full = system.predict(Xp)
-            y_sub = system.predict(Xp[:seg_sz], members=[0])
-            staged = sum(w.timers.counters.get("h2d_staged", 0)
-                         for w in system.workers)
-        return y_full, y_sub, staged
-
-    ref_full, ref_sub, _ = real_run(None)
-    q_full, q_sub, staged = real_run(["int8"] * len(cfgs))
-
-    def rel_err(y, yref):
-        return float(np.abs(y - yref).max() /
-                     max(np.abs(yref).max(), 1e-6))
-
-    out["parity_rel_err"] = rel_err(q_full, ref_full)
-    out["subset_rel_err"] = rel_err(q_sub, ref_sub)
-    out["h2d_staged"] = int(staged)
-    out["quant_parity_ok"] = float(out["parity_rel_err"] < 0.05
-                                   and out["subset_rel_err"] < 0.05)
-    return out
-
-
-SCENARIOS = ("core", "many_small", "mixed_priority", "skewed_load",
-             "fault_recovery", "overload_brownout", "sim_fidelity",
-             "tracing_overhead", "quantized_members")
-
-
-def run(csv=True, n_samples=2048, seq=16, requests=24, workers=4,
-        small_concurrency=48, small_rounds=8, small_max_wait_us=2000,
-        mixed_rounds=3, mixed_smalls=8, mixed_bulk=1024,
-        skew_requests=40, skew_delay_us=4000,
-        fault_requests=32, fault_delay_us=4000,
-        overload_requests=120, overload_pace_s=0.00133,
-        overload_cheap_us=400, overload_heavy_us=4000,
-        fidelity_requests=150, fidelity_pace_s=0.008,
-        fidelity_cheap_us=10000, fidelity_heavy_us=20000,
-        quant_requests=32, quant_delay_us=8000,
-        seed=0, scenarios=None):
-    import jax
-    import repro.models as M
-    from repro.serving.system import InferenceSystem
-
-    sel = set(SCENARIOS) if not scenarios else set(scenarios)
-    unknown = sel - set(SCENARIOS)
-    if unknown:
-        raise ValueError(f"unknown scenarios {sorted(unknown)} "
-                         f"(expected a subset of {list(SCENARIOS)})")
-
-    cfgs = ensemble("ENS4")[:workers]
-    rng = jax.random.PRNGKey(seed)
-    params = [M.init_params(jax.random.fold_in(rng, i), c)
-              for i, c in enumerate(cfgs)]
-    devs = host_cpus(1, memory_bytes=8 * GiB)       # ONE shared device
-    A = np.full((1, len(cfgs)), 8)
-    alloc = AllocationMatrix(devs, [c.name for c in cfgs], A)
-    X = np.random.default_rng([seed, 0]).integers(
-        0, 512, (n_samples, seq)).astype(np.int32)
-    small_cfgs = cfgs[:2]
-    small_params = params[:2]
-    A_small = np.full((1, len(small_cfgs)), 16)
-    alloc_small = AllocationMatrix(devs, [c.name for c in small_cfgs], A_small)
-
-    results = {"rng_seed": seed, "scenarios": sorted(sel)}
-    if "core" in sel:
-        with SeedSystem(cfgs, alloc, max_seq=seq) as system:
-            results["seed"] = _measure(system, X, requests, pipelined=False)
-        for name, coalesce in (("pipelined", False), ("coalesced", True)):
-            with InferenceSystem(cfgs, params, alloc, segment_size=128,
-                                 max_seq=seq, fake=True, device_combine=True,
-                                 max_in_flight=4, coalesce=coalesce) as system:
-                results[name] = _measure(system, X, requests, pipelined=True)
-
-        results["speedup"] = (results["pipelined"]["segments_per_sec"] /
-                              results["seed"]["segments_per_sec"])
-        # single large requests: coalescing must not regress the PR-1 engine
-        results["large_request_ratio"] = (
-            results["coalesced"]["segments_per_sec"] /
-            results["pipelined"]["segments_per_sec"])
-
-    # ---- many-small-requests: the north-star workload (real tiny models) ----
-    if "many_small" in sel:
-        sizes = [1, 2, 3, 4, 6]             # all <= segment_size/2 = 32
-        srng = np.random.default_rng([seed, 1])
-        Xs = [srng.integers(0, 512,
-                            (sizes[i % len(sizes)], seq)).astype(np.int32)
-              for i in range(small_concurrency)]
-        many = {}
-        for name, coalesce in (("pipelined", False), ("coalesced", True)):
-            with InferenceSystem(small_cfgs, small_params, alloc_small,
-                                 segment_size=64, max_seq=seq,
-                                 device_combine=True, coalesce=coalesce,
-                                 max_in_flight=small_concurrency,
-                                 max_wait_us=small_max_wait_us) as system:
-                many[name] = _measure_many_small(system, Xs, small_rounds)
-        many["speedup"] = (many["coalesced"]["segments_per_sec"] /
-                           many["pipelined"]["segments_per_sec"])
-        results["many_small"] = many
-
-    # ---- mixed-priority: SLO traffic behind a bulk scan (real tiny models) --
-    if "mixed_priority" in sel:
-        srng = np.random.default_rng([seed, 2])
-        bulk_X = srng.integers(0, 512, (mixed_bulk, seq)).astype(np.int32)
-        small_Xs = [srng.integers(0, 512, (2 + i % 3, seq)).astype(np.int32)
-                    for i in range(mixed_smalls)]
-        # segment_size 16 keeps compiled chunks small and dispatch_ahead=1
-        # keeps the committed (non-preemptible) window shallow: on a shared
-        # device, every committed bulk chunk is queue time a high-priority
-        # chunk cannot jump — the SLO deployment knob the chunk-granular
-        # pipeline exposes (DESIGN.md §3)
-        mixed = {}
-        for mode, high in (("fifo", False), ("priority", True)):
-            with InferenceSystem(small_cfgs, small_params, alloc_small,
-                                 segment_size=16, max_seq=seq,
-                                 device_combine=True, coalesce=True,
-                                 max_in_flight=32, dispatch_ahead=1,
-                                 max_wait_us=small_max_wait_us) as system:
-                mixed[mode] = _measure_mixed_priority(
-                    system, bulk_X, small_Xs, mixed_rounds,
-                    high_priority=high)
-        mixed["hp_p50_improvement"] = (mixed["fifo"]["high"]["p50_ms"] /
-                                       mixed["priority"]["high"]["p50_ms"])
-        mixed["hp_p99_improvement"] = (mixed["fifo"]["high"]["p99_ms"] /
-                                       mixed["priority"]["high"]["p99_ms"])
-        mixed["throughput_ratio"] = (mixed["priority"]["segments_per_sec"] /
-                                     mixed["fifo"]["segments_per_sec"])
-        results["mixed_priority"] = mixed
-
-    # ---- skewed_load: one hot member, work stealing off vs on (ISSUE 4) -----
-    if "skewed_load" in sel:
-        skew_devs = host_cpus(2, memory_bytes=8 * GiB)
-        skewed = {}
-        for mode, steal in (("no_steal", False), ("steal", True)):
-            skewed[mode] = _measure_skewed(small_cfgs, small_params,
-                                           skew_devs, seq, skew_requests,
-                                           skew_delay_us, steal, seed=seed)
-        skewed["steal_throughput_ratio"] = (
-            skewed["steal"]["segments_per_sec"] /
-            skewed["no_steal"]["segments_per_sec"])
-        results["skewed_load"] = skewed
-
-    # ---- fault_recovery: kill a sibling mid-trace, lose nothing (ISSUE 6) ---
-    if "fault_recovery" in sel:
-        results["fault_recovery"] = _measure_fault_recovery(
-            small_cfgs, small_params, seq, fault_requests, fault_delay_us,
-            seed=seed)
-
-    # ---- overload_brownout: 3x saturation, brownout off vs on (ISSUE 7) -----
-    if "overload_brownout" in sel:
-        overload = {}
-        for mode, on in (("off", False), ("on", True)):
-            overload[mode] = _measure_overload_brownout(
-                small_cfgs, small_params, seq, overload_requests,
-                overload_pace_s, overload_cheap_us, overload_heavy_us,
-                brownout=on, seed=seed)
-        overload["completed_or_shed_ratio"] = \
-            overload["on"]["completed_or_shed_ratio"]
-        overload["brownout_p99_improvement"] = (
-            overload["off"]["p99_ms"] / max(overload["on"]["p99_ms"], 1e-9))
-        results["overload_brownout"] = overload
-
-    # ---- quantized_members: int8 speedup + fused-combine parity (ISSUE 10) --
-    if "quantized_members" in sel:
-        results["quantized_members"] = _measure_quantized_members(
-            small_cfgs, small_params, seq, quant_requests, quant_delay_us,
-            seed=seed)
-
-    # ---- tracing_overhead: span layer on vs off, <= 5% budget (ISSUE 9) -----
-    if "tracing_overhead" in sel:
-        results["tracing_overhead"] = _measure_tracing_overhead(
-            cfgs, params, alloc, X, seq, requests)
-
-    # ---- sim_fidelity: record a real run, replay in-sim (DESIGN.md §12) -----
-    if "sim_fidelity" in sel:
-        results["sim_fidelity"] = _measure_sim_fidelity(
-            small_cfgs, small_params, seq, fidelity_requests,
-            fidelity_pace_s, fidelity_cheap_us, fidelity_heavy_us,
-            seed=seed)
-
-    if csv:
-        print(f"serving_hotpath:rng_seed,{seed},")
-        if "core" in sel:
-            print("serving_hotpath:variant,segments_per_sec,"
-                  "messages_per_request")
-            for name in ("seed", "pipelined", "coalesced"):
-                r = results[name]
-                print(f"serving_hotpath:{name},{r['segments_per_sec']:.1f},"
-                      f"{r['messages_per_request']:.1f}")
-            print(f"serving_hotpath:speedup,{results['speedup']:.2f},")
-            print(f"serving_hotpath:large_request_ratio,"
-                  f"{results['large_request_ratio']:.3f},")
-        if "many_small" in sel:
-            many = results["many_small"]
-            for name in ("pipelined", "coalesced"):
-                r = many[name]
-                print(f"serving_hotpath:many_small.{name},"
-                      f"{r['segments_per_sec']:.1f},"
-                      f"{r['messages_per_request']:.1f}")
-                print(f"serving_hotpath:many_small.{name}"
-                      f".padding_efficiency,{r['padding_efficiency']:.3f},")
-            print(f"serving_hotpath:many_small.speedup,"
-                  f"{many['speedup']:.2f},")
-        if "mixed_priority" in sel:
-            mixed = results["mixed_priority"]
-            for mode in ("fifo", "priority"):
-                r = mixed[mode]
-                print(f"serving_hotpath:mixed_priority.{mode}"
-                      f".high_p50/p99_ms,"
-                      f"{r['high']['p50_ms']:.1f},{r['high']['p99_ms']:.1f}")
-                print(f"serving_hotpath:mixed_priority.{mode}"
-                      f".bulk_p50/p99_ms,"
-                      f"{r['bulk']['p50_ms']:.1f},{r['bulk']['p99_ms']:.1f}")
-                print(f"serving_hotpath:mixed_priority.{mode}"
-                      f".segments_per_sec,{r['segments_per_sec']:.1f},")
-            print(f"serving_hotpath:mixed_priority.hp_p50_improvement,"
-                  f"{mixed['hp_p50_improvement']:.2f},")
-            print(f"serving_hotpath:mixed_priority.hp_p99_improvement,"
-                  f"{mixed['hp_p99_improvement']:.2f},")
-            print(f"serving_hotpath:mixed_priority.throughput_ratio,"
-                  f"{mixed['throughput_ratio']:.3f},")
-        if "skewed_load" in sel:
-            skewed = results["skewed_load"]
-            for mode in ("no_steal", "steal"):
-                r = skewed[mode]
-                print(f"serving_hotpath:skewed_load.{mode},"
-                      f"{r['segments_per_sec']:.1f},"
-                      f"{r['stolen_descriptors']}")
-            print(f"serving_hotpath:skewed_load.steal_throughput_ratio,"
-                  f"{skewed['steal_throughput_ratio']:.2f},")
-        if "fault_recovery" in sel:
-            fr = results["fault_recovery"]
-            print(f"serving_hotpath:fault_recovery.completed_ratio,"
-                  f"{fr['completed_ratio']:.3f},{fr['segments_replayed']}")
-            print(f"serving_hotpath:fault_recovery.recovery_s,"
-                  f"{fr['recovery_s']:.4f},{fr['recovery_ok']:.0f}")
-        if "overload_brownout" in sel:
-            overload = results["overload_brownout"]
-            for mode in ("off", "on"):
-                r = overload[mode]
-                print(f"serving_hotpath:overload_brownout.{mode}"
-                      f".p50/p99_ms,{r['p50_ms']:.1f},{r['p99_ms']:.1f}")
-                print(f"serving_hotpath:overload_brownout.{mode}"
-                      f".completed/shed,{r['completed']},{r['shed']}")
-            print(f"serving_hotpath:overload_brownout"
-                  f".completed_or_shed_ratio,"
-                  f"{overload['completed_or_shed_ratio']:.3f},")
-            print(f"serving_hotpath:overload_brownout"
-                  f".brownout_p99_improvement,"
-                  f"{overload['brownout_p99_improvement']:.2f},")
-        if "quantized_members" in sel:
-            qm = results["quantized_members"]
-            for mode in ("fp32", "int8"):
-                r = qm[mode]
-                print(f"serving_hotpath:quantized_members.{mode},"
-                      f"{r['segments_per_sec']:.1f},"
-                      f"{r['fake_delay_us']}")
-            print(f"serving_hotpath:quantized_members.quant_speedup,"
-                  f"{qm['quant_speedup']:.2f},")
-            print(f"serving_hotpath:quantized_members.parity_rel_err,"
-                  f"{qm['parity_rel_err']:.4f},{qm['subset_rel_err']:.4f}")
-            print(f"serving_hotpath:quantized_members.quant_parity_ok,"
-                  f"{qm['quant_parity_ok']:.0f},{qm['h2d_staged']}")
-        if "tracing_overhead" in sel:
-            to = results["tracing_overhead"]
-            print(f"serving_hotpath:tracing_overhead.off/on_segs_per_sec,"
-                  f"{to['off_segments_per_sec']:.1f},"
-                  f"{to['on_segments_per_sec']:.1f}")
-            print(f"serving_hotpath:tracing_overhead.overhead_ratio,"
-                  f"{to['overhead_ratio']:.3f},{to['trace_events']}")
-            print(f"serving_hotpath:tracing_overhead.overhead_ok,"
-                  f"{to['overhead_ok']:.0f},")
-        if "sim_fidelity" in sel:
-            sf = results["sim_fidelity"]
-            print(f"serving_hotpath:sim_fidelity.real.req_per_s/p99_ms,"
-                  f"{sf['real']['req_per_s']:.1f},{sf['real']['p99_ms']:.1f}")
-            print(f"serving_hotpath:sim_fidelity.sim.req_per_s/p99_ms,"
-                  f"{sf['sim']['req_per_s']:.1f},{sf['sim']['p99_ms']:.1f}")
-            print(f"serving_hotpath:sim_fidelity.throughput_ratio,"
-                  f"{sf['throughput_ratio']:.3f},")
-            print(f"serving_hotpath:sim_fidelity.p99_ratio,"
-                  f"{sf['p99_ratio']:.3f},")
-            print(f"serving_hotpath:sim_fidelity.fidelity_ok,"
-                  f"{sf['fidelity_ok']:.0f},")
-        if "core" in sel:
-            for name in ("pipelined", "coalesced"):
-                for stage, t in results[name]["stage_timings"].items():
-                    print(f"serving_hotpath:{name}.{stage},"
-                          f"{t['total_s']:.4f},{t['count']}")
-    return results
 
 
 def main(argv=None):
     import argparse
 
     ap = argparse.ArgumentParser(
-        description="serving hot-path A/B benchmark")
-    ap.add_argument("--seed", type=int, default=0,
-                    help="RNG seed for every scenario's inputs and "
-                         "member skews (recorded in the results as "
-                         "rng_seed)")
-    ap.add_argument("--scenario", action="append", default=[],
-                    metavar="NAME", choices=SCENARIOS,
-                    help=f"run only the named scenarios (repeatable); "
-                         f"default all of {list(SCENARIOS)}")
-    ap.add_argument("--replay-trace", default=None, metavar="PATH",
-                    help="replay a recorded request trace "
-                         "(launch/serve.py --record-trace or "
-                         "system.trace_recorder) against a fake-device "
-                         "system instead of running scenarios")
+        description="replay a recorded request trace against a "
+                    "fake-device serving system")
+    ap.add_argument("--replay-trace", required=True, metavar="PATH",
+                    help="a request trace (launch/serve.py --record-trace "
+                         "or system.trace_recorder)")
     ap.add_argument("--replay-speed", type=float, default=1.0,
-                    help="time-compression factor for --replay-trace")
+                    help="time-compression factor for the recorded gaps")
     args = ap.parse_args(argv)
-    if args.replay_trace:
-        replay_trace(args.replay_trace, speed=args.replay_speed)
-    else:
-        run(seed=args.seed, scenarios=args.scenario or None)
+    replay_trace(args.replay_trace, speed=args.replay_speed)
     return 0
 
 

@@ -48,9 +48,7 @@ Acceptance (ISSUE 8): >= 1M synthetic requests replay in < 60 s
 single-process (``scale.scale_ok``); forecast-fed replanning beats
 EWMA-fed on the diurnal trace (``forecast_replan.p99_improvement``, floor
 1.2x); the tuner reproduces K=16 on the throughput trace
-(``ktuner.recommended_ok``) — all gated by check_regression.py.  The
-sim-vs-real calibration gate lives in the serving bench
-(``serving_hotpath.py --scenario sim_fidelity``).
+(``ktuner.recommended_ok``).
 """
 from __future__ import annotations
 

@@ -1,28 +1,21 @@
 """The prediction accumulator (paper §II.C.2), multi-request edition.
 
 Consumes messages from the single prediction queue and folds them into the
-per-request ensemble prediction.  Two message kinds (DESIGN.md §§3-4):
+per-request ensemble prediction.  Every data message is a **device
+partial** (DESIGN.md §§3-4): the already-weighted sum of ``count`` member
+predictions of one whole segment, pre-combined on one device by its
+:class:`~repro.serving.combiner.DeviceCombiner` under the request's combine
+rule — so the fold is just ``Y[start(s):end(s)] += P``.
 
-  * **device partials** (``m is None``): already-weighted sums of ``count``
-    member predictions, pre-combined on one device — the fold is just
-    ``Y[start(s):end(s)] += P``;
-  * **per-member messages** (legacy path, ``device_combine=False``): the
-    paper's {s, m, P} triplet, folded under the request's combine rule —
-    "mean"/"weighted" (``Y += w_m P``), "vote" (majority voting on argmax),
-    or "pallas" (buffer the segment's M member predictions, then fuse the
-    weighted combine in the ensemble_combine Pallas kernel, DESIGN.md §9.4).
-
-Under the coalescing scheduler one member's segment may arrive split across
-several messages (each tagged with ``row_lo``), so completion accounting
-counts **rows, not messages**: a request owes ``n × len(members)``
-member-rows, a per-member message debits ``len(P)`` rows, and a device
-partial debits ``count × segment_rows``.  The total is invariant to how the
-batcher packed the spans.  Early-forward audit (chunk-granular pipeline,
-DESIGN.md §3): because nothing here assumes slot order — segments may
-complete in any order, rows in any split — a sender forwarding a segment
-the moment its last chunk returns (before its slot retires, possibly out
-of segment order under priority reordering) needs no changes on this side;
-the same row arithmetic closes.
+Completion accounting counts **rows, not messages**: a request owes
+``n × len(members)`` member-rows and a partial debits
+``count × segment_rows``, however the batchers packed the spans and
+however many devices split a segment's members.  Nothing here assumes
+arrival order — segments may complete in any order, across any number of
+devices — so a sender forwarding a segment the moment its last chunk
+returns (DESIGN.md §3) needs no changes on this side.  A forgiveness
+message (``P is None``, ``m`` set) debits one member's rows of one segment
+without folding anything (DESIGN.md §§10-11).
 
 Every message carries a request id, so any number of requests can be in
 flight; each ``begin()`` returns a :class:`RequestHandle` the caller waits
@@ -85,8 +78,6 @@ class RequestHandle:
         self.keep_buffer = False
         self._missing_w: Optional[np.ndarray] = None
         self.on_segment = on_segment          # streaming-partials callback
-        self._seg_buffers: Dict[int, Dict[int, np.ndarray]] = {}
-        self._seg_rows: Dict[int, int] = {}   # pallas path: rows buffered
         self._finished = False                # guarded by accumulator lock
         self._canceller: Optional["PredictionAccumulator"] = None
         if on_segment is not None:            # member-rows owed per segment
@@ -146,7 +137,7 @@ class PredictionAccumulator:
         self._lock = threading.Lock()
         self._requests: Dict[int, RequestHandle] = {}
         self._last: Optional[RequestHandle] = None
-        self.data_messages = 0                # partials + per-member messages
+        self.data_messages = 0                # device partials folded
 
     # ---- request lifecycle ----------------------------------------------------
     def begin(self, req: Request,
@@ -364,15 +355,10 @@ class PredictionAccumulator:
             lo, hi = req.bounds(msg.s)
             self.data_messages += 1
             handle.messages += 1
-            if msg.m is None:
-                # device partial: weights already applied on-device; the
-                # combiner flushes full segments, so this debits count x
-                # segment rows
-                handle.Y[lo:hi] += msg.P
-                rows = msg.count * (hi - lo)
-            else:
-                self._fold_member(handle, msg, lo, hi)
-                rows = int(msg.P.shape[0])
+            # weights already applied on-device; the combiner flushes full
+            # segments, so this debits count x segment rows
+            handle.Y[lo:hi] += msg.P
+            rows = msg.count * (hi - lo)
             handle.remaining -= rows
             if handle._seg_remaining is not None:
                 left = handle._seg_remaining[msg.s] - rows
@@ -394,51 +380,3 @@ class PredictionAccumulator:
                  msg.s, rows, None))
         if handle.remaining == 0:
             self._complete(handle)
-
-    def _fold_member(self, handle: RequestHandle, msg: Message,
-                     lo: int, hi: int):
-        """Fold a per-member span message: rows ``[row_lo, row_lo+len(P))``
-        of segment ``s``, i.e. request rows ``[lo+row_lo, ...)``."""
-        req = handle.req
-        w = req.weights[msg.m]
-        a = lo + msg.row_lo
-        b = a + int(msg.P.shape[0])
-        if req.combine in ("mean", "weighted"):
-            # the paper's one-liner: Y[start:end] += P / M (weighted form)
-            handle.Y[a:b] += msg.P * w
-        elif req.combine == "vote":
-            onehot = np.zeros_like(handle.Y[a:b])
-            onehot[np.arange(b - a), msg.P.argmax(axis=1)] = w
-            handle.Y[a:b] += onehot
-        elif req.combine == "pallas":
-            # spans buffer into per-(segment, member) staging rows; the fused
-            # kernel runs once all members' rows for the segment are present.
-            # Whole-segment messages (the common case — senders reassemble
-            # spans) store by reference instead of paying an alloc + copy.
-            buf = handle._seg_buffers.setdefault(msg.s, {})
-            if msg.row_lo == 0 and msg.P.shape[0] == hi - lo:
-                buf[msg.m] = msg.P
-            else:
-                arr = buf.get(msg.m)
-                if arr is None:
-                    arr = buf[msg.m] = np.zeros((hi - lo, req.num_classes),
-                                                np.float32)
-                arr[msg.row_lo:msg.row_lo + msg.P.shape[0]] = msg.P
-            got = self._seg_rows_add(handle, msg.s, int(msg.P.shape[0]))
-            if got == (hi - lo) * len(req.members):
-                from repro.kernels import ops as kops
-                import jax.numpy as jnp
-                stacked = jnp.asarray(np.stack([buf[m] for m in req.members]))
-                wv = jnp.asarray(np.array([req.weights[m] for m in req.members],
-                                          np.float32))
-                handle.Y[lo:hi] = np.asarray(kops.ensemble_combine(stacked, wv))
-                del handle._seg_buffers[msg.s]
-                del handle._seg_rows[msg.s]
-        else:
-            raise ValueError(f"unknown combine rule {req.combine!r}")
-
-    @staticmethod
-    def _seg_rows_add(handle: RequestHandle, s: int, rows: int) -> int:
-        got = handle._seg_rows.get(s, 0) + rows
-        handle._seg_rows[s] = got
-        return got

@@ -1,10 +1,11 @@
 """Device-resident partial ensemble combine (DESIGN.md §4).
 
-Workers co-located on one device fold their weighted predictions into a
-shared per-(request, segment) partial *on the device* and post **one**
-``Message(s, None, partial, rid, count)`` per device per segment — instead of
-one {s, m, P} message (and one device->host transfer) per member.  With M
-members sharing a device this cuts accumulator traffic by up to M×.
+Every device that hosts a worker has one combiner.  Its workers fold their
+weighted predictions into a shared per-(request, segment) partial *on the
+device*, and the combiner posts **one** ``Message(s, None, partial, rid,
+count)`` per device per segment: one device->host copy (the ``copy``
+stage) and one accumulator message, however many members share the
+device.
 
 How the flush trigger stays deterministic under coalescing: the broadcaster
 assigns every (segment, model) pair to a *specific* worker instance

@@ -11,12 +11,11 @@ re-routed to the sibling.  Three invariants make a steal safe:
   untouched (all of a segment's spans still flow through one batcher).
   Selection is deadline-aware (ROADMAP item i): the tightest remaining
   deadline budget moves first — it gains the most from the idle sibling;
-* **expected-row maps move with the work** — with the device-resident
-  partial combine, the source device's combiner expected one contribution
-  for the stolen (request, segment); ``unexpect``/``expect_one`` transfer
-  that expectation (flushing the source partial early when the remaining
-  members' rows already closed it), so row-count flush accounting still
-  closes on both devices;
+* **expected-row maps move with the work** — the source device's combiner
+  expected one contribution for the stolen (request, segment);
+  ``unexpect``/``expect_one`` transfer that expectation (flushing the
+  source partial early when the remaining members' rows already closed
+  it), so row-count flush accounting still closes on both devices;
 * **topology consistency** — steals run under the system's submit lock, so
   they cannot interleave with a spawn/drain (a descriptor is never re-routed
   into a queue behind a ``SHUTDOWN``) or a racing broadcaster registering a
@@ -32,12 +31,11 @@ from repro.serving.worker import Worker
 
 def _transfer(req, s: int, src: Worker, dst: Worker) -> None:
     """Move the combiner expectation for (req, s) from src's device to
-    dst's.  Same-device siblings share a combiner (no move); a dropped
-    (cancelled/expired) request's maps were already torn down — the
-    descriptor still forwards so the destination batcher posts the DROPPED
-    resolution."""
-    if (src.combiner is None or dst.combiner is None
-            or src.combiner is dst.combiner or req.dropped()):
+    dst's.  Same-device siblings share a combiner (no move; simulated
+    workers all share ``None``); a dropped (cancelled/expired) request's
+    maps were already torn down — the descriptor still forwards so the
+    destination batcher posts the DROPPED resolution."""
+    if src.combiner is dst.combiner or req.dropped():
         return
     if src.combiner.unexpect(req, s):
         dst.combiner.expect_one(req, s)

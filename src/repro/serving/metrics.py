@@ -14,8 +14,7 @@ name and one clock:
   ``predict``        one predictor round, pop to last dispatch (async --
                      excludes device time),
   ``device_wait``    the sender's wait for one dispatched chunk's output,
-  ``copy``           one device->host copy of answers (a chunk's on the
-                     host-combine path, a device partial's when posted),
+  ``copy``           one device partial's device->host copy when posted,
   ``combine``        one member contribution's fold into a device partial,
   ``combine_wait``   the wait for one device partial's fold program before
                      its copy,

@@ -13,16 +13,14 @@ Hot-path extensions (DESIGN.md §3):
     worker may still be reading (the seed's ``shared_x`` swap race);
   * messages are tagged with the request id ``rid`` so multiple requests can
     be in flight at once;
-  * a message with ``m is None`` is a *device partial*: the weighted sum of
-    ``count`` member predictions, pre-combined on one device
-    (DESIGN.md §4) — the accumulator just adds it into Y;
+  * a data message (``m is None``) is a *device partial*: the weighted sum
+    of ``count`` member predictions of one whole segment, pre-combined on
+    one device (DESIGN.md §4) — the accumulator just adds it into Y;
   * under the coalescing scheduler one compiled batch carries rows from
     *multiple* (request, segment) pairs — a :class:`Span` is one contiguous
     row-range of one segment inside one batch, and a batch's span list is
     the *scatter descriptor* the sender walks to route output rows back to
-    their requests.  A segment's rows may therefore arrive split across
-    several messages: ``Message.row_lo`` locates a message's rows inside the
-    segment, and downstream accounting counts **rows, not messages**.
+    their requests.  Downstream accounting counts **rows, not messages**.
 
 Request API (DESIGN.md §7): a :class:`PredictOptions` descriptor expresses
 per-request intent — priority class, deadline, member subset, combine rule,
@@ -212,17 +210,15 @@ def end(s: int, segment_size: int, nb_samples: int) -> int:
 class Message:
     """The {s, m, P} triplet (paper §II.C.2), tagged with the request id.
 
-    ``m is None`` (with ``s >= 0``) marks a device-partial message whose P
-    already folds ``count`` weighted member predictions.  Under coalescing a
-    per-member message may carry only a row-range of its segment: ``P`` then
-    covers segment rows ``[row_lo, row_lo + len(P))`` and the accumulator
-    debits rows, not messages.  Sentinels use P=None."""
+    With ``s >= 0`` a message is either a device partial (``m is None``):
+    P already folds ``count`` weighted member predictions of the whole
+    segment — or a forgiveness (``P is None``, ``m`` the member whose rows
+    of the segment will never arrive).  Sentinels use P=None."""
     s: int                       # segment id (or OOM / READY)
-    m: Optional[int]             # model id; None = device partial
+    m: Optional[int]             # forgiven member; None = device partial
     P: Optional[np.ndarray]      # (rows, C) prediction matrix
     rid: int = 0                 # request id
     count: int = 1               # member contributions folded into P
-    row_lo: int = 0              # first segment row covered by P
 
     @property
     def is_sentinel(self) -> bool:

@@ -32,8 +32,7 @@ actions.
 
 What is *not* modelled: payload bytes (shape-only), JAX compile time,
 host↔device transfer overlap, and thread scheduling jitter — service time
-is the model.  Fidelity against real ``fake_delay_us`` runs is asserted by
-the gated `sim_fidelity` bench scenario.
+is the model.
 """
 from __future__ import annotations
 
@@ -172,7 +171,6 @@ class SimWorker:
         chunks_per_seg = max(1, -(-self.segment_size // self.batch_size))
         self._span = chunks_per_seg * self.batch_size
         self.dispatch_ahead = system.dispatch_ahead
-        self.coalesce = system.coalesce
         self.linger_s = system.max_wait_us * 1e-6
         self.linger_mode = system.linger
         svc = system.service
@@ -287,8 +285,6 @@ class SimWorker:
                 b.deadline = min(b.deadline, (loop.now + req.deadline) / 2.0)
             if express:
                 b.deadline = loop.now   # flush once the queue runs dry
-            if not self.coalesce:
-                self._flush()
 
     def _open_new(self, express: bool) -> bool:
         if self._free > 0:
@@ -484,7 +480,7 @@ class SimSystem:
                  *, M: Optional[int] = None, segment_size: int = 64,
                  dispatch_ahead: int = DISPATCH_AHEAD,
                  max_wait_us: float = 500.0, linger: str = "fixed",
-                 coalesce: bool = True, queue_cls=DispatchQueue,
+                 queue_cls=DispatchQueue,
                  weights: Optional[Sequence[float]] = None,
                  live=None, record_events: bool = False,
                  tracing: bool = False, trace_capacity: int = 4096):
@@ -493,7 +489,6 @@ class SimSystem:
         self.dispatch_ahead = int(dispatch_ahead)
         self.max_wait_us = float(max_wait_us)
         self.linger = linger
-        self.coalesce = coalesce
         self.queue_cls = queue_cls
         self.loop = EventLoop()
         self.timers = StageTimers()

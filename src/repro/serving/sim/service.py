@@ -12,10 +12,9 @@ Two fit paths:
 * :meth:`ServiceModel.from_livebench` — from the ``latency_ewma_s`` block
   of a :class:`~repro.serving.control.livebench.LiveBench` snapshot taken
   during a real (simulated-device) run: keys ``m{m}|{dev}|b{bucket}``.
-  This is the calibration path the `sim_fidelity` bench gate exercises —
-  record a trace + profile from a live run, fit, replay, compare.
-  Measured EWMAs already embed dispatch overhead, so fitted models default
-  to ``dispatch_overhead_s=0``.
+  This is the calibration path: record a trace + profile from a live
+  run, fit, replay, compare.  Measured EWMAs already embed dispatch
+  overhead, so fitted models default to ``dispatch_overhead_s=0``.
 
 Unknown buckets are priced by nearest-bucket scaling with the same
 ``OVERHEAD_FLOOR`` rule LiveBench itself uses, so sim and live planner

@@ -14,16 +14,17 @@ Hot-path architecture (DESIGN.md §§3-5):
     later request can never invalidate a buffer workers still read);
   * (segment, model) pairs are striped round-robin across a model's
     data-parallel instances, which makes per-device contribution counts
-    deterministic and enables the device-resident partial combine
-    (``device_combine=True``): one accumulator message per device per
-    segment instead of one per member per segment — striping is unchanged
-    under coalescing, so row-count flush accounting still closes;
+    deterministic: every device hosting a worker has one
+    :class:`DeviceCombiner`, which folds its members' rows into a
+    device-resident partial and posts one accumulator message per device
+    per segment — striping is unchanged under coalescing, so row-count
+    flush accounting still closes;
   * requests are tagged with ids and pipelined — up to ``max_in_flight``
     ``predict_async()`` calls overlap instead of serializing on the
     accumulator.  The window defaults to 16 so the coalescing batchers
-    (``coalesce=True``, bounded ``max_wait_us`` linger) see rows from many
-    small concurrent requests and can pack them into full compiled batches;
-    ``quiesce()`` force-flushes any lingering partial batches.
+    (bounded ``max_wait_us`` linger) see rows from many small concurrent
+    requests and can pack them into full compiled batches; ``quiesce()``
+    force-flushes any lingering partial batches.
 """
 from __future__ import annotations
 
@@ -60,9 +61,7 @@ class InferenceSystem:
                  max_seq: int = 128,
                  use_kernel: bool = False,
                  ready_timeout: float = 300.0,
-                 device_combine: bool = True,
                  max_in_flight: int = 16,
-                 coalesce: bool = True,
                  max_wait_us: int = 500,
                  linger: str = "fixed",
                  fake_delay_us: int = 0,
@@ -84,9 +83,7 @@ class InferenceSystem:
         self.segment_size = segment_size
         self.max_seq = max_seq
         self.combine = combine
-        self.device_combine = device_combine
         self.max_in_flight = max(1, max_in_flight)
-        self.coalesce = coalesce
         self.max_wait_us = max_wait_us
         self.linger = linger
         # K outstanding async dispatches per worker: the committed
@@ -175,7 +172,7 @@ class InferenceSystem:
         self.workers: List[Worker] = []
         self._instances: Dict[int, List[Worker]] = {m: [] for m in range(self.M)}
         for d, m, batch in alloc.workers():
-            if device_combine and d not in self.combiners:
+            if d not in self.combiners:
                 self.combiners[d] = self._make_combiner(d)
             w = self._make_worker(d, m, batch, generation=0)
             self.workers.append(w)
@@ -219,8 +216,8 @@ class InferenceSystem:
                    self.max_seq, self.segment_size, fake=self._fake,
                    frontend=self._frontends.get(m),
                    use_kernel=self._use_kernel,
-                   combiner=self.combiners.get(d), timers=self.timers,
-                   coalesce=self.coalesce, max_wait_us=self.max_wait_us,
+                   combiner=self.combiners[d], timers=self.timers,
+                   max_wait_us=self.max_wait_us,
                    linger=self.linger, generation=generation,
                    profiler=self._profiler, oom_sentinel=oom_sentinel,
                    fake_delay_us=self._fake_delay_us,
@@ -260,12 +257,11 @@ class InferenceSystem:
         if self._shutdown:
             raise RuntimeError("system is shut down")
         gen = self.generation if generation is None else generation
-        if self.device_combine:
-            # registered before any descriptor can route to the new worker
-            # (_make_worker and _on_request_complete read self.combiners)
-            with self._submit_lock:
-                if d not in self.combiners:
-                    self.combiners[d] = self._make_combiner(d)
+        # registered before any descriptor can route to the new worker
+        # (_make_worker and _on_request_complete read self.combiners)
+        with self._submit_lock:
+            if d not in self.combiners:
+                self.combiners[d] = self._make_combiner(d)
         # warm-up compile outside the routing lock: submission stays live
         w = self._make_worker(d, m, batch_size, generation=gen,
                               oom_sentinel=False)
@@ -412,8 +408,7 @@ class InferenceSystem:
             else:
                 member_down = (w.model_idx, w.device_idx, w.batch_size)
                 for req, s in units:
-                    if w.combiner is not None and \
-                            not w.combiner.unexpect(req, s):
+                    if not w.combiner.unexpect(req, s):
                         continue          # request already torn down
                     # forgiveness message: P=None with s >= 0 — the
                     # accumulator debits the member's rows for this
@@ -703,14 +698,13 @@ class InferenceSystem:
                 for m in members:
                     inst = self._instances[m]
                     plan.append((inst[(s + rid) % len(inst)], s))
-            if self.combiners:
-                expected: Dict[int, list] = {}
-                for w, s in plan:
-                    comb, exp = expected.setdefault(id(w.combiner),
-                                                    [w.combiner, {}])
-                    exp[s] = exp.get(s, 0) + 1
-                for comb, exp in expected.values():
-                    comb.begin(req, exp)
+            expected: Dict[int, list] = {}
+            for w, s in plan:
+                comb, exp = expected.setdefault(id(w.combiner),
+                                                [w.combiner, {}])
+                exp[s] = exp.get(s, 0) + 1
+            for comb, exp in expected.values():
+                comb.begin(req, exp)
             for w, s in plan:
                 w.input_queue.put((req, s), req.priority)
             # budget ownership transfers to the live request LAST (nothing

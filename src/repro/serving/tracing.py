@@ -13,8 +13,7 @@ Events reuse timestamps the pipeline already computes (``chunk.t_enq``,
 ``Request.t_submit``, the ``StageTimers.timed`` return value), and the
 per-chunk dispatch-wait record is stored grouped per dispatch round
 ("G" below), so tracing adds one C-level append, not allocation or
-clock calls, per chunk — the ``tracing_overhead`` bench gates the total
-at <= 5%.
+clock calls, per chunk.
 
 The clock is pluggable: the live system uses ``time.perf_counter``; the
 discrete-event simulator passes ``lambda: loop.now`` so a recorded trace

@@ -4,7 +4,8 @@ Faithful to paper Fig. 2 — three asynchronous threads per worker:
   * the *batcher* coalesces incoming segment rows into padded batches,
   * the *predictor* owns the params on its device and runs the jitted step,
   * the *prediction sender* scatters batch outputs back to their segments
-    and forwards them (device partial or {s, m, P} message).
+    and folds each finished segment into its device's
+    :class:`~repro.serving.combiner.DeviceCombiner`.
 
 Hardware adaptation (DESIGN.md §2): the paper uses one OS process per worker
 (TF1 sessions hold the GIL); with JAX, XLA executions release the GIL and
@@ -30,8 +31,6 @@ requests/segments into full compiled batches:
     padded to the next **power-of-two bucket** (1, 2, 4, ..., batch; not the
     full compiled batch) — one jitted callable serves every bucket, and the
     constructor compiles all log2(batch)+1 of them before READY;
-  * ``coalesce=False`` restores the PR-1 one-item-at-a-time batching (each
-    (request, segment) flushes its own slot) as a measurement baseline;
   * slots come from a **preallocated ring** (free-list backpressure bounds
     in-flight memory).  Mismatched-seq requests (request width != compiled
     ring width) draw buffers from a small per-width side pool instead of
@@ -77,7 +76,7 @@ priority :class:`~repro.serving.admission.DispatchQueue` as an independent
     wait for a ring slot, each batch's linger, batch fill, per-class
     dispatch-queue wait (``dispatch_wait.high`` / ``dispatch_wait.normal``),
     predict dispatch, a group's wait in the sender's queue, and the
-    sender's device wait and device-to-host copy; padding counters
+    sender's device wait; padding counters
     (``rows_valid`` / ``rows_dispatched``) and the ``queue_depth`` gauge
     expose coalescing efficiency.
 
@@ -260,9 +259,9 @@ class Worker:
                  prediction_queue: "queue.Queue[Message]",
                  model_idx: int, max_seq: int, segment_size: int,
                  *, fake: bool = False, frontend: Optional[np.ndarray] = None,
-                 use_kernel: bool = False, combiner=None,
+                 use_kernel: bool = False, combiner,
                  timers: Optional[StageTimers] = None,
-                 coalesce: bool = True, max_wait_us: int = 500,
+                 max_wait_us: int = 500,
                  linger: str = "fixed", generation: int = 0,
                  profiler=None, oom_sentinel: bool = True,
                  fake_delay_us: int = 0,
@@ -291,7 +290,6 @@ class Worker:
         self.device = device
         self.combiner = combiner
         self.timers = timers or StageTimers()
-        self.coalesce = coalesce
         self.linger_s = max(0, max_wait_us) * 1e-6
         if linger not in ("fixed", "adaptive"):
             raise ValueError(f"linger must be 'fixed' or 'adaptive', "
@@ -394,13 +392,11 @@ class Worker:
                 fe = frontend if frontend is not None else np.zeros(
                     (batch_size, cfg.frontend_tokens, cfg.fdim), np.float32)
                 self.frontend = jnp.asarray(fe)
-            # quantized members feeding a device combiner emit (q, scale)
-            # logits for the fused dequant-weight-accumulate epilogue
-            self._quant_out = (kquant.is_quantized_dtype(self.member_dtype)
-                               and combiner is not None)
+            # quantized members emit (q, scale) logits for the combiner's
+            # fused dequant-weight-accumulate epilogue
             self.predict_fn = make_predict_fn(
-                cfg, use_kernel,
-                member_dtype=self.member_dtype, quant_out=self._quant_out)
+                cfg, use_kernel, member_dtype=self.member_dtype,
+                quant_out=kquant.is_quantized_dtype(self.member_dtype))
             # per-call expert row counts, read by the sender (grouped
             # expert layers only)
             self._expert_rows = getattr(self.predict_fn, "expert_rows", None)
@@ -412,13 +408,12 @@ class Worker:
                           if self.frontend is not None else None)
                     y = self.predict_fn(self.params, self._to_device(
                         np.zeros((b, max_seq), np.int32)), fe)
-                    if combiner is not None:
-                        # the sender cuts span rows out of the output on
-                        # the device: one slice program per (bucket, rows),
-                        # the row offset being an argument
-                        y = [leaf[:n] for leaf in jax.tree.leaves(y)
-                             for n in range(1, b)]
-                    jax.block_until_ready(y)
+                    # the sender cuts span rows out of the output on the
+                    # device: one slice program per (bucket, rows), the
+                    # row offset being an argument
+                    jax.block_until_ready([leaf[:n]
+                                           for leaf in jax.tree.leaves(y)
+                                           for n in range(1, b)])
                 if self._expert_rows is not None:
                     self._expert_rows.clear()      # warm-up's, not served
             self.prediction_queue.put(Message(seg.READY, model_idx, None))
@@ -714,7 +709,7 @@ class Worker:
             # completion-time renormalization.  Never DROPPED (that fails
             # the whole request).  Checked BEFORE the ledger add, so no
             # pop-gate is involved: this batcher is the unit's only owner.
-            if self.combiner is None or self.combiner.unexpect(req, s):
+            if self.combiner.unexpect(req, s):
                 lo, hi = req.bounds(s)
                 self.timers.inc("rows_demoted", hi - lo)
                 self.prediction_queue.put(Message(
@@ -783,9 +778,6 @@ class Worker:
             # the queue runs dry instead of waiting out max_wait_us
             # (anything already queued still coalesces first)
             open_batch.deadline = 0.0
-        if not self.coalesce and open_batch is not None:
-            self._flush(open_batch)           # PR-1 semantics: per-item flush
-            open_batch = None
         return open_batch
 
     # ---- stage 2: predictor --------------------------------------------------
@@ -944,7 +936,6 @@ class Worker:
         skipped chunks) purge their staging entry and post the rows to the
         ``rows_dropped`` counter, keyed by an idempotent ``DROPPED``
         resolution message."""
-        on_device = self.combiner is not None
         staging: Dict[tuple, list] = {}     # (rid, s) -> [rows, {seg_off: P}]
         tr = self.tracer
         tr_ring = tr.ring(self._tr_sender) if tr is not None else None
@@ -960,8 +951,7 @@ class Worker:
             self.timers.add("send_wait", t0 - t_put)
             profiled = []                  # (bucket, valid) materialized
             for chunk, y, t_dispatch, skipped in batch:
-                self._send_chunk(chunk, y, skipped, staging, on_device,
-                                 profiled)
+                self._send_chunk(chunk, y, skipped, staging, profiled)
             now = time.perf_counter()
             if tr is not None and tr.enabled:
                 # grouped single span: slot a carries the group's shared
@@ -984,13 +974,12 @@ class Worker:
                     self.profiler.observe(self.model_idx, self.device.key(),
                                           bucket, valid, dt * bucket / total)
 
-    def _send_chunk(self, chunk, y, skipped, staging, on_device, profiled):
+    def _send_chunk(self, chunk, y, skipped, staging, profiled):
         if not skipped:
             if self._fault is not None:
                 self._fault.tick(self.worker_id, "sender")
             # one device wait per dispatched chunk (none to wait for from a
-            # fake predictor or an injected NaN output, both on the host);
-            # on the host-combine path, the device-to-host copy after it
+            # fake predictor or an injected NaN output, both on the host)
             on_chip = y is not None and not isinstance(y, np.ndarray)
             with self.timers.stage("device_wait"):
                 if on_chip:
@@ -1002,17 +991,13 @@ class Worker:
                 # dispatched the chunk, and chunks reach here in that order
                 self.timers.inc("expert_rows",
                                 int(self._expert_rows.popleft()))
-            if on_chip and not on_device:
-                with self.timers.stage("copy"):
-                    y = np.asarray(y)
-            if y is not None:
-                if self.nan_guard and isinstance(y, np.ndarray) \
-                        and np.isnan(y).any():
-                    # poisoned output: dying here (WorkerCrashed through
-                    # _guarded) routes recovery through quarantine + replay
-                    # on a sibling rather than folding NaN into Y
-                    raise seg.WorkerCrashed(
-                        f"{self.worker_id}: NaN in device output")
+            if self.nan_guard and isinstance(y, np.ndarray) \
+                    and np.isnan(y).any():
+                # poisoned output: dying here (WorkerCrashed through
+                # _guarded) routes recovery through quarantine + replay on
+                # a sibling rather than folding NaN into Y
+                raise seg.WorkerCrashed(
+                    f"{self.worker_id}: NaN in device output")
             self._dispatch_sem.release()   # window slot free again
             if self.profiler is not None and (y is not None
                                               or self.fake_delay_us):
@@ -1032,9 +1017,8 @@ class Worker:
                 # demoted must forgive, never DROPPED-fail the request.
                 staging.pop(key, None)
                 self.timers.inc("rows_demoted", sp.n)
-                if self._ledger.pop(key, None) is not None and (
-                        self.combiner is None or
-                        self.combiner.unexpect(sp.req, sp.s)):
+                if self._ledger.pop(key, None) is not None and \
+                        self.combiner.unexpect(sp.req, sp.s):
                     self.prediction_queue.put(Message(
                         sp.s, self.model_idx, None, rid=sp.req.rid))
                     tr = self.tracer
@@ -1087,10 +1071,7 @@ class Worker:
             if self._ledger.pop(key, None) is None:
                 continue
             # no forward instant here: the pop-gate moment is already
-            # observable as the downstream combine/accumulate span for
-            # (rid, s), and this path runs per (segment, member) — hot
-            # enough that an extra clock call + emit showed up in the
-            # tracing_overhead gate
+            # observable as the downstream combine span for (rid, s)
             if y is None and not st[1]:    # fake predictor: instant zeros
                 P = np.zeros((hi - lo, self.num_classes), np.float32)
             else:
@@ -1100,13 +1081,6 @@ class Worker:
                 elif isinstance(parts[0], tuple):   # quantized parts
                     P = (jnp.concatenate([p[0] for p in parts], axis=0),
                          jnp.concatenate([p[1] for p in parts], axis=0))
-                elif on_device:
-                    P = jnp.concatenate(parts, axis=0)
                 else:
-                    P = np.concatenate(parts, axis=0)
-            if on_device:
-                self.combiner.add(sp.req, sp.s, self.model_idx, P)
-            else:
-                self.prediction_queue.put(Message(
-                    sp.s, self.model_idx, np.asarray(P),
-                    rid=sp.req.rid))
+                    P = jnp.concatenate(parts, axis=0)
+            self.combiner.add(sp.req, sp.s, self.model_idx, P)

@@ -22,13 +22,13 @@ def make_req(rid, n=8, seg=4, members=(0, 1)):
 
 
 def data_messages(req):
-    """Every per-member message the pipeline would produce for ``req``."""
+    """Every device partial the pipeline would produce for ``req`` with all
+    its members on one device: one per segment."""
     out = []
     for s in range(req.num_segments()):
         lo, hi = req.bounds(s)
-        for m in req.members:
-            out.append(Message(s, m, np.ones((hi - lo, C), np.float32),
-                               rid=req.rid))
+        out.append(Message(s, None, np.ones((hi - lo, C), np.float32),
+                           rid=req.rid, count=len(req.members)))
     return out
 
 

@@ -1,5 +1,5 @@
 """Chunk-granular predictor pipeline tests (ISSUE 5): bit-identical results
-vs the ``coalesce=False`` baseline, priority chunk ordering under a
+vs each request served alone, priority chunk ordering under a
 saturated ring, refcount-correct slot recycling on CPU (aliased
 ``device_put``), quiesce/FLUSH barriers with chunks in the dispatch queue,
 dropped-at-dequeue chunks of cancelled/expired requests, the deadline-aware
@@ -88,14 +88,15 @@ def test_slot_ref_release_exactly_once_owner():
 
 # ---- bit-identical results under chunk reordering ----------------------------
 
-@pytest.mark.parametrize("device_combine", [True, False])
-def test_chunk_pipeline_bit_identical_vs_uncoalesced(ens2, device_combine):
-    """Acceptance: ensemble outputs are bit-identical to the
-    ``coalesce=False`` baseline under chunk-granular dispatch — including
-    member subsets, mixed priorities (which reorder chunks), and the device
-    combine.  Request sizes are multiples of the compiled batch so both
-    schedules group the same rows into the same compiled shapes and the
-    comparison is exact, not approximate."""
+@pytest.mark.parametrize("combine", ["mean", "pallas"])
+def test_chunk_pipeline_bit_identical_vs_uncoalesced(ens2, combine):
+    """Acceptance: ensemble outputs of interleaved requests, whose rows
+    coalesce into shared slots, are bit-identical to the same system
+    serving each request alone, in order — including member subsets and
+    mixed priorities (which reorder chunks), for mean partials and for the
+    Pallas accumulate kernel's.  Request sizes are multiples of the
+    compiled batch so both schedules group the same rows into the same
+    compiled shapes and the comparison is exact, not approximate."""
     cfgs, params = ens2
     rng = np.random.default_rng(7)
     sizes = [8, 16, 24, 8, 32, 16]
@@ -103,14 +104,9 @@ def test_chunk_pipeline_bit_identical_vs_uncoalesced(ens2, device_combine):
     Xs = [rng.integers(0, 512, (n, SEQ)).astype(np.int32) for n in sizes]
 
     with make_system(cfgs, params, np.array([[8, 8]]), segment_size=32,
-                     device_combine=device_combine, coalesce=False,
-                     max_in_flight=6) as ref:
-        Y_ref = [ref.predict(x, members=m, timeout=120.0)
+                     combine=combine, max_in_flight=6) as s:
+        Y_ref = [s.predict(x, members=m, timeout=120.0)
                  for x, m in zip(Xs, member_sets)]
-
-    with make_system(cfgs, params, np.array([[8, 8]]), segment_size=32,
-                     device_combine=device_combine, coalesce=True,
-                     max_in_flight=6) as s:
         opts = [PredictOptions(priority="high" if i % 2 else "normal")
                 for i in range(len(Xs))]
         handles = [s.predict_async(x, members=m, options=o)
@@ -129,7 +125,7 @@ def test_high_priority_chunk_jumps_saturated_ring(ens2):
     bulk chunks instead of waiting for RING_SLOTS slots."""
     cfgs, params = ens2
     with make_system(cfgs[:1], params[:1], np.array([[8]]), segment_size=32,
-                     fake=True, fake_delay_us=3000, coalesce=True,
+                     fake=True, fake_delay_us=3000,
                      max_in_flight=16, max_wait_us=100,
                      dispatch_ahead=2) as s:   # shallow committed window
         bulk = [s.predict_async(np.zeros((32, SEQ), np.int32))
@@ -160,7 +156,7 @@ def test_ring_slots_all_recycle_after_completion(ens2):
     the bit-identity test above, and leaks show up here."""
     cfgs, params = ens2
     with make_system(cfgs[:1], params[:1], np.array([[8]]), segment_size=32,
-                     coalesce=True, max_in_flight=8) as s:
+                     max_in_flight=8) as s:
         handles = [s.predict_async(
             np.random.default_rng(i).integers(0, 512, (24, SEQ))
             .astype(np.int32)) for i in range(8)]
@@ -182,7 +178,7 @@ def test_quiesce_barrier_with_queued_chunks(ens2):
     slow simulated-device chunks; the system keeps serving afterwards."""
     cfgs, params = ens2
     with make_system(cfgs[:1], params[:1], np.array([[8]]), segment_size=32,
-                     fake=True, fake_delay_us=2000, coalesce=True,
+                     fake=True, fake_delay_us=2000,
                      max_in_flight=8, max_wait_us=30_000_000,
                      dispatch_ahead=2) as s:
         handles = [s.predict_async(np.zeros((32, SEQ), np.int32))
@@ -220,7 +216,7 @@ def test_cancelled_request_chunks_dropped_at_dequeue(ens2, monkeypatch):
     cfgs, params = ens2
     release = _stall_predictor(monkeypatch, {"w0.0"})
     with make_system(cfgs[:1], params[:1], np.array([[8]]), segment_size=32,
-                     fake=True, coalesce=True, max_in_flight=8,
+                     fake=True, max_in_flight=8,
                      max_wait_us=100) as s:
         try:
             h = s.predict_async(np.zeros((32, SEQ), np.int32))
@@ -255,7 +251,7 @@ def test_expired_request_chunks_dropped_at_dequeue(ens2, monkeypatch):
     cfgs, params = ens2
     release = _stall_predictor(monkeypatch, {"w0.0"})
     with make_system(cfgs[:1], params[:1], np.array([[8]]), segment_size=32,
-                     fake=True, coalesce=True, max_in_flight=8,
+                     fake=True, max_in_flight=8,
                      max_wait_us=100) as s:
         try:
             h = s.predict_async(np.zeros((32, SEQ), np.int32),
@@ -311,7 +307,7 @@ def test_steal_prefers_tightest_deadline():
 def test_latency_snapshot_and_hp_gauge(ens2):
     cfgs, params = ens2
     with make_system(cfgs[:1], params[:1], np.array([[8]]), segment_size=16,
-                     fake=True, coalesce=True, max_wait_us=100) as s:
+                     fake=True, max_wait_us=100) as s:
         for i in range(4):
             s.predict(np.zeros((4, SEQ), np.int32), timeout=30.0,
                       options=PredictOptions(

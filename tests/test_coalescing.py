@@ -1,6 +1,7 @@
 """Coalescing-scheduler tests (ISSUE 2): cross-request batch packing,
 ensemble selection (``predict(members=...)``) under coalesced batches,
-device_combine parity, deterministic flush counts, row-count (not
+every combine rule against the reference, deterministic flush counts,
+row-count (not
 message-count) accounting in the combiner and accumulator, the quiesce
 flush, mismatched-seq buffer pooling, and best-fit input-buffer reuse."""
 import queue
@@ -65,7 +66,7 @@ def test_members_subsets_interleaved_under_coalescing(ens2):
     Xs = small_batch(np.random.default_rng(10), 12)
     member_sets = [[0], [1], [0, 1]]
     with make_system(cfgs, params, np.array([[8, 8]]), segment_size=32,
-                     combine="weighted", weights=w, coalesce=True,
+                     combine="weighted", weights=w,
                      max_in_flight=12) as s:
         handles = [s.predict_async(x, members=member_sets[i % 3])
                    for i, x in enumerate(Xs)]
@@ -77,23 +78,33 @@ def test_members_subsets_interleaved_under_coalescing(ens2):
         np.testing.assert_allclose(y, ref, atol=2e-5)
 
 
+def vote_oracle(cfgs, params, X):
+    """Each member votes for its argmax class with weight 1/members."""
+    out = np.zeros((X.shape[0], cfgs[0].vocab_size), np.float32)
+    for c, p in zip(cfgs, params):
+        top = oracle([c], [p], X).argmax(axis=1)
+        out[np.arange(X.shape[0]), top] += 1.0 / len(cfgs)
+    return out
+
+
 @pytest.mark.parametrize("combine", ["mean", "vote", "pallas"])
 def test_device_combine_parity_under_coalescing(ens2, combine):
-    """Acceptance: device_combine=True and =False produce identical outputs
-    under coalescing, for interleaved small requests with member subsets."""
+    """Acceptance: the device combine under coalescing matches the
+    reference of each request's member subset, for interleaved small
+    requests whose rows share batches."""
     cfgs, params = ens2
     Xs = small_batch(np.random.default_rng(11), 10)
     member_sets = [[0, 1], [1], [0]]
-    outs = {}
-    for dc in (True, False):
-        with make_system(cfgs, params, np.array([[8, 8]]), segment_size=32,
-                         combine=combine, coalesce=True, max_in_flight=10,
-                         device_combine=dc) as s:
-            handles = [s.predict_async(x, members=member_sets[i % 3])
-                       for i, x in enumerate(Xs)]
-            outs[dc] = [h.result(120.0) for h in handles]
-    for y_dev, y_host in zip(outs[True], outs[False]):
-        np.testing.assert_allclose(y_dev, y_host, atol=1e-5)
+    with make_system(cfgs, params, np.array([[8, 8]]), segment_size=32,
+                     combine=combine, max_in_flight=10) as s:
+        handles = [s.predict_async(x, members=member_sets[i % 3])
+                   for i, x in enumerate(Xs)]
+        Ys = [h.result(120.0) for h in handles]
+    ref_fn = vote_oracle if combine == "vote" else oracle
+    for i, (x, y) in enumerate(zip(Xs, Ys)):
+        ms = member_sets[i % 3]
+        ref = ref_fn([cfgs[m] for m in ms], [params[m] for m in ms], x)
+        np.testing.assert_allclose(y, ref, atol=2e-5)
 
 
 def test_deterministic_flush_counts_under_coalescing(ens2):
@@ -103,7 +114,7 @@ def test_deterministic_flush_counts_under_coalescing(ens2):
     cfgs, params = ens2
     Xs = small_batch(np.random.default_rng(12), 9, sizes=(5, 20, 40))
     with make_system(cfgs, params, np.array([[8, 8]]), segment_size=16,
-                     coalesce=True, max_in_flight=9) as s:
+                     max_in_flight=9) as s:
         before = s.accumulator.data_messages
         posted0 = sum(c.partials_posted for c in s.combiners.values())
         handles = [s.predict_async(x) for x in Xs]
@@ -123,7 +134,7 @@ def test_single_segment_requests_spread_across_instances(ens2):
     A = np.array([[8, 8],
                   [8, 0]])                  # model 0 data-parallel on d0+d1
     with make_system(cfgs, params, A, segment_size=16, fake=True,
-                     coalesce=True, max_in_flight=8) as s:
+                     max_in_flight=8) as s:
         handles = [s.predict_async(np.zeros((5, SEQ), np.int32))
                    for _ in range(8)]
         for h in handles:
@@ -184,24 +195,33 @@ def test_combiner_pallas_rule_row_spans():
 
 
 def test_accumulator_counts_rows_not_messages():
-    """A request owes n x members member-rows; split row_lo messages debit
-    their row counts and completion fires exactly when rows close."""
-    req = _mk_request(10, weights=(0.5, 0.5))
+    """A request owes n x members member-rows; each partial debits count x
+    segment rows.  Segment 0 closes with one count=1 partial from each of
+    two devices, segment 1 with one count=2 partial from one device: the
+    handle completes only once both devices have posted segment 0."""
+    req = _mk_request(20, weights=(0.5, 0.5))
     rng = np.random.default_rng(2)
-    P0 = rng.normal(size=(10, 8)).astype(np.float32)
-    P1 = rng.normal(size=(10, 8)).astype(np.float32)
+    d0, d1, d01 = (rng.normal(size=(16, 8)).astype(np.float32),
+                   rng.normal(size=(16, 8)).astype(np.float32),
+                   rng.normal(size=(4, 8)).astype(np.float32))
     q = queue.Queue()
     acc = PredictionAccumulator(q, 2, combine="weighted",
                                 weights=np.array([0.5, 0.5], np.float32))
     acc.start()
     try:
         handle = acc.begin(req)
-        assert handle.remaining == 20                  # rows, not messages
-        q.put(Message(0, 0, P0[:6], rid=0, row_lo=0))
-        q.put(Message(0, 0, P0[6:], rid=0, row_lo=6))
-        q.put(Message(0, 1, P1, rid=0, row_lo=0))
+        assert handle.remaining == 40                  # rows, not messages
+        q.put(Message(1, None, d01, rid=0, count=2))
+        q.put(Message(0, None, d0, rid=0, count=1))
+        deadline = time.perf_counter() + 10.0
+        while handle.remaining != 16 and time.perf_counter() < deadline:
+            time.sleep(0.01)
+        assert handle.remaining == 16                  # device 1 still owes
+        assert not handle.done.is_set()
+        q.put(Message(0, None, d1, rid=0, count=1))
         Y = handle.result(30.0)
-        np.testing.assert_allclose(Y, 0.5 * P0 + 0.5 * P1, atol=1e-5)
+        np.testing.assert_allclose(Y[:16], d0 + d1, atol=1e-6)
+        np.testing.assert_array_equal(Y[16:], d01)
         assert handle.messages == 3
     finally:
         acc.stop()
@@ -229,7 +249,7 @@ def test_quiesce_flushes_lingering_partial_batch(ens2):
     open batch; quiesce() force-flushes it."""
     cfgs, params = ens2
     with make_system(cfgs, params, np.array([[8, 8]]), segment_size=16,
-                     fake=True, coalesce=True, max_wait_us=30_000_000) as s:
+                     fake=True, max_wait_us=30_000_000) as s:
         h = s.predict_async(np.zeros((3, SEQ), np.int32))
         time.sleep(0.3)
         assert not h.done.is_set()          # batch is lingering open
@@ -242,7 +262,7 @@ def test_bounded_linger_flushes_without_quiesce(ens2):
     flushes on its own once max_wait_us expires."""
     cfgs, params = ens2
     with make_system(cfgs, params, np.array([[8, 8]]), segment_size=16,
-                     fake=True, coalesce=True, max_wait_us=1000) as s:
+                     fake=True, max_wait_us=1000) as s:
         t0 = time.perf_counter()
         s.predict(np.zeros((3, SEQ), np.int32), timeout=30.0)
         assert time.perf_counter() - t0 < 5.0
@@ -256,7 +276,7 @@ def test_mismatched_seq_buffers_are_pooled(ens2):
     cfgs, params = ens2
     alt_seq = SEQ // 2
     with make_system(cfgs, params, np.array([[8, 8]]), segment_size=16,
-                     fake=True, coalesce=True) as s:
+                     fake=True) as s:
         for _ in range(6):
             Y = s.predict(np.zeros((20, alt_seq), np.int32), timeout=30.0)
             assert Y.shape == (20, cfgs[0].vocab_size)
@@ -292,7 +312,7 @@ def test_padding_counters_and_queue_gauge(ens2):
     cfgs, params = ens2
     X = np.random.default_rng(13).integers(0, 512, (20, SEQ)).astype(np.int32)
     with make_system(cfgs, params, np.array([[8, 8]]), segment_size=16,
-                     fake=True, coalesce=True) as s:
+                     fake=True) as s:
         s.predict(X, timeout=30.0)
         c = s.serving_counters()
         assert c["batches"] > 0 and c["spans"] > 0

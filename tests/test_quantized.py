@@ -193,18 +193,20 @@ def test_int8_member_subsets_track_fp32(ens2):
             assert _rel_err(Y, Yref) < 0.05, members
 
 
-def test_int8_host_combine_path(ens2):
-    """device_combine=False: no device-resident partials, so workers ship
-    fp32 logits computed from quantized params (no logit quantization)."""
+@pytest.mark.parametrize("combine", ["pallas", "mean"])
+def test_int8_members_on_two_devices_track_fp32(ens2, combine):
+    """One int8 member on each of two devices: each device's combiner folds
+    its member's (q, scale) logits into its own partial, and the two
+    partials add up to within int8 tolerance of the fp32 system."""
     cfgs, params = ens2
     X = _X(30, seed=6)
-    with make_system(cfgs, params, [[8, 8]], segment_size=16,
-                     device_combine=False) as s:
+    kw = dict(segment_size=16, combine=combine)
+    with make_system(cfgs, params, [[8, 0], [0, 8]], **kw) as s:
         Yref = s.predict(X)
-    with make_system(cfgs, params, [[8, 8]], segment_size=16,
-                     device_combine=False,
-                     member_dtypes=["int8", "int8"]) as s:
+    with make_system(cfgs, params, [[8, 0], [0, 8]],
+                     member_dtypes=["int8", "int8"], **kw) as s:
         Y = s.predict(X)
+        assert all(c.partials_posted for c in s.combiners.values())
     assert _rel_err(Y, Yref) < 0.05
 
 

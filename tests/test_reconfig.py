@@ -1,7 +1,7 @@
 """Online-reconfiguration tests (ISSUE 4): live instance spawn/drain with
 zero dropped or corrupted in-flight requests (exact-result assertions),
 cross-worker work stealing with expected-map migration (bit-identical
-combine results vs no-steal, member subsets, device_combine parity),
+combine results vs no-steal, member subsets, mean and pallas partials),
 re-entrant quiesce, deadline-aware linger, the LiveBench profile, and the
 controller's replan/apply loop."""
 import queue
@@ -144,13 +144,14 @@ def _stall_batcher(monkeypatch, worker_ids):
     return release
 
 
-@pytest.mark.parametrize("device_combine", [True, False])
-def test_steal_end_to_end_bit_identical(ens2, monkeypatch, device_combine):
+@pytest.mark.parametrize("combine", ["mean", "pallas"])
+def test_steal_end_to_end_bit_identical(ens2, monkeypatch, combine):
     """Stolen descriptors produce bit-identical results vs no-steal,
-    including member subsets, with and without the device combine.  The
-    victim's batcher is frozen, so every one of its descriptors completes
-    via the sibling — proving the re-route AND the expected-map migration
-    (the request could never finish otherwise)."""
+    including member subsets, for mean partials and for the Pallas
+    accumulate kernel's partials.  The victim's batcher is frozen, so
+    every one of its descriptors completes via the sibling on the other
+    device — proving the re-route AND the expected-map migration between
+    the two combiners (the request could never finish otherwise)."""
     cfgs, params = ens2
     A = np.array([[8, 8],
                   [8, 0]])                # member 0 data-parallel on d0+d1
@@ -159,14 +160,14 @@ def test_steal_end_to_end_bit_identical(ens2, monkeypatch, device_combine):
     Xs = [rng.integers(0, 512, (24, SEQ)).astype(np.int32)
           for _ in member_sets]
 
-    with make_system(cfgs, params, A, segment_size=32,
-                     device_combine=device_combine, max_in_flight=8) as ref:
+    with make_system(cfgs, params, A, segment_size=32, combine=combine,
+                     max_in_flight=8) as ref:
         Y_ref = [ref.predict(x, members=m, timeout=120.0)
                  for x, m in zip(Xs, member_sets)]
 
     release = _stall_batcher(monkeypatch, {"w0.0"})
-    with make_system(cfgs, params, A, segment_size=32,
-                     device_combine=device_combine, max_in_flight=8) as s:
+    with make_system(cfgs, params, A, segment_size=32, combine=combine,
+                     max_in_flight=8) as s:
         try:
             w_stalled = [w for w in s.instances(0)
                          if w.worker_id == "w0.0"][0]
@@ -353,7 +354,7 @@ def test_quiesce_then_predict_cycles(ens2):
     quiesce(wait=True) blocks until every batcher processed its flush."""
     cfgs, params = ens2
     with make_system(cfgs, params, np.array([[8, 8]]), segment_size=16,
-                     fake=True, coalesce=True, max_wait_us=30_000_000) as s:
+                     fake=True, max_wait_us=30_000_000) as s:
         for _ in range(3):
             h = s.predict_async(np.zeros((3, SEQ), np.int32))
             time.sleep(0.05)
@@ -375,7 +376,7 @@ def test_deadline_bounds_linger(ens2):
     (ROADMAP item f)."""
     cfgs, params = ens2
     with make_system(cfgs, params, np.array([[8, 8]]), segment_size=16,
-                     fake=True, coalesce=True, max_wait_us=30_000_000) as s:
+                     fake=True, max_wait_us=30_000_000) as s:
         t0 = time.perf_counter()
         Y = s.predict(np.zeros((3, SEQ), np.int32), timeout=30.0,
                       options=PredictOptions(deadline_ms=4000))
@@ -387,7 +388,7 @@ def test_deadline_bounds_linger(ens2):
 def test_deadline_linger_expired_request_still_fails_fast(ens2):
     cfgs, params = ens2
     with make_system(cfgs, params, np.array([[8, 8]]), segment_size=16,
-                     fake=True, coalesce=True, max_wait_us=30_000_000) as s:
+                     fake=True, max_wait_us=30_000_000) as s:
         h = s.predict_async(np.zeros((3, SEQ), np.int32),
                             options=PredictOptions(deadline_ms=0.01))
         with pytest.raises(DeadlineExceeded):

@@ -110,7 +110,7 @@ def test_high_priority_overtakes_bulk_scan(ens2):
     bulk = np.zeros((8192, SEQ), np.int32)          # 512 segments/member
     small = np.zeros((4, SEQ), np.int32)
     with make_system(cfgs, params, np.array([[8, 8]]), segment_size=16,
-                     fake=True, coalesce=True) as s:
+                     fake=True) as s:
         h_bulk = s.predict_async(bulk)
         h_high = s.predict_async(small,
                                  options=PredictOptions(priority="high"))
@@ -127,7 +127,7 @@ def test_high_priority_preempts_linger(ens2):
     lingering in a partial batch."""
     cfgs, params = ens2
     with make_system(cfgs, params, np.array([[8, 8]]), segment_size=16,
-                     fake=True, coalesce=True, max_wait_us=30_000_000) as s:
+                     fake=True, max_wait_us=30_000_000) as s:
         t0 = time.perf_counter()
         s.predict(np.zeros((3, SEQ), np.int32), timeout=30.0,
                   options=PredictOptions(priority="high"))
@@ -154,7 +154,7 @@ def test_deadline_expires_in_admission_queue(ens2):
     cfgs, params = ens2
     bulk = np.zeros((8192, SEQ), np.int32)
     with make_system(cfgs, params, np.array([[8, 8]]), segment_size=16,
-                     fake=True, coalesce=True) as s:
+                     fake=True) as s:
         rows0 = s.serving_counters().get("rows_valid", 0.0)
         s.predict_async(bulk)                        # saturate the queue
         h = s.predict_async(np.zeros((4, SEQ), np.int32),
@@ -176,7 +176,7 @@ def test_cancel_releases_window_and_keeps_serving(ens2):
     cfgs, params = ens2
     bulk = np.zeros((4096, SEQ), np.int32)
     with make_system(cfgs, params, np.array([[8, 8]]), segment_size=16,
-                     fake=True, coalesce=True, max_in_flight=2) as s:
+                     fake=True, max_in_flight=2) as s:
         h_bulk = s.predict_async(bulk)
         h2 = s.predict_async(np.zeros((4, SEQ), np.int32))
         buf2 = h2.req.x
@@ -202,7 +202,7 @@ def test_cancel_with_real_models_keeps_results_correct(ens2):
     rng = np.random.default_rng(3)
     Xs = [rng.integers(0, 512, (5, SEQ)).astype(np.int32) for _ in range(6)]
     with make_system(cfgs, params, np.array([[8, 8]]), segment_size=32,
-                     coalesce=True, max_in_flight=8) as s:
+                     max_in_flight=8) as s:
         handles = [s.predict_async(x) for x in Xs]
         handles[2].cancel()
         handles[4].cancel()
@@ -370,7 +370,7 @@ def test_adaptive_linger_flushes_under_backlog(ens2):
     allow if each new slot waited out the full fixed linger."""
     cfgs, params = ens2
     with make_system(cfgs, params, np.array([[8, 8]]), segment_size=16,
-                     fake=True, coalesce=True, max_wait_us=2_000_000,
+                     fake=True, max_wait_us=2_000_000,
                      linger="adaptive", max_in_flight=32) as s:
         handles = [s.predict_async(np.zeros((24, SEQ), np.int32))
                    for _ in range(32)]

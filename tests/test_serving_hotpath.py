@@ -121,35 +121,44 @@ def test_weighted_combine_member_subset(ens2):
     np.testing.assert_allclose(y1, oracle(cfgs[1:], params[1:], X), atol=2e-5)
 
 
-@pytest.mark.parametrize("device_combine", [True, False])
-def test_vote_combine_member_subset(ens2, device_combine):
+# one device folds both members into one partial; two devices each fold
+# one member, and the accumulator adds the two combiners' partials
+PLACEMENTS = {"one_device": [[8, 8]], "two_devices": [[8, 0], [0, 8]]}
+
+
+@pytest.mark.parametrize("placement", PLACEMENTS)
+def test_vote_combine_member_subset(ens2, placement):
     cfgs, params = ens2
+    A = np.array(PLACEMENTS[placement])
     X = np.random.default_rng(3).integers(0, 512, (20, SEQ)).astype(np.int32)
-    with make_system(cfgs, params, np.array([[8, 8]]), combine="vote",
-                     segment_size=16, device_combine=device_combine) as s:
+    with make_system(cfgs, params, A, combine="vote",
+                     segment_size=16) as s:
         y_all = s.predict(X)
         y_sub = s.predict(X, members=[0])
+        assert len(s.combiners) == A.shape[0]
+        assert all(c.partials_posted for c in s.combiners.values())
     np.testing.assert_allclose(y_all.sum(axis=1), 1.0, atol=1e-6)
     # single-member vote: exactly one class gets weight 1.0 per row
     np.testing.assert_allclose(y_sub.max(axis=1), 1.0, atol=1e-6)
     np.testing.assert_allclose(y_sub.sum(axis=1), 1.0, atol=1e-6)
+    np.testing.assert_array_equal(
+        y_sub.argmax(axis=1),
+        oracle(cfgs[:1], params[:1], X).argmax(axis=1))
 
 
-@pytest.mark.parametrize("device_combine", [True, False])
+@pytest.mark.parametrize("placement", PLACEMENTS)
 @pytest.mark.parametrize("n", [37, 40])       # 37: non-block-aligned segments
-def test_pallas_combine_non_aligned(ens2, device_combine, n):
+def test_pallas_combine_non_aligned(ens2, placement, n):
     cfgs, params = ens2
+    A = np.array(PLACEMENTS[placement])
     X = np.random.default_rng(4).integers(0, 512, (n, SEQ)).astype(np.int32)
-    with make_system(cfgs, params, np.array([[8, 8]]), segment_size=16) as s:
+    with make_system(cfgs, params, A, segment_size=16) as s:
         Y_mean = s.predict(X)
-    with make_system(cfgs, params, np.array([[8, 8]]), combine="pallas",
-                     segment_size=16, device_combine=device_combine) as s:
+    with make_system(cfgs, params, A, combine="pallas",
+                     segment_size=16) as s:
         Y_pallas = s.predict(X)
-    np.testing.assert_allclose(Y_mean, Y_pallas, atol=1e-5)
-
-    with make_system(cfgs, params, np.array([[8, 8]]), combine="pallas",
-                     segment_size=16, device_combine=device_combine) as s:
         Y_sub = s.predict(X, members=[1])
+    np.testing.assert_allclose(Y_mean, Y_pallas, atol=1e-5)
     np.testing.assert_allclose(Y_sub, oracle(cfgs[1:], params[1:], X),
                                atol=2e-5)
 
@@ -161,20 +170,14 @@ def test_partial_combine_message_reduction(ens2):
     drop from M x segments to devices x segments."""
     cfgs, params = ens2
     X = np.random.default_rng(5).integers(0, 512, (64, SEQ)).astype(np.int32)
-    with make_system(cfgs, params, np.array([[8, 8]]), segment_size=16,
-                     device_combine=True) as s:
+    with make_system(cfgs, params, np.array([[8, 8]]),
+                     segment_size=16) as s:
         before = s.accumulator.data_messages
-        Y1 = s.predict(X)
+        Y = s.predict(X)
         assert s.accumulator.data_messages - before == 4      # 1 dev x 4 segs
         assert s.combiners and all(c.partials_posted for c in
                                    s.combiners.values())
-    with make_system(cfgs, params, np.array([[8, 8]]), segment_size=16,
-                     device_combine=False) as s:
-        before = s.accumulator.data_messages
-        Y2 = s.predict(X)
-        # senders reassemble spans before forwarding: still M=2 x 4 segs
-        assert s.accumulator.data_messages - before == 8
-    np.testing.assert_allclose(Y1, Y2, atol=2e-5)
+    np.testing.assert_allclose(Y, oracle(cfgs, params, X), atol=2e-5)
 
 
 def test_partial_combine_data_parallel(ens2):
@@ -184,8 +187,7 @@ def test_partial_combine_data_parallel(ens2):
     X = np.random.default_rng(6).integers(0, 512, (100, SEQ)).astype(np.int32)
     A = np.array([[8, 8],
                   [16, 0]])
-    with make_system(cfgs, params, A, segment_size=16,
-                     device_combine=True) as s:
+    with make_system(cfgs, params, A, segment_size=16) as s:
         before = s.accumulator.data_messages
         Y = s.predict(X)
         msgs = s.accumulator.data_messages - before

@@ -3,6 +3,7 @@ counts each stage makes per request, chunk, flush and posted partial, and
 the ``serving.<stage>`` spans a ``jax.profiler`` session records."""
 import glob
 import os
+import threading
 
 import jax
 import numpy as np
@@ -33,10 +34,10 @@ def ens2():
     return cfgs, params
 
 
-def make_system(cfgs, params, **kw):
-    devs = host_cpus(1, memory_bytes=8 * 1024 ** 3)
-    alloc = AllocationMatrix(devs, [c.name for c in cfgs],
-                             np.array([[8, 8]]))
+def make_system(cfgs, params, A=((8, 8),), **kw):
+    A = np.array(A)
+    devs = host_cpus(A.shape[0], memory_bytes=8 * 1024 ** 3)
+    alloc = AllocationMatrix(devs, [c.name for c in cfgs], A)
     kw.setdefault("max_seq", SEQ)
     kw.setdefault("segment_size", 8)
     return InferenceSystem(cfgs, params, alloc, **kw)
@@ -85,13 +86,30 @@ def test_stage_counts_per_request_flush_chunk_and_partial(ens2):
     assert "transfer" not in counts and "batcher_wait" not in counts
 
 
-def test_host_combine_copies_each_chunk_after_its_device_wait(ens2):
+def test_each_device_copies_its_partial_after_the_combine_wait(ens2):
+    """With one member on each of two devices, each device's combiner posts
+    one partial per segment: one ``copy`` per partial posted, each on the
+    thread that waited for that partial's fold just before."""
     cfgs, params = ens2
-    with make_system(cfgs, params, device_combine=False) as s:
+    with make_system(cfgs, params, A=((8, 0), (0, 8))) as s:
+        order, add = [], s.timers.add
+
+        def add_in_order(stage, dt):
+            order.append((threading.get_ident(), stage))
+            add(stage, dt)
+
+        s.timers.add = add_in_order
         _requests(s)
-        counts, counters = _counts(s), s.serving_counters()
-    assert counts["device_wait"] == counts["copy"] == counters["batches"]
-    assert "combine" not in counts and "combine_wait" not in counts
+        counts = _counts(s)
+        posted = [c.partials_posted for c in s.combiners.values()]
+    segments = sum(-(-n // 8) for n in ROWS)
+    assert posted == [segments, segments]
+    assert counts["copy"] == counts["combine_wait"] == sum(posted)
+    last = {}
+    for tid, stage in order:
+        if stage == "copy":
+            assert last.get(tid) == "combine_wait"
+        last[tid] = stage
 
 
 def test_no_profiler_session_times_the_stage_and_opens_no_span():
