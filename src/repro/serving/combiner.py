@@ -18,13 +18,30 @@ flushes the moment ``members_on_device × segment_rows`` rows have been
 folded, which is reached exactly once however the spans were packed.
 
 Early-forward audit (chunk-granular pipeline, DESIGN.md §3): senders now
-forward a (request, segment) the moment its last chunk materializes —
+commit a (request, segment) the moment its last chunk materializes —
 before the slot retires, and under priority reordering possibly *out of
 segment order* and interleaved arbitrarily across members.  The row
 arithmetic above is already order-free (each (segment, member) contributes
 its rows exactly once, whenever it arrives), so nothing here changes; the
 same holds for the `unexpect`/`expect_one` steal migration, which operates
 on counts, not arrival order.
+
+Fold at dispatch, commit at the sender (DESIGN.md §4): the chip runs
+programs in the order they were dispatched, so a fold dispatched after the
+sender's device wait queues behind every step dispatched meanwhile — the
+other member's and later requests'.  The worker's *predictor* therefore
+calls :meth:`add` for a (request, segment) right behind the step that
+carries its last span: the fold's inputs are futures, nothing blocks, and
+the fold program enters the device queue directly behind its own step.
+The *sender* calls :meth:`commit` once the step's output is materialized,
+has passed the NaN guard and the sender has won the in-flight ledger entry
+(the pop-gate, DESIGN.md §10): only committed rows count toward the flush.
+A folded contribution that is never committed (its worker quarantined and
+the unit replayed or forgiven, its member demoted after dispatch, its
+chunk poisoned) must leave no trace: the partial keeps a reference to each
+contribution folded into it, and a partial posted with any uncommitted
+fold is rebuilt from its committed contributions alone (``fold_rebuilds``;
+the slow path).
 
 Combination rules are applied member-side, so the partial is always additive:
   mean/weighted  partial[lo:hi] += w_m · P_m[lo:hi]
@@ -47,18 +64,34 @@ from repro.serving.metrics import StageTimers
 from repro.serving.segments import Message, Request
 
 
+class _Fold:
+    """One member contribution folded into a partial at dispatch time; it
+    counts toward the flush only once committed."""
+    __slots__ = ("part", "contrib", "w", "row_lo", "rows", "committed")
+
+    def __init__(self, part, contrib, w: float, row_lo: int, rows: int):
+        self.part = part           # the _SegPartial it was folded into
+        self.contrib = contrib     # kept for a rebuild until the post
+        self.w = w
+        self.row_lo = row_lo
+        self.rows = rows
+        self.committed = False
+
+
 class _SegPartial:
-    __slots__ = ("acc", "rows")
+    __slots__ = ("acc", "rows", "folds")
 
     def __init__(self):
         self.acc = None        # np.ndarray or jax.Array (device-resident)
-        self.rows = 0          # member-rows folded so far
+        self.rows = 0          # committed member-rows
+        self.folds = []        # every _Fold in acc, committed or not
 
 
 class DeviceCombiner:
     """One per device hosting >= 1 worker.  ``add()`` is called from worker
-    sender threads; a per-combiner lock serializes the fold bookkeeping (the
-    device math itself is dispatched asynchronously)."""
+    predictor threads and ``commit()`` from their sender threads; a
+    per-combiner lock serializes the fold bookkeeping (the device math
+    itself is dispatched asynchronously)."""
 
     def __init__(self, name: str, prediction_queue: "queue.Queue[Message]",
                  timers: Optional[StageTimers] = None, tracer=None,
@@ -117,8 +150,9 @@ class DeviceCombiner:
             want_rows -= hi - lo
             part = self._parts.get((req.rid, s))
             if count <= 0:
-                # no member left on this device: nothing can have been folded
-                # (each (segment, member) routes to exactly one instance)
+                # no member left on this device: nothing here can commit
+                # (each (segment, member) routes to exactly one instance),
+                # so whatever was folded ahead is dropped with the partial
                 self._parts.pop((req.rid, s), None)
                 del expected[s]
             elif part is not None and part.rows >= want_rows:
@@ -130,7 +164,7 @@ class DeviceCombiner:
             if not expected:
                 del self._expected[req.rid]
         if flush is not None:
-            self._post(req.rid, s, *flush)
+            self._post(req, s, *flush)
         return True
 
     def expect_one(self, req: Request, s: int) -> None:
@@ -142,58 +176,92 @@ class DeviceCombiner:
             count, want_rows = expected.get(s, (0, 0))
             expected[s] = (count + 1, want_rows + (hi - lo))
 
-    # ---- the fold ------------------------------------------------------------
-    def add(self, req: Request, s: int, m: int, P, row_lo: int = 0) -> None:
+    # ---- the fold (predictor) and the commit (sender) ------------------------
+    def add(self, req: Request, s: int, m: int, P,
+            row_lo: int = 0) -> Optional[_Fold]:
         """Fold member ``m``'s rows ``[row_lo, row_lo+len(P))`` of segment
-        ``s`` into the device partial; post the partial once the segment's
-        expected row count is reached.  ``P`` may be a numpy array (fake
-        workers) or a device array — device arrays stay resident until the
-        single flush transfer.  The ``combine`` stage times the fold; the
-        flush is :meth:`_post`."""
-        flush = None
+        ``s`` into the device partial and return the fold, which counts
+        toward the flush only once :meth:`commit` is called with it.  ``P``
+        may be a numpy array (fake workers) or a device array — device
+        arrays stay resident until the single flush transfer, and nothing
+        here waits for them.  The ``combine`` stage times the fold.
+        Returns None when the request is no longer tracked here."""
         # quantized members forward (q, per-row scale) tuples
         nrows = int(P[0].shape[0]) if isinstance(P, tuple) else int(P.shape[0])
         with self.timers.stage("combine") as fold:
             # the heavy elementwise math runs outside the lock; only the
             # accumulate + bookkeeping is serialized
-            contrib = self._contribution(req, P, req.weights[m])
+            w = req.weights[m]
+            contrib = self._contribution(req, P, w)
             with self._lock:
                 expected = self._expected.get(req.rid)
                 if expected is None or s not in expected:   # torn down
-                    return
+                    return None
                 part = self._parts.setdefault((req.rid, s), _SegPartial())
-                part.acc = self._fold(req, part.acc, contrib,
-                                      req.weights[m], s, row_lo)
-                part.rows += nrows
-                count, want_rows = expected[s]
-                if part.rows >= want_rows:
-                    flush = (part, count)
-                    del self._parts[(req.rid, s)]
-                    del expected[s]
-                    if not expected:
-                        del self._expected[req.rid]
-        t1 = fold.t1
-        if flush is not None:
-            t1 = self._post(req.rid, s, *flush)
+                part.acc = self._fold(req, part.acc, contrib, w, s, row_lo)
+                f = _Fold(part, contrib, w, row_lo, nrows)
+                part.folds.append(f)
+        self.timers.inc("folds_ahead")
         tr = self.tracer
         if tr is not None and tr.enabled:
             self._tr_ring.append(
-                ("X", "combine", fold.t0, t1 - fold.t0, req.rid,
-                 s, m, flush is not None))
+                ("X", "combine", fold.t0, fold.t1 - fold.t0, req.rid,
+                 s, m, None))
+        return f
 
-    def _post(self, rid: int, s: int, part: _SegPartial, count: int) -> float:
-        """The single device->host copy per device per segment.  The wait
-        for the partial's fold program (which the chip may run behind
-        queued member steps) is the ``combine_wait`` stage, the copy alone
-        ``copy``; returns the clock when the copy ended."""
-        with self.timers.stage("combine_wait"):
+    def commit(self, req: Request, s: int, f: Optional[_Fold]) -> None:
+        """Count fold ``f`` (from :meth:`add`) toward its segment's flush
+        and post the partial once the expected row count is reached.  The
+        caller commits a fold at most once, and only after the sender won
+        the unit's ledger entry; a fold whose partial was torn down or
+        dropped meanwhile is ignored."""
+        if f is None:
+            return
+        flush = None
+        with self._lock:
+            if self._parts.get((req.rid, s)) is not f.part:
+                return                    # torn down, or its partial dropped
+            part = f.part
+            f.committed = True
+            part.rows += f.rows
+            expected = self._expected[req.rid]
+            count, want_rows = expected[s]
+            if part.rows >= want_rows:
+                flush = (part, count)
+                del self._parts[(req.rid, s)]
+                del expected[s]
+                if not expected:
+                    del self._expected[req.rid]
+        if flush is not None:
+            self._post(req, s, *flush)
+
+    def _post(self, req: Request, s: int, part: _SegPartial,
+              count: int) -> None:
+        """The single device->host copy per device per segment, of the
+        committed contributions only.  The wait for the partial's fold
+        programs is the ``combine_wait`` stage, the copy alone ``copy``."""
+        if any(not f.committed for f in part.folds):
+            # a withdrawn contribution was folded ahead: rebuild the
+            # partial from the committed ones (the slow path)
+            acc = None
+            for f in part.folds:
+                if f.committed:
+                    acc = self._fold(req, acc, f.contrib, f.w, s, f.row_lo)
+            part.acc = acc
+            self.timers.inc("fold_rebuilds")
+        with self.timers.stage("combine_wait") as wait:
             if not isinstance(part.acc, np.ndarray):   # fake workers: host
                 jax.block_until_ready(part.acc)
         with self.timers.stage("copy") as copy:
             acc = np.asarray(part.acc)
-        self.prediction_queue.put(Message(s, None, acc, rid=rid, count=count))
+        self.prediction_queue.put(Message(s, None, acc, rid=req.rid,
+                                          count=count))
         self.partials_posted += 1
-        return copy.t1
+        tr = self.tracer
+        if tr is not None and tr.enabled:
+            self._tr_ring.append(
+                ("X", "post", wait.t0, copy.t1 - wait.t0, req.rid,
+                 s, count, None))
 
     @staticmethod
     def _contribution(req: Request, P, w: float):
@@ -225,14 +293,17 @@ class DeviceCombiner:
     def _fold(self, req: Request, acc, contrib, w: float, s: int, row_lo: int):
         """Fold a span contribution into the full-segment partial at its row
         offset.  The partial is allocated once per (request, segment) at the
-        segment's full row count, host- or device-side matching the first
-        contribution (device-side on this combiner's own device)."""
+        segment's full row count, host-side while every contribution is a
+        host array, else on this combiner's own device.  A host
+        contribution (fake workers, an injected fault) is already
+        weighted."""
         lo, hi = req.bounds(s)
         seg_rows = hi - lo
         quant = isinstance(contrib, tuple)     # (q, per-row scale) pair
+        host = not quant and isinstance(contrib, np.ndarray)
         a = row_lo
         b = row_lo + int(contrib[0].shape[0] if quant else contrib.shape[0])
-        if not quant and isinstance(contrib, np.ndarray):
+        if host and (acc is None or isinstance(acc, np.ndarray)):
             if acc is None:
                 acc = np.zeros((seg_rows, req.num_classes), np.float32)
             acc[a:b] += contrib                # in-place: no temp per fold
@@ -241,7 +312,9 @@ class DeviceCombiner:
         if acc is None:
             acc = jnp.zeros((seg_rows, req.num_classes), jnp.float32,
                             device=self.device)
-        if req.combine == "pallas":
+        elif isinstance(acc, np.ndarray):      # host folds so far
+            acc = jax.device_put(acc, self.device)
+        if req.combine == "pallas" and not host:
             from repro.kernels import ops as kops
             w = jnp.full((1,), w, jnp.float32, device=self.device)
             if quant:

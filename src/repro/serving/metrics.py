@@ -11,11 +11,12 @@ name and one clock:
   ``inflight_wait``  one request's wait for a slot of the in-flight window,
   ``slot_wait``      one bulk batch opening's wait for a free ring slot,
   ``linger``         one batch, from its opening to its flush,
-  ``predict``        one predictor round, pop to last dispatch (async --
-                     excludes device time),
+  ``predict``        one predictor round, pop to last dispatch, its folds
+                     included (async -- excludes device time),
   ``device_wait``    the sender's wait for one dispatched chunk's output,
   ``copy``           one device partial's device->host copy when posted,
   ``combine``        one member contribution's fold into a device partial,
+                     dispatched by the predictor behind its step,
   ``combine_wait``   the wait for one device partial's fold program before
                      its copy,
   ``accumulate``     one accumulator fold.
@@ -38,7 +39,11 @@ Counters (monotonic sums) instrument the coalescing scheduler:
                        (or instead of) device time,
   ``batches``          compiled-batch dispatches,
   ``spans``            (request, segment, row-range) spans packed into
-                       batches — spans/batches is the coalescing factor.
+                       batches — spans/batches is the coalescing factor,
+  ``folds_ahead``      member contributions folded into a device partial at
+                       dispatch,
+  ``fold_rebuilds``    device partials rebuilt at their post because a
+                       folded contribution was withdrawn before its commit.
 
 Gauges record last/max/mean of a sampled value (e.g.
 ``queue_depth.<worker_id>``, that batcher's input-queue backlog at each

@@ -106,7 +106,8 @@ _SLOT_KEYS = {
     "transfer": ("chunks",),
     "dropped": ("s",),
     "forgive_demoted": ("s",),
-    "combine": ("s", "m", "posted"),
+    "combine": ("s", "m"),
+    "post": ("s", "count"),
     "accumulate": ("s", "rows"),
 }
 

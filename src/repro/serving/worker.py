@@ -2,10 +2,11 @@
 
 Faithful to paper Fig. 2 — three asynchronous threads per worker:
   * the *batcher* coalesces incoming segment rows into padded batches,
-  * the *predictor* owns the params on its device and runs the jitted step,
-  * the *prediction sender* scatters batch outputs back to their segments
-    and folds each finished segment into its device's
-    :class:`~repro.serving.combiner.DeviceCombiner`.
+  * the *predictor* owns the params on its device, runs the jitted step and
+    folds each segment it completes into its device's
+    :class:`~repro.serving.combiner.DeviceCombiner` right behind that step,
+  * the *prediction sender* waits for each step's output and commits the
+    segments it completes to the combiner.
 
 Hardware adaptation (DESIGN.md §2): the paper uses one OS process per worker
 (TF1 sessions hold the GIL); with JAX, XLA executions release the GIL and
@@ -64,13 +65,16 @@ priority :class:`~repro.serving.admission.DispatchQueue` as an independent
     only after every chunk's output is materialized — on CPU ``device_put``
     may alias host memory, so one chunk retiring early must not free rows
     another chunk still reads;
-  * the sender forwards a (request, segment) contribution **as soon as its
-    last span's chunk returns** (early per-segment forwarding) rather than
-    when the whole slot retires; spans may now materialize out of order
+  * the predictor folds a (request, segment) contribution into the device
+    partial **as soon as its last span's chunk is dispatched**, so the fold
+    program enters the device queue right behind that step and not behind
+    whatever was dispatched while it ran (DESIGN.md §4); the sender commits
+    it once that chunk returns (early per-segment forwarding) rather than
+    when the whole slot retires.  Spans may be dispatched out of order
     within a segment (a mixed chunk rides the high-priority class while its
     bulk siblings queue), so reassembly is row-count-based with parts keyed
     by segment offset.  Still ONE contribution per (request, segment) —
-    per-span forwarding would multiply combiner/accumulator traffic by
+    per-span folds would multiply combiner/accumulator traffic by
     chunks-per-segment;
   * per-stage wall-clock counters (metrics.StageTimers) instrument the
     wait for a ring slot, each batch's linger, batch fill, per-class
@@ -344,9 +348,9 @@ class Worker:
         # here instead of posting the paper's global {-1, None, None}
         self.on_crash: Optional[Callable[["Worker", BaseException], None]] = None
         # in-flight ledger: (rid, s) -> Request for every descriptor admitted
-        # by the batcher but not yet forwarded by the sender.  The sender
-        # pops an entry IMMEDIATELY BEFORE posting its completed
-        # contribution and skips the post when the pop misses — dict ops
+        # by the batcher but not yet committed by the sender.  The sender
+        # pops an entry IMMEDIATELY BEFORE committing its completed
+        # contribution and skips the commit when the pop misses — dict ops
         # are GIL-atomic, so the pop is a perfect mutual-exclusion gate
         # between the sender and a supervisor replaying this worker's
         # in-flight units (replay idempotency; a late wakeup of a stalled
@@ -408,9 +412,9 @@ class Worker:
                           if self.frontend is not None else None)
                     y = self.predict_fn(self.params, self._to_device(
                         np.zeros((b, max_seq), np.int32)), fe)
-                    # the sender cuts span rows out of the output on the
-                    # device: one slice program per (bucket, rows), the
-                    # row offset being an argument
+                    # the predictor cuts span rows out of the output on
+                    # the device: one slice program per (bucket, rows),
+                    # the row offset being an argument
                     jax.block_until_ready([leaf[:n]
                                            for leaf in jax.tree.leaves(y)
                                            for n in range(1, b)])
@@ -787,7 +791,9 @@ class Worker:
         outstanding.  A window token is acquired *before* each pop, so a
         chunk only leaves the queue when it can dispatch immediately — the
         queue stays free to reorder until the last moment, and K bounds the
-        committed (non-preemptible) work.  Dispatched chunks accumulate in
+        committed (non-preemptible) work.  Right behind the step that
+        carries a segment's last span, the segment is folded into the
+        combiner (:meth:`_fold_ahead`).  Dispatched chunks accumulate in
         a local group shipped to the sender in ONE queue hop whenever the
         window fills, the queue runs dry, or a control item arrives —
         per-chunk hand-offs would pay a thread wakeup per chunk
@@ -795,9 +801,10 @@ class Worker:
         since the window token is what gates commitment.  A chunk whose
         every span belongs to a cancelled/expired request is never
         dispatched: it rides the group as a skipped chunk (the sender owns
-        the staging dict and the DROPPED accounting)."""
+        the DROPPED accounting)."""
         tr = self.tracer
         tr_ring = tr.ring(self._tr_predict) if tr is not None else None
+        staging: Dict[tuple, list] = {}     # (rid, s) -> [rows, {seg_off: P}]
         while True:
             # grab every instantly-available window token (>= 1, blocking
             # for the first) and pop that many chunks in ONE queue lock
@@ -854,7 +861,9 @@ class Worker:
                                 seg.PRIORITY_HIGH else "dispatch_wait.normal",
                                 t0 - chunk.t_enq)
                 if _skippable(chunk):
-                    group.append((chunk, None, t0, True))   # never dispatched
+                    for sp in chunk.spans:      # its segments never fold here
+                        staging.pop((sp.req.rid, sp.s), None)
+                    group.append((chunk, None, t0, True, None))
                     continue
                 committed += 1
                 y = None
@@ -889,7 +898,9 @@ class Worker:
                             if not _skippable(nxt):
                                 staged = (nxt, _upload(nxt))
                                 break
-                group.append((chunk, y, t0, False))
+                # the folds this chunk completes queue right behind its step
+                group.append((chunk, y, t0, False,
+                              self._fold_ahead(chunk, y, staging)))
             t1 = rnd.stop()
             for _ in range(tokens - committed):   # unused / skipped tokens
                 self._dispatch_sem.release()
@@ -918,25 +929,66 @@ class Worker:
                 self._send_q.put(None)
                 return
 
+    def _fold_ahead(self, chunk, y, staging) -> Optional[dict]:
+        """Stage the span rows of dispatched ``chunk`` (output ``y``, still
+        a future) by (request, segment), and fold each segment whose last
+        span this chunk carries into the combiner: its fold program enters
+        the device queue right behind this step.  Returns ``{(rid, s):
+        fold}`` for the sender to commit, or None.  A segment of a dropped
+        or demoted request is never folded here; the flags only ever turn
+        on, so the sender, which looks later, forgives or drops it too."""
+        folds = None
+        for sp in chunk.spans:
+            key = (sp.req.rid, sp.s)
+            if sp.req.dropped() or sp.req.demoted_for(self.model_idx):
+                staging.pop(key, None)
+                continue
+            st = staging.get(key)
+            if st is None:
+                st = staging[key] = [0, {}]
+            if y is not None:
+                off = sp.batch_off - chunk.off   # row within this chunk
+                if isinstance(y, tuple):   # quantized (q, per-row scale)
+                    st[1][sp.seg_off] = (y[0][off:off + sp.n],
+                                         y[1][off:off + sp.n])
+                else:
+                    st[1][sp.seg_off] = y[off:off + sp.n]
+            st[0] += sp.n
+            lo, hi = sp.req.bounds(sp.s)
+            if st[0] < hi - lo:
+                continue                   # segment still in flight
+            del staging[key]
+            if not st[1]:                  # fake predictor: instant zeros
+                P = np.zeros((hi - lo, self.num_classes), np.float32)
+            else:
+                parts = [st[1][k] for k in sorted(st[1])]
+                if len(parts) == 1:
+                    P = parts[0]
+                elif isinstance(parts[0], tuple):   # quantized parts
+                    P = (jnp.concatenate([p[0] for p in parts], axis=0),
+                         jnp.concatenate([p[1] for p in parts], axis=0))
+                else:
+                    P = jnp.concatenate(parts, axis=0)
+            if folds is None:
+                folds = {}
+            folds[key] = self.combiner.add(sp.req, sp.s, self.model_idx, P)
+        return folds
+
     # ---- stage 3: sender -----------------------------------------------------
     def _sender(self):
-        """Materialize each chunk's output and scatter its spans back to
-        their segments, forwarding a (request, segment) contribution **as
-        soon as its last span's chunk returns** — early per-segment
-        forwarding; the whole slot no longer has to retire first.  All of a
-        segment's spans still pass through THIS sender (the broadcaster
-        assigns every (segment, model) pair to one instance), but priority
-        reordering in the dispatch queue means they may arrive out of
-        seg_off order, so staging is row-count-based with parts keyed by
-        segment offset; downstream accounting already counts rows.  Still
-        ONE contribution per (request, segment) — per-span forwarding would
-        multiply combiner/accumulator traffic by chunks-per-segment and
-        serialize senders on the combiner lock.  The sender also owns the
-        DROPPED path: spans of cancelled/expired requests (and whole
-        skipped chunks) purge their staging entry and post the rows to the
-        ``rows_dropped`` counter, keyed by an idempotent ``DROPPED``
-        resolution message."""
-        staging: Dict[tuple, list] = {}     # (rid, s) -> [rows, {seg_off: P}]
+        """Materialize each chunk's output and count its spans' rows back
+        to their segments, committing a (request, segment) contribution —
+        folded by the predictor at dispatch — **as soon as its last span's
+        chunk returns**: early per-segment forwarding; the whole slot no
+        longer has to retire first.  All of a segment's spans still pass
+        through THIS sender (the broadcaster assigns every (segment, model)
+        pair to one instance), but priority reordering in the dispatch
+        queue means they may arrive out of seg_off order, so staging counts
+        rows.  The sender also owns the DROPPED path: spans of
+        cancelled/expired requests (and whole skipped chunks) purge their
+        staging entry and post the rows to the ``rows_dropped`` counter,
+        keyed by an idempotent ``DROPPED`` resolution message."""
+        staging: Dict[tuple, int] = {}      # (rid, s) -> rows materialized
         tr = self.tracer
         tr_ring = tr.ring(self._tr_sender) if tr is not None else None
         hb = self._hb["sender"]
@@ -950,8 +1002,8 @@ class Worker:
             hb[:] = [_HB_ACTIVE, t0]
             self.timers.add("send_wait", t0 - t_put)
             profiled = []                  # (bucket, valid) materialized
-            for chunk, y, t_dispatch, skipped in batch:
-                self._send_chunk(chunk, y, skipped, staging, profiled)
+            for chunk, y, t_dispatch, skipped, folds in batch:
+                self._send_chunk(chunk, y, skipped, folds, staging, profiled)
             now = time.perf_counter()
             if tr is not None and tr.enabled:
                 # grouped single span: slot a carries the group's shared
@@ -974,7 +1026,7 @@ class Worker:
                     self.profiler.observe(self.model_idx, self.device.key(),
                                           bucket, valid, dt * bucket / total)
 
-    def _send_chunk(self, chunk, y, skipped, staging, profiled):
+    def _send_chunk(self, chunk, y, skipped, folds, staging, profiled):
         if not skipped:
             if self._fault is not None:
                 self._fault.tick(self.worker_id, "sender")
@@ -1045,42 +1097,24 @@ class Worker:
                             ("i", "dropped", tr.clock(), 0.0,
                              sp.req.rid, sp.s, None, None))
                 continue
-            st = staging.get(key)
-            if st is None:
-                st = staging[key] = [0, {}]
-            if y is not None:
-                off = sp.batch_off - chunk.off   # row within this chunk
-                if isinstance(y, tuple):   # quantized (q, per-row scale)
-                    st[1][sp.seg_off] = (y[0][off:off + sp.n],
-                                         y[1][off:off + sp.n])
-                else:
-                    st[1][sp.seg_off] = y[off:off + sp.n]
-            st[0] += sp.n
-            if st[0] < hi - lo:
+            rows = staging.get(key, 0) + sp.n
+            if rows < hi - lo:
+                staging[key] = rows
                 continue                   # segment still in flight
-            del staging[key]
+            staging.pop(key, None)
             # pop-gate (DESIGN.md §10): claim the in-flight ledger entry
-            # IMMEDIATELY before forwarding.  dict.pop is GIL-atomic, so
+            # IMMEDIATELY before committing.  dict.pop is GIL-atomic, so
             # exactly one of {this sender, a supervisor replaying this
             # worker} wins the entry — a miss means the unit was already
             # resubmitted to a sibling (this worker was quarantined, e.g.
-            # a stalled stage waking up late) and forwarding it again
-            # would double-count rows into Y.  Popping BEFORE the post
-            # (not after) means a crash inside the post window hangs the
-            # unit (bounded by deadline / retry) instead of corrupting Y.
+            # a stalled stage waking up late) and committing it would
+            # double-count rows into Y; its fold, made at dispatch, is
+            # never committed and the combiner leaves it out of the post.
+            # Popping BEFORE the commit (not after) means a crash inside
+            # the post window hangs the unit (bounded by deadline / retry)
+            # instead of corrupting Y.
             if self._ledger.pop(key, None) is None:
                 continue
-            # no forward instant here: the pop-gate moment is already
-            # observable as the downstream combine span for (rid, s)
-            if y is None and not st[1]:    # fake predictor: instant zeros
-                P = np.zeros((hi - lo, self.num_classes), np.float32)
-            else:
-                parts = [st[1][k] for k in sorted(st[1])]
-                if len(parts) == 1:
-                    P = parts[0]
-                elif isinstance(parts[0], tuple):   # quantized parts
-                    P = (jnp.concatenate([p[0] for p in parts], axis=0),
-                         jnp.concatenate([p[1] for p in parts], axis=0))
-                else:
-                    P = jnp.concatenate(parts, axis=0)
-            self.combiner.add(sp.req, sp.s, self.model_idx, P)
+            # the dropped and demoted flags only ever turn on, so the
+            # predictor, which looked earlier, folded this segment
+            self.combiner.commit(sp.req, sp.s, folds.get(key))

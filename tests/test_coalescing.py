@@ -165,11 +165,14 @@ def test_combiner_counts_rows_not_messages(to_device):
     q = queue.Queue()
     comb = DeviceCombiner("d0", q)
     comb.begin(req, {0: 2})
-    comb.add(req, 0, 0, conv(P0[:5]), row_lo=0)       # member 0, split rows
+    # member 0, split rows
+    comb.commit(req, 0, comb.add(req, 0, 0, conv(P0[:5]), row_lo=0))
     assert q.empty() and comb.partials_posted == 0
-    comb.add(req, 0, 1, conv(P1), row_lo=0)           # member 1, whole seg
+    # member 1, whole seg
+    comb.commit(req, 0, comb.add(req, 0, 1, conv(P1), row_lo=0))
     assert q.empty()                                  # rows: 5 + 12 of 24
-    comb.add(req, 0, 0, conv(P0[5:]), row_lo=5)       # member 0, tail rows
+    # member 0, tail rows
+    comb.commit(req, 0, comb.add(req, 0, 0, conv(P0[5:]), row_lo=5))
     msg = q.get_nowait()
     assert comb.partials_posted == 1 and msg.count == 2 and msg.m is None
     np.testing.assert_allclose(msg.P, 0.6 * P0 + 0.4 * P1, atol=1e-5)
@@ -187,9 +190,9 @@ def test_combiner_pallas_rule_row_spans():
     q = queue.Queue()
     comb = DeviceCombiner("d0", q)
     comb.begin(req, {0: 2})
-    comb.add(req, 0, 0, jnp.asarray(P0[:7]), row_lo=0)
-    comb.add(req, 0, 0, jnp.asarray(P0[7:]), row_lo=7)
-    comb.add(req, 0, 1, jnp.asarray(P1), row_lo=0)
+    comb.commit(req, 0, comb.add(req, 0, 0, jnp.asarray(P0[:7]), row_lo=0))
+    comb.commit(req, 0, comb.add(req, 0, 0, jnp.asarray(P0[7:]), row_lo=7))
+    comb.commit(req, 0, comb.add(req, 0, 1, jnp.asarray(P1), row_lo=0))
     msg = q.get_nowait()
     np.testing.assert_allclose(msg.P, 0.6 * P0 + 0.4 * P1, atol=1e-5)
 

@@ -97,7 +97,7 @@ def test_combiner_unexpect_flushes_early_and_dest_closes():
     qa, qb = queue.Queue(), queue.Queue()
     a, b = DeviceCombiner("dA", qa), DeviceCombiner("dB", qb)
     a.begin(req, {0: 2})                  # both members expected on dA
-    a.add(req, 0, 1, P1)                  # member 1 lands before the steal
+    a.commit(req, 0, a.add(req, 0, 1, P1))   # member 1 lands before the steal
     assert qa.empty()
     assert a.unexpect(req, 0)             # member 0's descriptor stolen away
     msg_a = qa.get_nowait()               # dA closed early with count=1
@@ -105,7 +105,7 @@ def test_combiner_unexpect_flushes_early_and_dest_closes():
     np.testing.assert_array_equal(msg_a.P, 0.4 * P1)
     assert not a._parts and not a._expected
     b.expect_one(req, 0)                  # destination side of the steal
-    b.add(req, 0, 0, P0)
+    b.commit(req, 0, b.add(req, 0, 0, P0))
     msg_b = qb.get_nowait()
     assert msg_b.count == 1
     np.testing.assert_array_equal(msg_b.P, 0.6 * P0)

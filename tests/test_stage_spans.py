@@ -4,6 +4,7 @@ the ``serving.<stage>`` spans a ``jax.profiler`` session records."""
 import glob
 import os
 import threading
+import time
 
 import jax
 import numpy as np
@@ -81,7 +82,8 @@ def test_stage_counts_per_request_flush_chunk_and_partial(ens2):
     # one posted partial per segment on the one device, each waited for
     # and copied once; one fold per (segment, member)
     assert counts["combine_wait"] == counts["copy"] == posted == segments
-    assert counts["combine"] == 2 * segments
+    assert counts["combine"] == counters["folds_ahead"] == 2 * segments
+    assert "fold_rebuilds" not in counters
     assert counts["accumulate"] == segments
     assert "transfer" not in counts and "batcher_wait" not in counts
 
@@ -110,6 +112,63 @@ def test_each_device_copies_its_partial_after_the_combine_wait(ens2):
         if stage == "copy":
             assert last.get(tid) == "combine_wait"
         last[tid] = stage
+
+
+def test_each_fold_is_made_by_the_predictor_before_its_chunk_is_waited_for(
+        ens2):
+    """A segment's fold is dispatched by its member's predictor right
+    behind the step that completes it, so it starts before the sender's
+    device wait for that step ends; the sender commits it after that
+    wait.  One fold per (segment, member), none rebuilt."""
+    cfgs, params = ens2
+    with make_system(cfgs, params) as s:
+        member = {w.worker_id: w.model_idx for w in s.workers}
+        comb, = s.combiners.values()
+        order, add = [], s.timers.add
+        add_fold, commit = comb.add, comb.commit
+
+        def add_in_order(stage, dt):
+            order.append((threading.current_thread().name, stage,
+                          time.perf_counter() - dt, time.perf_counter()))
+            add(stage, dt)
+
+        def fold(req, seg, m, P, row_lo=0):
+            order.append((threading.current_thread().name, "fold",
+                          (req.rid, seg, m), time.perf_counter()))
+            return add_fold(req, seg, m, P, row_lo)
+
+        def commit_in_order(req, seg, f):
+            order.append((threading.current_thread().name, "commit",
+                          (req.rid, seg), time.perf_counter()))
+            commit(req, seg, f)
+
+        s.timers.add = add_in_order
+        comb.add, comb.commit = fold, commit_in_order
+        _requests(s)
+        counters = s.serving_counters()
+    segments = sum(-(-n // 8) for n in ROWS)
+    folds = {ev[2]: ev[3] for ev in order if ev[1] == "fold"}
+    assert len(folds) == counters["folds_ahead"] == 2 * segments
+    assert "fold_rebuilds" not in counters
+    combines = [ev for ev in order if ev[1] == "combine"]
+    assert len(combines) == 2 * segments
+    assert all(ev[0].endswith("-predictor") for ev in combines)
+    assert all(ev[0].endswith("-predictor")
+               for ev in order if ev[1] == "fold")
+    waited, last, commits = {}, {}, 0
+    for thread, stage, a, b in order:
+        if stage == "device_wait":
+            waited[thread] = b            # this chunk's wait ended at b
+        elif stage == "commit":
+            assert thread.endswith("-sender")
+            rid, seg = a
+            m = member[thread[:-len("-sender")]]
+            assert folds[(rid, seg, m)] < waited[thread]
+            commits += 1
+        elif stage == "copy":
+            assert last.get(thread) == "combine_wait"
+        last[thread] = stage
+    assert commits == 2 * segments
 
 
 def test_no_profiler_session_times_the_stage_and_opens_no_span():

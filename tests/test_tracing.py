@@ -106,8 +106,10 @@ def test_decode_grouped_single_span():
 
 
 def test_decode_slot_keys_and_passthrough():
-    assert _decode("X", "combine", 0.0, 0.1, 7, 2, 1, True)[5] == \
-        {"s": 2, "m": 1, "posted": True}
+    assert _decode("X", "combine", 0.0, 0.1, 7, 2, 1, None)[5] == \
+        {"s": 2, "m": 1}
+    assert _decode("X", "post", 0.0, 0.1, 7, 2, 3, None)[5] == \
+        {"s": 2, "count": 3}
     assert _decode("X", "accumulate", 0.0, 0.1, 7, 3, 64, None)[5] == \
         {"s": 3, "rows": 64}
     assert _decode("i", "pack", 0.0, 0.0, 1, 16, 0, None)[5] == \
